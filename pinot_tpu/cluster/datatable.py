@@ -392,7 +392,7 @@ def _to_tag(t):
 #
 # The MSE cross-server shuffle's fast wire format: every numeric column of
 # an exchange block is byte-packed into ONE buffer by the device kernel
-# (ops/kernels._pack_u8 — the PR-12 mesh combine pack), so the host path
+# (ops/kernels._pack_flat — the PR-12 mesh combine pack), so the host path
 # is memcpy→socket with zero per-row Python encodes. Its own magic keeps
 # it loudly incompatible with the row-wise PTDT container: an old reader
 # handed a PTDP blob raises DataTableError instead of misparsing.
@@ -441,7 +441,9 @@ def encode_packed_block(block: dict) -> bytes:
         cols.append({"name": name, "dtype": a.dtype.str,
                      "shape": list(a.shape)})
         arrs.append(jnp.asarray(a))
-    payload = np.asarray(kernels._pack_u8(tuple(arrs))).tobytes()
+    metas = [(np.dtype(c["dtype"]), tuple(c["shape"])) for c in cols]
+    payload = kernels.canonical_bytes(
+        np.asarray(kernels._pack_flat(tuple(arrs))), metas)
     header = json.dumps({"cols": cols}).encode()
     out = bytearray(PACKED_MAGIC)
     out += struct.pack("<H", PACKED_VERSION)

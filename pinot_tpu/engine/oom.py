@@ -21,26 +21,14 @@ from __future__ import annotations
 
 from typing import Callable, Optional
 
+from jax.errors import JaxRuntimeError
+
 from ..spi.metrics import SERVER_METRICS, ServerMeter
 
 
 class HbmExhaustedError(Exception):
     """Device memory exhausted even after evicting cold segment planes;
     the query fails cleanly (reference: QueryException on OOM-kill)."""
-
-
-def _jax_runtime_error_types() -> tuple:
-    try:
-        from jax.errors import JaxRuntimeError
-
-        return (JaxRuntimeError,)
-    except ImportError:  # older jaxlib layout
-        try:
-            from jaxlib.xla_extension import XlaRuntimeError
-
-            return (XlaRuntimeError,)
-        except ImportError:
-            return ()
 
 
 def is_hbm_oom(exc: BaseException) -> bool:
@@ -56,7 +44,7 @@ def is_hbm_oom(exc: BaseException) -> bool:
     msg = str(exc).lower()
     if "resource_exhausted" in msg:
         return True
-    if isinstance(exc, _jax_runtime_error_types()):
+    if isinstance(exc, JaxRuntimeError):
         return any(m in msg for m in ("out of memory", "failed to allocate",
                                       "allocating", "hbm"))
     return False

@@ -165,12 +165,9 @@ def enabled(ln: int, rn: int) -> bool:
         return True
     if ln + rn < AUTO_MIN_ROWS:
         return False
-    try:
-        from ..ops.mxu_groupby import backend_platform
+    from ..ops.mxu_groupby import backend_platform
 
-        return backend_platform() != "cpu"
-    except Exception:
-        return False
+    return backend_platform() != "cpu"
 
 
 # -- fused partition→join→aggregate stage ------------------------------------

@@ -399,8 +399,12 @@ def _kernel(fp: FusedPlan, s1: int, bpsb: int, num_segments: int,
             mats.append(m.astype(dt))
         elif pd[0] == "limb":
             _, slot, shift = pd
-            u = jnp.where(m, loaded[slot], 0).astype(jnp.uint32)
-            mats.append(((u >> shift) & bmask).astype(dt))
+            # i32 literal: a Python 0 traces as i64 under x64, which
+            # Mosaic cannot narrow
+            u = jnp.where(m, loaded[slot], jnp.int32(0)).astype(jnp.uint32)
+            # limbs are < 2^8, so the i32 hop is exact (Mosaic has no
+            # u32 -> bf16 cast)
+            mats.append(((u >> shift) & bmask).astype(jnp.int32).astype(dt))
         else:  # neg
             mats.append((m & (loaded[pd[1]] < 0)).astype(dt))
 

@@ -28,22 +28,10 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..engine import ir
-from ..ops.kernels import PackedOuts, _apply_packed, _pack_u8, _run_program_impl
+from ..ops.kernels import PackedOuts, _apply_packed, _pack_flat, _run_program_impl
 
 ROW_AXIS = "sp"  # intra-segment row sharding (sequence-parallel analogue)
 SEGMENT_AXIS = "dp"  # across segments (data-parallel analogue)
-
-
-def shard_map_compat(f, **kwargs):
-    """jax.shard_map with a fallback for jax 0.4.x, where it still lives in
-    jax.experimental.shard_map and `check_vma` is spelled `check_rep`."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, **kwargs)
-    from jax.experimental.shard_map import shard_map
-
-    if "check_vma" in kwargs:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    return shard_map(f, **kwargs)
 
 
 def make_mesh(n_devices: int | None = None, axes=(ROW_AXIS,)) -> Mesh:
@@ -140,7 +128,7 @@ def _row_sharded_call(program: ir.Program, arrays: tuple, params: tuple, num_doc
     param_specs = tuple(
         P(ROW_AXIS) if i in mask_idxs else P() for i in range(len(params)))
     out_specs = P(ROW_AXIS) if program.mode == "selection" else P()
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(array_specs, param_specs, P()),
         out_specs=out_specs,
@@ -201,10 +189,7 @@ def run_program_row_sharded(program: ir.Program, arrays: tuple, params: tuple,
 def mesh_device_count() -> int:
     """Local devices the segment-axis mesh may span, capped by the
     PINOT_TPU_MESH_DEVICES env knob (<=1 disables mesh execution)."""
-    try:
-        n = len(jax.devices())
-    except Exception:  # backend init failure → solo execution
-        return 1
+    n = len(jax.devices())
     cap = os.environ.get("PINOT_TPU_MESH_DEVICES")
     if cap:
         try:
@@ -245,7 +230,7 @@ def _batch_sharded_call(program: ir.Program, arrays: tuple, params: tuple,
 
         return jax.vmap(one)(arrays_w, params_l, num_docs_l)
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(tuple(P(SEGMENT_AXIS) for _ in arrays),
                   tuple(P(SEGMENT_AXIS) for _ in params),
@@ -277,7 +262,7 @@ def run_program_batch_sharded(program: ir.Program, arrays: tuple, params: tuple,
 def _pack_sliced(outs: tuple, s_real: int):
     # drop the ragged pad rows on device, then byte-pack exactly like the
     # solo path so the host sees identical flat bytes
-    return _pack_u8(tuple(o[:s_real] for o in outs))
+    return _pack_flat(tuple(o[:s_real] for o in outs))
 
 
 def pack_outputs_gathered(outs: tuple, s_real: int) -> PackedOuts:
@@ -301,9 +286,9 @@ def _pack_collective(outs: tuple, s_real: int, ndev: int):
         gathered = tuple(
             jax.lax.all_gather(o, SEGMENT_AXIS, axis=0, tiled=True)
             for o in outs_l)
-        return _pack_u8(tuple(g[:s_real] for g in gathered))
+        return _pack_flat(tuple(g[:s_real] for g in gathered))
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(tuple(P(SEGMENT_AXIS) for _ in outs),),
         out_specs=P(),

@@ -26,14 +26,21 @@ _tried = False
 
 
 def _build() -> bool:
+    # build under a per-process name and rename into place: rename is
+    # atomic, so concurrent builders (parallel test workers) can never
+    # expose a half-written library to each other's CDLL
+    tmp = _SO.with_name(f"{_SO.name}.{os.getpid()}.tmp")
     try:
         subprocess.run(
             ["g++", "-O3", "-march=native", "-shared", "-fPIC",
-             str(_SRC), "-o", str(_SO)],
+             str(_SRC), "-o", str(tmp)],
             check=True, capture_output=True, timeout=120)
+        os.replace(tmp, _SO)
         return True
-    except (subprocess.SubprocessError, FileNotFoundError):
+    except (subprocess.SubprocessError, OSError):
         return False
+    finally:
+        tmp.unlink(missing_ok=True)
 
 
 def get_lib() -> Optional[ctypes.CDLL]:

@@ -204,44 +204,47 @@ def test_compare_is_pure():
     assert verdicts["q2_groupby"] == "PASS"
 
 
-@pytest.mark.parametrize("path_a,path_b", [
-    ("BENCH_r05.json", "BENCH_r05.json"),
-    (".bench_partial/summary.json", ".bench_partial/summary.json"),
-])
-def test_real_artifacts_self_compare_pass(path_a, path_b):
-    """The committed rounds themselves must load (wrapper salvage for the
-    r0X files) and self-compare clean."""
-    from pathlib import Path
+def _truncated_wrapper(payload: dict) -> dict:
+    """A driver wrapper whose ``tail`` kept only the END of the printed
+    payload: the head (metric, opening of ``detail``, part of the first
+    config) is cut off, whole per-config objects survive."""
+    line = json.dumps(payload)
+    cut = line.index('"q2_groupby"') - 40  # mid-way through q1's object
+    return {"n": 1, "cmd": "python bench.py", "rc": 0, "tail": line[cut:]}
 
-    root = Path(__file__).resolve().parent.parent
-    a, b = root / path_a, root / path_b
-    if not a.exists():
-        pytest.skip(f"{path_a} not in this checkout")
-    assert main([str(a), str(b)]) == 0
+
+@pytest.mark.parametrize("form", ["payload", "truncated-wrapper"])
+def test_round_files_self_compare_pass(tmp_path, form):
+    """Both on-disk forms of a round must load (wrapper salvage for the
+    truncated form) and self-compare clean."""
+    doc = _payload()
+    if form == "truncated-wrapper":
+        doc = _truncated_wrapper(doc)
+    a = _write(tmp_path, "a.json", doc)
+    if form == "truncated-wrapper":
+        loaded = load_round(a)
+        assert loaded.get("salvaged") is True
+        # q1's object lost its head to the cut; the other two survive whole
+        assert set(loaded["detail"]) == {"q2_groupby", "q3_highcard"}
+    assert main([a, a]) == 0
 
 
 @pytest.mark.gate
-def test_two_most_recent_committed_rounds_no_correctness_flip(capsys):
-    """Tier-1 gate smoke: bench_gate over the two most recent committed
-    rounds. Committed rounds may come from different machines, so pure
-    timing deltas only warn here — but a correctness ``match`` flip (any
-    config returning different rows than sqlite) fails the suite."""
-    from pathlib import Path
-
-    root = Path(__file__).resolve().parent.parent
-    rounds = sorted(root.glob("BENCH_r[0-9][0-9].json"))
-    if len(rounds) < 2:
-        pytest.skip("fewer than two committed BENCH rounds")
-    base, cand = load_round(str(rounds[-2])), load_round(str(rounds[-1]))
-    report = compare(base, cand, threshold=0.30)
-    flips = [f for f in report["failures"] if "flip" in f]
-    assert not flips, f"correctness flipped between rounds: {flips}"
-    if not report["pass"]:
-        import warnings
-
-        warnings.warn("bench_gate timing verdict FAIL between committed "
-                      f"rounds (cross-machine noise tolerated): "
-                      f"{report['failures']}")
+def test_two_consecutive_rounds_no_correctness_flip(tmp_path):
+    """Gate smoke over two consecutive rounds recorded on different
+    machines: pure timing deltas only warn — but a correctness ``match``
+    flip (any config returning different rows than the oracle) fails."""
+    base = _payload(runner={"logicalCores": 8, "physicalCores": 4})
+    cand = _payload(runner={"logicalCores": 1, "physicalCores": 1})
+    cand["detail"]["q3_highcard"]["tpu_p50_s"] = 3.0  # 2x slower: noise
+    rounds = [_write(tmp_path, "BENCH_r01.json", _truncated_wrapper(base)),
+              _write(tmp_path, "BENCH_r02.json", cand)]
+    report = compare(load_round(rounds[0]), load_round(rounds[1]),
+                     threshold=0.30)
+    assert not [f for f in report["failures"] if "flip" in f]
+    cand["detail"]["q2_groupby"]["match"] = False
+    flipped = compare(load_round(rounds[0]), cand, threshold=0.30)
+    assert [f for f in flipped["failures"] if "flip" in f]
 
 
 def test_warm_p50_regression_fails(tmp_path, capsys):
