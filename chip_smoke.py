@@ -241,11 +241,11 @@ def watch_compiles() -> dict:
     return seen
 
 
-def run_query(ctx, sql):
-    # a query still running close to the broker's 60 s default timeout is
-    # about to fail: say where every thread is (a compile that takes
-    # minutes on the chip shows up here, not in any CPU test)
-    faulthandler.dump_traceback_later(50, exit=False)
+def run_query(ctx, sql, watchdog_s=50):
+    # a query still running close to its timeout (the broker's default is
+    # 60 s) is about to fail: say where every thread is (a compile that
+    # takes minutes on the chip shows up here, not in any CPU test)
+    faulthandler.dump_traceback_later(watchdog_s, exit=False)
     t0 = time.perf_counter()
     try:
         resp = ctx.broker.execute_sql(sql)
@@ -259,12 +259,12 @@ def run_query(ctx, sql):
 
 
 def cold_and_warm(ctx, label, sql, expected, ordered=False,
-                  one_family=False):
+                  one_family=False, watchdog_s=50):
     """Run ``sql`` twice; both answers must equal ``expected``; the first
     must dispatch on the device and the second must not compile. With
     ``one_family`` the table's segments must ride ONE batched dispatch."""
     before = dict(ctx.compiles)
-    resp, rows, cold = run_query(ctx, NOCACHE + sql)
+    resp, rows, cold = run_query(ctx, NOCACHE + sql, watchdog_s)
     xla_n = ctx.compiles["requests"] - before["requests"]
     xla_s = ctx.compiles["seconds"] - before["seconds"]
     got = norm(rows, sort=not ordered)
@@ -303,9 +303,9 @@ def check_no_fallbacks(ctx):
     from pinot_tpu.ops import fused_groupby
 
     totals = PERF_LEDGER.snapshot()["fallbackEvents"]["total"]
-    for ev in ("fused-host", "mesh-solo", "device-join-host"):
-        require(not totals.get(ev),
-                f"fallback events: {ev}={totals.get(ev)}")
+    # fused-host, mesh-solo, device-join-host, sparse-combine-host: none
+    # of any kind may have fired
+    require(not any(totals.values()), f"fallback events: {totals}")
     require(fused_groupby._STATE["error"] is None,
             f"fused kernel disabled: {fused_groupby._STATE['error']!r}")
     require(fused_groupby.active() == ctx.kernel_mode,
@@ -357,17 +357,36 @@ def one_chip_phase(args, ctx):
                 f"fusedFallback={a.get('fusedFallback')!r}")
     say(f"traced fused dispatch span: fused={spans[0].get('fused')!r}, "
         "no fusedFallback")
+    # SET sparseGroupBy = true: lineorder1's segment holds more keys than
+    # the dense table admits (2^21) and goes sparse by itself, but a
+    # lineorder16 segment holds about 419,000 and a rehearsal's far fewer;
+    # the option sends all of them down the sort/scan path
+    sparse = "SET sparseGroupBy = true; "
     cold_and_warm(
         ctx, "high-cardinality GROUP BY lo_orderkey, lineorder1 "
         "(sparse path)",
-        f"SET numGroupsLimit = 20000000; SELECT lo_orderkey, "
+        f"{sparse}SET numGroupsLimit = 20000000; SELECT lo_orderkey, "
         f"SUM(lo_revenue), COUNT(*) FROM lineorder1 GROUP BY lo_orderkey "
         f"ORDER BY lo_orderkey LIMIT {limit}", ref_highcard(c1, limit),
         ordered=True)
+    # the first cold run compiles a 64-bit lax.sort (the merge of the 16
+    # segment tables), which alone takes the chip's compiler most of a
+    # minute (cold reading 54 s on the chip's host, 61-74 s of compile in
+    # the sandbox): too close to the broker's 60 s default, so this query
+    # brings its own timeout
+    say("next query sets timeoutMs = 600000: its cold compile (one "
+        "lax.sort over int64 keys) takes most of the broker's 60 s default")
+    cold_and_warm(
+        ctx, "high-cardinality GROUP BY lo_orderkey, lineorder16 (sparse "
+        "path, one batch family, 16 segment tables merged on the device)",
+        f"{sparse}SET timeoutMs = 600000; SELECT lo_orderkey, "
+        f"SUM(lo_revenue), COUNT(*) FROM lineorder16 GROUP BY lo_orderkey "
+        f"ORDER BY lo_orderkey LIMIT {limit}", ref_highcard(c16, limit),
+        ordered=True, one_family=True, watchdog_s=590)
     cold_and_warm(
         ctx, "DISTINCTCOUNT + SUM inside high-cardinality GROUP BY, "
         "lineorder1 (sparse path)",
-        f"SET numGroupsLimit = {limit}; SELECT lo_orderkey, "
+        f"{sparse}SET numGroupsLimit = {limit}; SELECT lo_orderkey, "
         f"DISTINCTCOUNT(lo_discount), SUM(lo_revenue) FROM lineorder1 "
         f"GROUP BY lo_orderkey ORDER BY lo_orderkey LIMIT {limit}",
         ref_highcard_distinct(c1, limit), ordered=True)
@@ -429,7 +448,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=20260926)
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     ap.add_argument("--rehearse", action="store_true",
-                    help="toy sizes on whatever backend JAX finds, Pallas "
+                    help="toy sizes on the CPU (JAX_PLATFORMS=cpu), Pallas "
                          "kernels in interpret mode; never a chip pass")
     args = ap.parse_args(argv)
     device = {"platform": None, "kind": None, "count": 0}
@@ -456,9 +475,11 @@ def main(argv=None) -> int:
                   "count": len(devs)}
         say(f"device: {device}, jax {jax.__version__}, seed {args.seed}, "
             f"compile cache at {jax.config.jax_compilation_cache_dir}")
-        if not args.rehearse:
-            require(device["platform"] == "tpu",
-                    f"no TPU: JAX found {device['platform']!r}")
+        # a rehearsal is never a chip pass: it runs on the CPU only
+        want_platform = "cpu" if args.rehearse else "tpu"
+        require(device["platform"] == want_platform,
+                f"want platform {want_platform!r}, JAX found "
+                f"{device['platform']!r}")
         require(device["count"] == args.chips,
                 f"--chips {args.chips} but JAX found {device['count']} "
                 "device(s)")

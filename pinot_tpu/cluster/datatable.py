@@ -480,5 +480,5 @@ def decode_packed_block(blob: bytes) -> dict:
     flat = np.frombuffer(payload, dtype=np.uint8)
     metas = [(np.dtype(c["dtype"]), tuple(c["shape"]))
              for c in header["cols"]]
-    arrs = kernels._split_flat(flat, metas)
+    arrs = kernels._split_flat(flat, metas, planar=False)
     return {c["name"]: a for c, a in zip(header["cols"], arrs)}

@@ -286,8 +286,9 @@ class Program:
     # (ids remapped through a host-computed LUT — ParamGather). When set,
     # group_slots is empty.
     group_vexprs: tuple[ValueExpr, ...] = ()
-    # sparse mode: the FULL composite key space (cardinality product before
-    # the numGroupsLimit cap). Static, so the kernel can sort 32-bit keys
+    # sparse mode: an upper bound on the FULL composite key space
+    # (cardinality product before the numGroupsLimit cap, bucketed by
+    # plan._key_space_bucket). Static, so the kernel can sort 32-bit keys
     # when they fit — 64-bit sorts and scatters are emulated on TPU
     key_space: int = 0
     # sparse mode: the device trim is an ORDER BY pushdown (ASC group-key

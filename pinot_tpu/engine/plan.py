@@ -40,6 +40,18 @@ DEFAULT_NUM_GROUPS_LIMIT = 100_000  # reference InstancePlanMakerImplV2 default
 _SPARSE_AGG_KINDS = {"count", "sum", "sumsq", "min", "max"}
 
 
+def _key_space_bucket(num_groups: int) -> int:
+    """Program.key_space for a sparse group-by: an upper bound the kernel
+    only uses to pick 32-bit keys, so it is rounded up to a power of two
+    (kept below the int32 sentinel where the exact bound is). Segments
+    whose dictionaries differ in size then share ONE program — one compile
+    and one batch family, as `executor._dict_pad` does for dict planes —
+    instead of one program per segment."""
+    bucket = 1 << max(0, int(num_groups) - 1).bit_length()
+    i32_top = (1 << 31) - 2
+    return i32_top if num_groups <= i32_top < bucket else bucket
+
+
 def _vexpr_uses_slots(ve, slots: set) -> bool:
     """True when a value expression reads any of the given array slots."""
     if ve is None:
@@ -929,7 +941,8 @@ class SegmentPlanner(AggPlanContext):
                 group_strides=tuple(strides),
                 num_groups=out_groups,
                 group_vexprs=tuple(group_vexprs) if any_derived else (),
-                key_space=num_groups if mode == "group_by_sparse" else 0,
+                key_space=(_key_space_bucket(num_groups)
+                           if mode == "group_by_sparse" else 0),
                 exact_trim=exact_trim,
                 keys_presorted=(keys_presorted
                                 and mode == "group_by_sparse"),

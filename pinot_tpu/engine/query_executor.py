@@ -1036,10 +1036,15 @@ class QueryExecutor:
             outs_np = unpack_outputs(kernels.pack_outputs(merged))
         except TimeoutError:
             raise
-        except Exception:
-            logging.getLogger(__name__).debug(
-                "sparse device combine failed; host merge fallback",
-                exc_info=True)
+        except Exception as e:
+            # counted like every other engine fallback (perf_ledger's
+            # fallbackEvents; chip_smoke.py requires zero)
+            from .perf_ledger import PERF_LEDGER
+
+            PERF_LEDGER.note_event("sparse-combine-host")
+            logging.getLogger(__name__).warning(
+                "sparse device combine failed (%s: %s); host merge "
+                "fallback", type(e).__name__, e)
             return None
         counts = outs_np[0][:-1]
         gids = np.nonzero(counts)[0]

@@ -256,44 +256,46 @@ def _decode_f64(raw: np.ndarray, shape) -> np.ndarray:
 
 
 def _word_planes(o) -> list:
-    """The little-endian bytes of `o` as flat uint32 planes, one per 4-byte
-    word of a value (1 for 32-bit dtypes, 2 for 64-bit ints, 4 for the f64
-    wire format), for dtypes whose itemsize is a multiple of 4."""
+    """`o` as flat uint32 planes. A 4-byte dtype is one plane; wider ones
+    split into one plane per 4-byte word of a value (2 for 64-bit ints, 4
+    for the f64 wire format). A narrower dtype (selection bitmaps, bool
+    planes) packs 2 or 4 values per word, again planar: with m words, word
+    j holds values j, m+j, 2m+j, ... lowest bits first — contiguous slices
+    only. Words are never interleaved on device: a (.., 2)/(.., 4) minor
+    dim flattened, or a 64-bit bitcast-convert, costs the chip's compiler
+    tens of seconds per program; `_value_major_chunks` transposes on the
+    host instead."""
     if o.dtype == jnp.float64:
         w = _encode_f64(o)
         return [w[..., k].reshape(-1) for k in range(4)]
     if o.dtype.itemsize == 8:
-        # split arithmetically: shift/mask is what the TPU's x64 rewrite
-        # does natively, while a 64-bit bitcast-convert costs the chip's
-        # compiler tens of seconds per program
         return [(o & 0xFFFFFFFF).astype(jnp.uint32).reshape(-1),
                 (o >> 32).astype(jnp.uint32).reshape(-1)]
-    return [jax.lax.bitcast_convert_type(o, jnp.uint32).reshape(-1)]
+    if o.dtype.itemsize == 4:
+        return [jax.lax.bitcast_convert_type(o, jnp.uint32).reshape(-1)]
+    if o.dtype == jnp.bool_:
+        o = o.astype(jnp.uint8)
+    bits = 8 * o.dtype.itemsize
+    per = 32 // bits
+    u = jax.lax.bitcast_convert_type(
+        o, jnp.uint8 if bits == 8 else jnp.uint16)
+    u = u.astype(jnp.uint32).reshape(-1)
+    m = -(-u.shape[0] // per)
+    u = jnp.concatenate([u, jnp.zeros(per * m - u.shape[0], jnp.uint32)])
+    w = u[:m]
+    for k in range(1, per):
+        w = w | (u[k * m:(k + 1) * m] << (bits * k))
+    return [w]
 
 
 @jax.jit
 def _pack_flat(outs: tuple):
-    """Concatenate the outputs into one flat device buffer.
-
-    When every output is 4-byte-aligned (every aggregation / group-by
-    shape) the buffer is uint32 and PLANAR: each output contributes its
-    word planes back to back (all low words, then all high words, ...).
-    Interleaving words on device — the value-major byte stream — makes the
-    chip's compiler spend half a minute on a relayout per program, so the
-    host transposes instead (`_split_flat(planar=True)`, `canonical_bytes`).
-    Otherwise (selection bitmaps, bool planes) it is the value-major uint8
-    byte stream itself."""
-    if all(o.dtype.itemsize % 4 == 0 for o in outs):
-        chunks = [w for o in outs for w in _word_planes(o)]
-    else:
-        chunks = []
-        for o in outs:
-            if o.dtype == jnp.bool_:
-                o = o.astype(jnp.uint8)
-            elif o.dtype == jnp.float64:
-                o = _encode_f64(o)
-            chunks.append(
-                jax.lax.bitcast_convert_type(o, jnp.uint8).reshape(-1))
+    """Concatenate the outputs into one flat uint32 device buffer, PLANAR:
+    each output contributes its word planes back to back (all low words,
+    then all high words, ...). Interleaving words on device — the
+    value-major byte stream — is what the chip's compiler cannot do in
+    usable time (`_word_planes`), so the host transposes instead."""
+    chunks = [w for o in outs for w in _word_planes(o)]
     return jnp.concatenate(chunks) if len(chunks) > 1 else chunks[0]
 
 
@@ -323,32 +325,37 @@ def count_host_fetch() -> None:
 def unpack_outputs(p: PackedOuts) -> list:
     count_host_fetch()
     flat = np.asarray(p.flat)  # the query's single device→host transfer
-    return _split_flat(flat.view(np.uint8), p.metas,
-                       planar=flat.dtype == np.uint32)
+    return _split_flat(flat.view(np.uint8), p.metas)
 
 
 def _value_major_chunks(flat: np.ndarray, metas, planar: bool):
-    """Yield (dtype, shape, bytes-of-that-output) over a packed buffer's
-    bytes in VALUE-MAJOR order — the layout wire formats and `_decode_f64`
-    are defined over. `planar` marks a uint32 `_pack_flat` buffer, whose
-    multi-word outputs (64-bit ints: 2 words, the f64 wire format: 4) are
-    transposed back here; the one place that knows that layout."""
+    """Yield (dtype, shape, bytes-of-that-output) over packed bytes in
+    VALUE-MAJOR order — the layout the PTDP wire format and `_decode_f64`
+    are defined over. `planar` says the bytes are a `_pack_flat` buffer,
+    whose word planes (`_word_planes`) are transposed back here — the one
+    place on the host that knows the device layout. Otherwise they already
+    are the value-major stream (a PTDP payload)."""
     off = 0
     for dt, shape in metas:
         count = int(np.prod(shape, dtype=np.int64))
         words = 4 if dt == np.float64 else max(1, dt.itemsize // 4)
-        nbytes = count * (4 * words if planar or dt == np.float64
-                          else dt.itemsize)
-        chunk = flat[off:off + nbytes]
-        if planar and words > 1:
+        nbytes = count * (4 * words if dt.itemsize >= 4 else dt.itemsize)
+        stored = -(-nbytes // 4) * 4 if planar else nbytes
+        chunk = flat[off:off + stored]
+        off += stored
+        if planar and dt.itemsize < 4:
+            per = 4 // dt.itemsize
+            chunk = np.ascontiguousarray(
+                chunk.view(f"u{dt.itemsize}").reshape(-1, per).T
+            ).reshape(-1)[:count].view(np.uint8)
+        elif planar and words > 1:
             chunk = np.ascontiguousarray(
                 chunk.view(np.uint32).reshape(words, count).T
             ).reshape(-1).view(np.uint8)
         yield dt, shape, chunk
-        off += nbytes
 
 
-def _split_flat(flat: np.ndarray, metas, planar: bool = False) -> list:
+def _split_flat(flat: np.ndarray, metas, planar: bool = True) -> list:
     """Packed bytes (uint8 view) → one array per output."""
     return [_decode_f64(chunk, shape) if dt == np.float64
             else chunk.view(dt).reshape(shape)
@@ -357,10 +364,7 @@ def _split_flat(flat: np.ndarray, metas, planar: bool = False) -> list:
 
 def canonical_bytes(flat: np.ndarray, metas) -> bytes:
     """The value-major byte stream of a fetched `_pack_flat` buffer
-    (cluster/datatable.py's PTDP payload), whichever layout the device
-    used."""
-    if flat.dtype != np.uint32:
-        return flat.tobytes()
+    (cluster/datatable.py's PTDP payload)."""
     return b"".join(chunk.tobytes() for _dt, _shape, chunk in
                     _value_major_chunks(flat.view(np.uint8), metas, True))
 
@@ -384,11 +388,11 @@ def fetch_packed_batch(packs: list) -> list:
     16. Unequal lengths fetch individually — batching them
     would compile a fresh concat executable per length combination."""
     out = [None] * len(packs)
-    by_len: dict[tuple, list[int]] = {}
+    by_len: dict[int, list[int]] = {}
     for i, p in enumerate(packs):
-        by_len.setdefault((p.flat.dtype, int(p.flat.shape[0])), []).append(i)
-    for (dt, n), idxs in by_len.items():
-        nbytes = n * dt.itemsize
+        by_len.setdefault(int(p.flat.shape[0]), []).append(i)
+    for n, idxs in by_len.items():
+        nbytes = n * 4
         group_ok = len(idxs) > 1 and nbytes * len(idxs) <= _BATCH_FETCH_CAP
         if not group_ok:
             for i in idxs:
@@ -399,7 +403,7 @@ def fetch_packed_batch(packs: list) -> list:
             tuple(packs[i].flat for i in idxs))).view(np.uint8)
         for j, i in enumerate(idxs):
             out[i] = _split_flat(flat[j * nbytes:(j + 1) * nbytes],
-                                 packs[i].metas, planar=dt == np.uint32)
+                                 packs[i].metas)
     return out
 
 
@@ -1107,22 +1111,10 @@ def _prefix_sum(x):
 
 def _segmented_scan(v, first, op):
     """Per-segment running reduce over sorted data: at index i, op over
-    v[segment_start..i]. log2(n) passes — no scatter."""
-    if jnp.issubdtype(v.dtype, jnp.floating) and op is jnp.add:
-        # float addition is order-sensitive: keep the recursive tree's
-        # association so f64 sums stay bit-identical to earlier releases
-        def combine(a, b):
-            va, fa = a
-            vb, fb = b
-            return jnp.where(fb, vb, op(va, vb)), fa | fb
-
-        out, _ = jax.lax.associative_scan(combine, (v, first))
-        return out
-    # exact ops (integer add, min, max, or): any association gives the same
-    # bits, so use shift passes (see _prefix_sum). Row 0 opens the first
-    # segment whatever its flag says; with it set, every row i < d already
-    # carries a flag at pass d and the zeros _delayed shifts in are never
-    # combined.
+    v[segment_start..i]. log2(n) shift passes (see _prefix_sum) — no
+    scatter, no recursive scan. Row 0 opens the first segment whatever its
+    flag says; with it set, every row i < d already carries a flag at pass
+    d and the zeros _delayed shifts in are never combined."""
     n = v.shape[-1]
     f = first.at[..., 0].set(True)
     d = 1

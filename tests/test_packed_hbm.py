@@ -84,7 +84,7 @@ def test_f64_wire_codec_bit_exact():
     import jax.numpy as jnp
 
     from pinot_tpu.ops.kernels import _decode_f64, _encode_f64, \
-        pack_outputs, unpack_outputs
+        canonical_bytes, pack_outputs, unpack_outputs
 
     rng = np.random.default_rng(3)
     mags = np.ldexp(1.0, rng.integers(-1020, 1020, 4000).astype(np.int32))
@@ -113,10 +113,22 @@ def test_f64_wire_codec_bit_exact():
     outs = (jnp.asarray(vals, jnp.float64),
             jnp.asarray(rng.integers(-2**62, 2**62, 100), jnp.int64),
             jnp.asarray(rng.integers(0, 2, 64), jnp.bool_),
-            jnp.asarray(rng.standard_normal(33), jnp.float32))
-    got = unpack_outputs(pack_outputs(outs))
+            jnp.asarray(rng.standard_normal(33), jnp.float32),
+            # narrow dtypes whose byte count is not a whole number of words
+            jnp.asarray(rng.integers(0, 2, (3, 5)), jnp.bool_),
+            jnp.asarray(rng.integers(0, 256, 1001), jnp.uint8),
+            jnp.asarray(rng.integers(-2**15, 2**15, 7), jnp.int16))
+    packed = pack_outputs(outs)
+    assert packed.flat.dtype == jnp.uint32  # the one device layout
+    got = unpack_outputs(packed)
     for g, o in zip(got, outs):
+        assert g.dtype == o.dtype and g.shape == o.shape
         assert np.asarray(g).tobytes() == np.asarray(o).tobytes()
+    # the PTDP payload is the value-major byte stream whatever the device
+    # layout: every non-f64 output's own bytes, back to back
+    wire = canonical_bytes(np.asarray(packed.flat), packed.metas)
+    assert wire[len(vals) * 16:] == b"".join(
+        np.asarray(o).tobytes() for o in outs[1:])
 
 
 def test_device_cache_warm(tmp_path):

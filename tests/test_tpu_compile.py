@@ -41,20 +41,27 @@ def one_chip():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
 
+    prev_log = os.environ.get("TPU_LOG_DIR")
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     # a compile for a described chip is written to the persistent cache but
     # cannot be read back without the chip: keep these out of it
     prev = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", prev)
-    cc.reset_cache()
+    try:
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        jax.config.update("jax_enable_compilation_cache", False)
+        cc.reset_cache()
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+        if prev_log is None:
+            os.environ.pop("TPU_LOG_DIR", None)
+        else:
+            os.environ["TPU_LOG_DIR"] = prev_log
 
 
 @pytest.fixture(scope="module")
@@ -194,6 +201,22 @@ def test_fused_kernel_compiles(one_chip, ssb, where):
     assert "tpu_custom_call" in text
 
 
+def test_fused_kernel_bf16_limbs_compiles(one_chip, ssb, monkeypatch):
+    """The fused kernel with the bf16 limb planes PINOT_TPU_MXU_INT8=0
+    selects (Mosaic has no u32 -> bf16 cast; the limbs hop through i32).
+    Another row count than the int8 cases, so the jit does not hand back
+    their trace."""
+    monkeypatch.setattr(mxu_groupby, "_INT8", False)
+    monkeypatch.setattr(mxu_groupby, "PLANE_DTYPE", jnp.bfloat16)
+    monkeypatch.setattr(mxu_groupby, "LIMB_BITS", 8)
+    monkeypatch.setattr(mxu_groupby, "MAX_PLANES", _WIDEST["bfloat16"])
+    _, text = _compile_program(
+        one_chip, ssb, _GROUP2.format(where="lo_quantity BETWEEN 10 AND 30"),
+        R22, fused="tpu")
+    assert "tpu_custom_call" in text
+    assert "bf16" in text and "s8[" not in text
+
+
 def test_fused_kernel_three_sums_compiles(one_chip, ssb):
     """1 count + 3 x 6 signed-width limb planes: the widest fused shape the
     SSB queries reach."""
@@ -235,13 +258,46 @@ def test_sparse_group_by_compiles(one_chip, ssb, sql):
     """High-cardinality (sort/scan-based) group-by at a 16M-row segment
     with a 4M-key dictionary. Guards compile TIME as much as acceptance:
     the chip's compiler needs minutes for jnp.cumsum / associative_scan at
-    this n, seconds for the shift scans kernels._prefix_sum uses."""
+    this n, seconds for the shift scans kernels._prefix_sum uses (a
+    lax.sort costs it 20-60 s whatever n is). The bound is wide because
+    five other workers share the host; it guards the cliff."""
     t0 = time.perf_counter()
     program, _ = _compile_program(
         one_chip, ssb, "SET sparseGroupBy = true; " + sql, R24,
         sparse_groups=1 << 22)
     assert program.mode == "group_by_sparse"
-    assert time.perf_counter() - t0 < 120  # was > 300 s with jnp.cumsum
+    assert time.perf_counter() - t0 < 300  # did not end in 300 s with cumsum
+
+
+def test_sparse_batch_family_compiles(one_chip, ssb):
+    """The multi-segment form: one vmapped dispatch over 16 x 2^22 rows."""
+    program, _ = _compile_program(
+        one_chip, ssb,
+        "SET sparseGroupBy = true; SELECT lo_orderkey, SUM(lo_revenue), "
+        "COUNT(*) FROM t GROUP BY lo_orderkey ORDER BY lo_orderkey "
+        "LIMIT 100000", R22, batch=16, sparse_groups=1 << 19)
+    assert program.mode == "group_by_sparse"
+
+
+def test_sparse_device_combine_compiles(one_chip):
+    """The server-level merge of 16 segments' sparse tables
+    (kernels.combine_sparse_group_tables) with f64 SUM states: min, max and
+    float adds all run the shift-pass scan. Its int64 lax.sort is what
+    takes the chip's compiler about a minute."""
+    s, k = 16, 100_000
+    keys = tuple(_spec(one_chip, (k,), jnp.int64) for _ in range(s))
+    counts = tuple(_spec(one_chip, (k + 1,), jnp.int64) for _ in range(s))
+    states = tuple(tuple(_spec(one_chip, (k + 1,), dt)
+                         for dt in (jnp.float64, jnp.int64, jnp.float64,
+                                    jnp.float64))
+                   for _ in range(s))
+    t0 = time.perf_counter()
+    compiled = kernels.combine_sparse_group_tables.lower(
+        keys, counts, states, kinds=("add", "add", "min", "max")).compile()
+    assert time.perf_counter() - t0 < 400  # 74 s alone; 87 s at 2^20 rows
+    # for the recursive scan this replaced, which grows with n
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 4 * 10 ** 9
 
 
 def test_selection_compiles(one_chip, ssb):
@@ -270,20 +326,31 @@ def test_batch_family_compiles(one_chip, ssb, monkeypatch, sql, pallas):
 # -- the output pack ---------------------------------------------------------
 
 
-@pytest.mark.parametrize("shape", [(16, 7001), (100_001,)],
-                         ids=["batch-16x7001", "sparse-100001"])
-def test_output_pack_compiles_fast(one_chip, shape):
+@pytest.mark.parametrize("outs", [
+    pytest.param([((16, 7001), "int64"), ((16, 7001), "float64"),
+                  ((16, 7001), "uint32")], id="batch-16x7001"),
+    pytest.param([((1_600_001,), "int64"), ((1_600_001,), "float64"),
+                  ((1_600_000,), "int64")], id="sparse-merged-1600001"),
+    # narrow planes beside 64-bit ones: selection bitmaps of a 16 x 2^22
+    # family, DISTINCTCOUNT bitmaps, a PTDP block with an odd row count
+    pytest.param([((16, 1 << 19), "uint8")], id="selection-16x2^19"),
+    pytest.param([((16, 8), "int64"), ((16, 7, 11), "bool"),
+                  ((16, 8), "float64")], id="distinct-bitmap"),
+    pytest.param([((1_000_003,), "int64"), ((1_000_003,), "bool"),
+                  ((1_000_003,), "int16"), ((1_000_003,), "int8")],
+                 id="ptdp-odd-rows"),
+])
+def test_output_pack_compiles_fast(one_chip, outs):
     """Every dispatch ends in kernels._pack_flat (one D2H fetch per query).
-    Interleaving 64-bit words on device (a 64-bit bitcast, or a (.., 2)
-    stack flattened) cost the chip's compiler 35-134 s for these shapes —
-    longer than the broker's default timeout; the planar pack takes about
-    two. The bound is generous: it guards the cliff, not the seconds."""
-    outs = (_spec(one_chip, shape, jnp.int64),
-            _spec(one_chip, shape, jnp.float64),
-            _spec(one_chip, shape, jnp.uint32))
+    Interleaving words on device (a 64-bit bitcast, a (.., 2) or (.., 4)
+    minor dim flattened) cost the chip's compiler 18-145 s for these
+    shapes — the broker's default timeout is 60 s; the planar pack takes
+    about one. The bound is generous: it guards the cliff, not the
+    seconds."""
+    specs = tuple(_spec(one_chip, shape, jnp.dtype(dt)) for shape, dt in outs)
     t0 = time.perf_counter()
-    kernels._pack_flat.lower(outs).compile()
-    assert time.perf_counter() - t0 < 30
+    kernels._pack_flat.lower(specs).compile()
+    assert time.perf_counter() - t0 < 60
 
 
 def test_f64_bitcast_still_unimplemented(one_chip):

@@ -238,6 +238,9 @@ def test_ledger_records_warm_query_at_zero_cost(warm_cluster, monkeypatch):
 
     store, broker, _ = warm_cluster
     monkeypatch.delenv("PINOT_TPU_TRACE_SAMPLE", raising=False)
+    # the ledger is process-global: an alert another test file fired on
+    # this worker may have left exemplar sampling armed
+    PERF_LEDGER.disarm_exemplars()
     assert PERF_LEDGER.exemplar_armed is False
     sync = _CountingSync(monkeypatch)
     walks = {"n": 0}
