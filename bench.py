@@ -881,9 +881,8 @@ def run_single(cfg: str, outpath: str):
     except Exception:
         rw = None  # warm numbers are additive; never fail the config
 
-    # one traced run OUTSIDE the timed loop (tracing blocks on every
-    # family dispatch to split compile vs device-execute, so it must not
-    # pollute p50): per-phase attribution for the BENCH json
+    # one traced run OUTSIDE the timed loop (it takes the untraced path,
+    # but allocates spans): per-phase attribution for the BENCH json
     phases = None
     try:
         rt = tpu.execute_sql("SET trace = true; " + sql)
@@ -978,8 +977,9 @@ def run_single(cfg: str, outpath: str):
     if note:
         payload["note"] = note
     if phases is not None:
-        # compileMs/deviceExecMs/transferBytes sum the family_dispatch
-        # span attributes; hostCombineMs sums the SERVER_COMBINE +
+        # compileMs/transferBytes sum the family_dispatch span
+        # attributes; deviceWaitMs sums the DEVICE_FETCH spans (the host's
+        # wait for the device); hostCombineMs sums the SERVER_COMBINE +
         # BROKER_REDUCE spans (see pinot_tpu/spi/trace.py:phase_breakdown)
         payload["phases"] = phases
     stage_stats = getattr(r, "mse_stage_stats", None)

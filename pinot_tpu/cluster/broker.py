@@ -562,6 +562,19 @@ class Broker:
             cached = self.result_cache.get(ck)
             if cached is not None:
                 BROKER_METRICS.add_meter(BrokerMeter.RESULT_CACHE_HITS)
+                if query.query_options.get("trace") in (True, "true", 1):
+                    # a traced hit says so in a span; the cached copy is
+                    # shared between callers and stays plain
+                    import copy
+
+                    from ..spi.trace import Trace
+
+                    cached = copy.copy(cached)
+                    trace = Trace(uuid.uuid4().hex[:12])
+                    trace.record("RESULT_CACHE(hit)", t0,
+                                 time.perf_counter()).set_attribute(
+                                     "cache", "hit")
+                    cached.trace_info = trace.to_json()
                 cached.cache_outcome = "hit"
                 cached.time_used_ms = (time.perf_counter() - t0) * 1000
                 cached._log_table = query.table_name
@@ -605,10 +618,10 @@ class Broker:
                 and not resp.partial_result \
                 and resp.result_table is not None:
             BROKER_METRICS.add_meter(BrokerMeter.RESULT_CACHE_MISSES)
-            if getattr(resp, "trace_sampled", False) and resp.trace_info:
-                # a head-sampled query is cacheable (the CLIENT never asked
-                # for a trace) — but the cached copy must be plain, or the
-                # next client's hit replays a stale trace
+            if resp.trace_info:
+                # a traced or head-sampled query is cacheable — but the
+                # cached copy must be plain, or the next client's hit
+                # replays a stale trace
                 import copy
 
                 plain = copy.copy(resp)
@@ -624,7 +637,7 @@ class Broker:
                          t0: float) -> BrokerResponse:
         """EXPLAIN ANALYZE at the broker: consult the result cache first
         (a warm hit renders as a RESULT_CACHE node with zero dispatches),
-        otherwise scatter the real query with an analyze-flagged trace and
+        otherwise scatter the real query under a trace and
         render the merged cross-server span tree as the annotated plan."""
         import copy
 
@@ -647,9 +660,6 @@ class Broker:
         sub.explain = False
         sub.query_options = dict(query.query_options)
         sub.query_options["trace"] = True
-        # the analyze marker rides the query to every server so their
-        # traces keep the cache tiers live (spi/trace.py analyze flag)
-        sub.query_options["analyze"] = True
         budget = _QueryBudget(self._timeout_ms(query),
                               self._partial_allowed(query))
         try:
@@ -680,7 +690,7 @@ class Broker:
     def _result_cache_key(self, query: QueryContext,
                           only_segments: Optional[dict]) -> Optional[tuple]:
         """Cacheability decision tree (README "Result caching"): no explicit
-        segment restriction, no trace, no SET resultCache=false, no
+        segment restriction, no SET resultCache=false, no
         non-deterministic functions, and no REALTIME half (a consuming
         snapshot's rows advance without any lineage event). Returns the
         (query_fp, table, lineage epoch) key, or None → bypass."""
@@ -688,8 +698,6 @@ class Broker:
             return None
         opt = query.query_options.get("resultCache")
         if opt is not None and str(opt).lower() in ("false", "0", "off"):
-            return None
-        if query.query_options.get("trace") in (True, "true", 1):
             return None
         text = str(query).lower()
         if "now(" in text or "rand(" in text or "ago(" in text:
@@ -879,25 +887,22 @@ class Broker:
         # head-samples production queries deterministically on the queryId
         # hash — every server strips its ``:<n>`` shard suffix and makes
         # the SAME decision, so sampled queries trace end to end without
-        # any option riding the wire. Sampled traces arm analyze=True so
-        # the cache tiers stay live (a sampled query must behave exactly
-        # like its unsampled twin).
+        # any option riding the wire. A trace changes nothing of the run
+        # (a sampled query behaves exactly like its unsampled twin); it is
+        # named for the queryId that the broker and every shard share.
         from ..spi.trace import TRACING, sample_decision, trace_sample_rate
 
         trace = None
         sampled = False
         if TRACING.active_trace() is None:
             if query.query_options.get("trace") in (True, "true", 1):
-                trace = TRACING.start_trace(
-                    f"broker:{raw}",
-                    analyze=query.query_options.get("analyze") in
-                    (True, "true", 1))
+                trace = TRACING.start_trace(budget.query_id)
             elif force_trace or sample_decision(budget.query_id,
                                                 trace_sample_rate()):
                 # force_trace: sentinel exemplar pinning — sample this
                 # query regardless of the configured head-sampling rate
                 sampled = True
-                trace = TRACING.start_trace(f"broker:{raw}", analyze=True)
+                trace = TRACING.start_trace(budget.query_id)
         all_results = []
         stats_sum = {"total_docs": 0, "num_segments_processed": 0,
                      "num_segments_pruned": 0, "num_segments_queried": 0,
@@ -913,8 +918,8 @@ class Broker:
                      "partial_exceptions": []}
         try:
             try:
-                # BROKER_SCATTER is the exporter's flow anchor: shard
-                # timelines re-base here and scatter flows fan out from it
+                # BROKER_SCATTER is the exporter's flow anchor: scatter
+                # flows fan out from it
                 with TRACING.scope("BROKER_SCATTER"):
                     for name_with_type, extra_filter in halves:
                         sub = _with_filter(query, name_with_type, extra_filter)

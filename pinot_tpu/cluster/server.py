@@ -23,6 +23,8 @@ from ..segment.loader import SegmentIntegrityError, load_segment
 from ..spi import faults
 from ..spi.data_types import Schema
 from ..spi.metrics import SERVER_METRICS, ServerMeter, ServerTimer
+from ..spi.trace import (TRACING, ServerQueryPhase, sample_decision,
+                         trace_sample_rate)
 from ..storage.tier import SegmentTierManager
 from .controller import ERROR, ONLINE, raw_table_name
 from .store import PropertyStore
@@ -922,9 +924,40 @@ class ServerInstance:
         """Execute a QueryContext over an explicit segment list (the broker
         names segments per server, reference InstanceRequest.searchSegments)
         under the scheduler's admission control."""
+        query = request["query"]
+        query_id = request.get("queryId")
+        # trace option: the server owns a trace for its shard of the query
+        # (scheduler.submit runs the query on this thread, so the
+        # thread-local trace covers execute_segments and its family
+        # dispatches); the span list rides back next to the datatable for
+        # the broker to merge. The trace is named for the broker's queryId
+        # (each scatter RPC carries ``<query_id>:<n>``), which the broker
+        # and every shard share.
+        trace = None
+        root_qid = str(query_id).split(":", 1)[0] if query_id else ""
+        if TRACING.active_trace() is None and (
+                query.query_options.get("trace") in (True, "true", 1)
+                # flight-recorder head sampling: hashing the queryId PREFIX
+                # makes every shard reach the broker's own sample decision
+                # without an option riding the wire
+                or (root_qid and sample_decision(root_qid,
+                                                 trace_sample_rate()))):
+            trace = TRACING.start_trace(
+                root_qid or f"server:{self.instance_id}")
+        try:
+            # the server-side total: entry to the encoded blob in hand
+            with TRACING.scope(ServerQueryPhase.QUERY_PROCESSING):
+                out = self._process_query(request, query, query_id)
+        finally:
+            if trace is not None:
+                TRACING.end_trace()
+        if trace is not None:
+            out["trace"] = trace.to_json()
+        return out
+
+    def _process_query(self, request, query, query_id):
         table = request["table"]
         names = request["segments"]
-        query = request["query"]
         if faults.ACTIVE:
             faults.FAULTS.fire("server.query", table=table,
                                instance=self.instance_id)
@@ -933,7 +966,6 @@ class ServerInstance:
         # per-segment loop's timeoutMs (the request is unpickled fresh per
         # RPC, so mutating query_options here is private to this call)
         deadline_ms = request.get("deadlineMs")
-        query_id = request.get("queryId")
         t_enter = time.monotonic()
         # cold (metadata-only) routed segments warm BEFORE admission,
         # bounded by the remaining broker budget; un-warmable ones ride the
@@ -966,38 +998,10 @@ class ServerInstance:
         def run(tracker):
             return self.executor.execute_segments(query, segs, tracker=tracker)
 
-        # trace option: the server owns a trace for its shard of the query
-        # (scheduler.submit runs `run` on this thread, so the thread-local
-        # trace covers execute_segments and its family dispatches); the span
-        # list rides back next to the datatable for the broker to merge
-        from ..spi.trace import TRACING, sample_decision, trace_sample_rate
-
-        trace = None
-        if TRACING.active_trace() is None:
-            if query.query_options.get("trace") in (True, "true", 1):
-                # the analyze marker keeps cache tiers live under this trace
-                # (EXPLAIN ANALYZE must observe real cache behaviour)
-                trace = TRACING.start_trace(
-                    f"server:{self.instance_id}",
-                    analyze=query.query_options.get("analyze") in
-                    (True, "true", 1))
-            elif query_id:
-                # flight-recorder head sampling: hash the broker queryId
-                # PREFIX (each scatter RPC carries ``<query_id>:<n>``) so
-                # every shard reaches the broker's own sample decision
-                # without an option riding the wire; analyze=True keeps the
-                # cache tiers live — a sampled query must behave exactly
-                # like its unsampled twin
-                root_qid = str(query_id).split(":", 1)[0]
-                if sample_decision(root_qid, trace_sample_rate()):
-                    trace = TRACING.start_trace(
-                        f"server:{self.instance_id}", analyze=True)
         try:
             combined, stats = self.scheduler.submit(
                 run, group=table, timeout_s=timeout_s, query_id=query_id)
         finally:
-            if trace is not None:
-                TRACING.end_trace()
             self._tier.unpin(pins)
         stats["missing_segments"] = missing
         if still_cold:
@@ -1008,16 +1012,14 @@ class ServerInstance:
         # pickled Python objects (reference: DataTableImplV4 on the wire)
         from .datatable import encode
 
-        blob = encode(combined, stats)
+        with TRACING.scope(ServerQueryPhase.RESPONSE_SERIALIZATION):
+            blob = encode(combined, stats)
         if faults.ACTIVE:
             # the "datatable.encode" corrupt fault damages the encoded
             # payload — the broker's checksum must catch it downstream
             blob = faults.corrupt_at("datatable.encode", blob, table=table,
                                      instance=self.instance_id)
-        out = {"datatable": blob}
-        if trace is not None:
-            out["trace"] = trace.to_json()
-        return out
+        return {"datatable": blob}
 
     def _handle_scan_arrow(self, request):
         """Direct Arrow IPC segment read for external engines — straight

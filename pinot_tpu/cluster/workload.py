@@ -49,7 +49,7 @@ MAX_PATTERNS_PER_TABLE = 64
 # QueryCostReport key
 _SUM_FIELDS = (
     "queries", "failures", "rejected", "tracedQueries",
-    "timeMs", "deviceMs", "compileMs", "hostCombineMs",
+    "timeMs", "deviceWaitMs", "compileMs", "hostCombineMs",
     "transferBytes", "hbmBytesTouched", "shuffledBytes", "cacheHitBytes",
     "docsScanned", "deviceDispatches", "compiles",
     "segmentCacheHits", "segmentCacheMisses",
@@ -72,13 +72,13 @@ def build_cost_report(resp, table: str = "", client_id: str = "",
     """Fold one completed query's response (and its trace, when present)
     into a flat cost report. Every numeric key is decayed-summable."""
     trace_info = getattr(resp, "trace_info", None)
-    device_ms = compile_ms = combine_ms = 0.0
+    device_wait_ms = compile_ms = combine_ms = 0.0
     transfer = shuffled = hbm_touched = cache_hit_bytes = 0
     if trace_info:
         from ..spi.trace import phase_breakdown
 
         phases = phase_breakdown(trace_info)
-        device_ms = phases["deviceExecMs"]
+        device_wait_ms = phases["deviceWaitMs"]
         compile_ms = phases["compileMs"]
         combine_ms = phases["hostCombineMs"]
         transfer = phases["transferBytes"]
@@ -102,7 +102,9 @@ def build_cost_report(resp, table: str = "", client_id: str = "",
         "rejected": 1 if getattr(resp, "query_rejected", False) else 0,
         "tracedQueries": 1 if trace_info else 0,
         "timeMs": round(float(getattr(resp, "time_used_ms", 0.0) or 0.0), 3),
-        "deviceMs": device_ms,
+        # the host's wait for the device (DEVICE_FETCH spans): queueing
+        # behind other requests + execution + copy, not this query's device time
+        "deviceWaitMs": device_wait_ms,
         "compileMs": compile_ms,
         "hostCombineMs": combine_ms,
         "transferBytes": transfer,

@@ -14,12 +14,14 @@ import logging
 import os
 import threading
 import time
+from contextlib import contextmanager
 
 import jax
 import numpy as np
 import jax.numpy as jnp
 
-from ..ops.kernels import PackedOuts, pack_outputs, run_program, unpack_outputs
+from ..ops.kernels import (PackedOuts, fetch_tally, pack_outputs, run_program,
+                           unpack_outputs)
 from .aot_cache import AOT_READY, aot_call
 from ..query.context import QueryContext
 from ..segment.device_cache import (
@@ -31,7 +33,8 @@ from ..segment.device_cache import (
 )
 from ..segment.loader import ImmutableSegment
 from ..spi import faults
-from ..spi.trace import TRACING
+from ..spi.trace import DEVICE_FETCH, FAMILY_DISPATCH, GATHER_STACK, TRACING
+from .ir import program_label
 from .plan import SegmentPlan, SegmentPlanner
 from .results import (
     AggIntermediate,
@@ -192,6 +195,38 @@ def _attach_dispatch_stats(span, cache: DeviceSegmentCache) -> None:
     clear_transfer_stats()
 
 
+@contextmanager
+def device_fetch():
+    """The DEVICE_FETCH span around a blocking device→host fetch: the one
+    place a request waits for the device, traced or not. Its duration is
+    the wait — queueing behind other requests' programs, execution, the
+    output pack and the copy; `hostFetches` and `fetchBytes` count what
+    crossed inside it (this thread's own, kernels.fetch_tally)."""
+    with TRACING.scope(DEVICE_FETCH) as span:
+        if span is None:
+            yield
+            return
+        with fetch_tally() as tally:
+            try:
+                yield
+            finally:
+                span.set_attribute("hostFetches", tally[0])
+                span.set_attribute("fetchBytes", tally[1])
+
+
+def fetch_outputs(outs) -> list:
+    """A dispatch's outputs as host arrays. Arrays that are on the host
+    already (a batch family's fetched rows) pass through; anything else
+    blocks under DEVICE_FETCH."""
+    if isinstance(outs, PackedOuts):
+        with device_fetch():
+            return unpack_outputs(outs)
+    if all(isinstance(o, np.ndarray) for o in outs):
+        return list(outs)
+    with device_fetch():
+        return [np.asarray(o) for o in outs]
+
+
 class BatchFamilyMismatch(Exception):
     """A family grouped by the host-side key turned out to gather planes of
     unequal dtype/shape — the caller falls back to per-segment dispatch."""
@@ -309,18 +344,19 @@ class TpuSegmentExecutor:
         async device queueing instead of threads).
 
         When a trace is active, the dispatch runs under a family_dispatch
-        span with the compile/execute split (compile detected via the
-        compile-cache guard; execute measured around block_until_ready —
-        which costs the async overlap, so traced runs are NOT perf runs),
-        per-slot transfer bytes, and an HBM snapshot. Tracing off takes the
-        first branch: one thread-local read, no spans, no added syncs."""
+        span: gather + enqueue, with the compile time (detected via the
+        compile-cache guard, measured without a sync), the program's label,
+        per-slot transfer bytes and an HBM snapshot. The span adds no sync:
+        the wait for the device is the DEVICE_FETCH span, where the
+        untraced path waits too. Tracing off takes the first branch: one
+        thread-local read, no spans."""
         if faults.ACTIVE:
             # kind="hbm_oom" specs raise RESOURCE_EXHAUSTED here and are
             # absorbed by the caller's with_oom_retry — the real OOM path
             faults.FAULTS.fire("device.dispatch", segment=segment.name)
         if TRACING.active_trace() is None:
             return self._dispatch_plan(segment, plan, None)
-        with TRACING.scope("family_dispatch") as span:
+        with TRACING.scope(FAMILY_DISPATCH) as span:
             reset_transfer_stats()
             try:
                 span.set_attribute("segment", segment.name)
@@ -364,14 +400,17 @@ class TpuSegmentExecutor:
         gkey = (plan.program, view.padded, fused, lut_meta)
         new_compile = _GUARD.note(gkey)
         _count_dispatch(new_compile)
+        label = program_label(plan.program)
         if span is not None:
             span.set_attribute("mode", plan.program.mode)
+            span.set_attribute("program", label)
             span.set_attribute("padded", view.padded)
             if fused:
                 span.set_attribute("fused", fused)
-        if span is not None or new_compile:
+        if new_compile:
             t0 = time.perf_counter()
         nd = np.int32(segment.num_docs)
+        compile_ms = 0.0
         try:
             # AOT-prewarmed family (engine/aot_cache.py): the persisted
             # executable serves the dispatch — zero compiles in this
@@ -390,22 +429,13 @@ class TpuSegmentExecutor:
                 # dispatch, so host wall of run_program ≈ compile cost on
                 # a guard miss — measurable WITHOUT a sync, so the compile
                 # registry gets fed on untraced production dispatches too
-                t1 = time.perf_counter()
-                _register_compile(gkey, round((t1 - t0) * 1000, 3),
+                compile_ms = round((time.perf_counter() - t0) * 1000, 3)
+                _register_compile(gkey, compile_ms,
                                   plan.program, view.padded, fused, lut_meta,
                                   packed=packed,
                                   aot_example=(arrays, params, nd))
             else:
                 _register_dispatch(gkey)
-            if span is not None:
-                if not new_compile:
-                    t1 = time.perf_counter()
-                span.set_attribute(
-                    "compileMs",
-                    round((t1 - t0) * 1000, 3) if new_compile else 0.0)
-                jax.block_until_ready(outs)
-                span.set_attribute(
-                    "deviceExecMs", round((time.perf_counter() - t1) * 1000, 3))
             # the compiled fused kernel varies with lut_meta (run counts
             # are static), so validation is keyed per (program, meta)
             vkey = (plan.program, lut_meta)
@@ -432,12 +462,10 @@ class TpuSegmentExecutor:
                                packed=packed, fused="")
             if span is not None:
                 span.set_attribute("fusedFallback", True)
-                jax.block_until_ready(outs)
-                span.set_attribute(
-                    "deviceExecMs",
-                    round((time.perf_counter() - t0) * 1000, 3))
+        if span is not None:
+            span.set_attribute("compileMs", compile_ms)
         # one flat buffer per query → one D2H transfer at collect()
-        return pack_outputs(outs)
+        return pack_outputs(outs, label)
 
     def dispatch_plan_raw(self, segment: ImmutableSegment, plan: SegmentPlan):
         """dispatch_plan without the flat-buffer packing: returns the raw
@@ -450,7 +478,7 @@ class TpuSegmentExecutor:
             faults.FAULTS.fire("device.dispatch", segment=segment.name)
         if TRACING.active_trace() is None:
             return self._dispatch_plan_raw(segment, plan, None)
-        with TRACING.scope("family_dispatch") as span:
+        with TRACING.scope(FAMILY_DISPATCH) as span:
             reset_transfer_stats()
             try:
                 span.set_attribute("segment", segment.name)
@@ -468,33 +496,30 @@ class TpuSegmentExecutor:
         gkey = (plan.program, view.padded, "", ())
         new_compile = _GUARD.note(gkey)
         _count_dispatch(new_compile)
-        if span is None and not new_compile:
-            _register_dispatch(gkey)
-            return run_program(plan.program, arrays, params,
-                               np.int32(segment.num_docs), view.padded,
-                               packed=packed, fused=""), view
         if span is not None:
             span.set_attribute("mode", plan.program.mode)
+            span.set_attribute("program", program_label(plan.program))
             span.set_attribute("padded", view.padded)
-        t0 = time.perf_counter()
+        if new_compile:
+            t0 = time.perf_counter()
         outs = run_program(plan.program, arrays, params,
                            np.int32(segment.num_docs), view.padded,
                            packed=packed, fused="")
-        t1 = time.perf_counter()
-        compile_ms = round((t1 - t0) * 1000, 3) if new_compile else 0.0
+        compile_ms = 0.0
         if new_compile:
+            compile_ms = round((time.perf_counter() - t0) * 1000, 3)
             _register_compile(gkey, compile_ms, plan.program, view.padded)
         else:
             _register_dispatch(gkey)
-        if span is None:
-            return outs, view
-        span.set_attribute("compileMs", compile_ms)
-        jax.block_until_ready(outs)
-        span.set_attribute("deviceExecMs",
-                           round((time.perf_counter() - t1) * 1000, 3))
+        if span is not None:
+            span.set_attribute("compileMs", compile_ms)
         return outs, view
 
     def _gather_batch(self, segments: list, plans: list, ndev: int = 1):
+        with TRACING.scope(GATHER_STACK):
+            return self._gather_batch_inner(segments, plans, ndev)
+
+    def _gather_batch_inner(self, segments: list, plans: list, ndev: int):
         """Gather + stack a batch family's kernel inputs: per-member planes
         come from the per-segment HBM cache (gather_arrays_packed — upload
         happens at most once per plane), the [S, ...] stacks from the
@@ -579,7 +604,7 @@ class TpuSegmentExecutor:
         if TRACING.active_trace() is None:
             return self._dispatch_batch_inner(segments, plans, None,
                                               mesh=mesh, pack=pack)
-        with TRACING.scope("family_dispatch") as span:
+        with TRACING.scope(FAMILY_DISPATCH) as span:
             reset_transfer_stats()
             try:
                 span.set_attribute("numSegments", len(segments))
@@ -604,21 +629,23 @@ class TpuSegmentExecutor:
         gkey = ("batchmesh", ndev, plan0.program, views[0].padded, packed,
                 asig, len(segments))
         new_compile = _GUARD.note(gkey)
+        label = program_label(plan0.program)
         if span is not None:
             span.set_attribute("mode", plan0.program.mode)
+            span.set_attribute("program", label)
             span.set_attribute("padded", views[0].padded)
             span.set_attribute("meshDevices", ndev)
         t0 = time.perf_counter()
         outs = pmesh.run_program_batch_sharded(
             plan0.program, arrays, params_b, num_docs, views[0].padded,
             ndev, packed=packed)
-        t1 = time.perf_counter()
         # counted only after the sharded dispatch succeeded: a trace-time
         # failure falls back to the solo path, which counts itself — so
         # numDeviceDispatches stays exactly one per family either way
         _count_dispatch(new_compile)
-        compile_ms = round((t1 - t0) * 1000, 3) if new_compile else 0.0
+        compile_ms = 0.0
         if new_compile:
+            compile_ms = round((time.perf_counter() - t0) * 1000, 3)
             _register_compile(gkey, compile_ms, plan0.program,
                               views[0].padded, batch_size=len(segments),
                               mesh=(ndev,))
@@ -626,41 +653,26 @@ class TpuSegmentExecutor:
             _register_dispatch(gkey)
         if span is not None:
             span.set_attribute("compileMs", compile_ms)
-            stamps = pmesh.block_per_device(outs, ndev, t1)
-            span.set_attribute(
-                "deviceExecMs", stamps[-1][1] if stamps else 0.0)
-            for did, ms in stamps:
-                with TRACING.scope(f"mesh_device:{did}") as dspan:
-                    dspan.set_attribute("device", did)
-                    dspan.set_attribute("deviceExecMs", ms)
-        t2 = time.perf_counter()
+            # the record of which chips took part; when each ran is in the
+            # profiler's per-device planes, not in a host stamp
+            for d in pmesh.mesh_devices(ndev):
+                with TRACING.scope(f"mesh_device:{d.id}") as dspan:
+                    dspan.set_attribute("device", d.id)
         if pack:
             try:
                 # preferred: shuffle-inside-the-program — all_gather over
                 # the mesh axis + on-device pack, no dev0 funnel of raw outs
                 result = pmesh.pack_outputs_collective(
-                    outs, len(segments), ndev)
+                    outs, len(segments), ndev, label)
             except Exception as e:
                 from .oom import HbmExhaustedError
 
                 if isinstance(e, HbmExhaustedError):
                     raise
-                result = pmesh.pack_outputs_gathered(outs, len(segments))
-            sync_target = result.flat
+                result = pmesh.pack_outputs_gathered(outs, len(segments),
+                                                     label)
         else:
             result = pmesh.gather_outputs(outs, len(segments))
-            sync_target = result
-        if span is not None:
-            jax.block_until_ready(sync_target)
-            combine_ms = round((time.perf_counter() - t2) * 1000, 3)
-            span.set_attribute("crossChipCombineMs", combine_ms)
-            try:
-                from ..spi.metrics import SERVER_METRICS, ServerTimer
-
-                SERVER_METRICS.update_timer(
-                    ServerTimer.CROSS_CHIP_COMBINE_MS, combine_ms)
-            except Exception:
-                pass
         return result, views
 
     def _dispatch_batch_inner(self, segments: list, plans: list, span,
@@ -690,40 +702,29 @@ class TpuSegmentExecutor:
                 len(segments))
         new_compile = _GUARD.note(gkey)
         _count_dispatch(new_compile)
-        if span is None and not new_compile:
-            _register_dispatch(gkey)
-            outs = aot_call(gkey, arrays, params_b, num_docs) \
-                if AOT_READY else None
-            if outs is None:
-                outs = run_program_batch(plan0.program, arrays, params_b,
-                                         num_docs, views[0].padded,
-                                         packed=packed)
-            return outs, views
         if span is not None:
             span.set_attribute("mode", plan0.program.mode)
+            span.set_attribute("program", program_label(plan0.program))
             span.set_attribute("padded", views[0].padded)
-        t0 = time.perf_counter()
+        if new_compile:
+            t0 = time.perf_counter()
         outs = aot_call(gkey, arrays, params_b, num_docs) \
             if AOT_READY else None
         if outs is None:
             outs = run_program_batch(plan0.program, arrays, params_b,
                                      num_docs, views[0].padded,
                                      packed=packed)
-        t1 = time.perf_counter()
-        compile_ms = round((t1 - t0) * 1000, 3) if new_compile else 0.0
+        compile_ms = 0.0
         if new_compile:
+            compile_ms = round((time.perf_counter() - t0) * 1000, 3)
             _register_compile(gkey, compile_ms, plan0.program,
                               views[0].padded, batch_size=len(segments),
                               packed=packed,
                               aot_example=(arrays, params_b, num_docs))
         else:
             _register_dispatch(gkey)
-        if span is None:
-            return outs, views
-        span.set_attribute("compileMs", compile_ms)
-        jax.block_until_ready(outs)
-        span.set_attribute("deviceExecMs",
-                           round((time.perf_counter() - t1) * 1000, 3))
+        if span is not None:
+            span.set_attribute("compileMs", compile_ms)
         return outs, views
 
     def dispatch_plan_batch(self, segments: list, plans: list,
@@ -738,7 +739,8 @@ class TpuSegmentExecutor:
         with the flat committed to device 0 — still one launch, one D2H.
         Raises BatchFamilyMismatch to request the per-segment fallback."""
         outs, _ = self._dispatch_batch(segments, plans, mesh=mesh, pack=True)
-        return outs if isinstance(outs, PackedOuts) else pack_outputs(outs)
+        return outs if isinstance(outs, PackedOuts) \
+            else pack_outputs(outs, program_label(plans[0].program))
 
     def dispatch_plan_batch_raw(self, segments: list, plans: list,
                                 mesh: tuple = ()):
@@ -753,8 +755,7 @@ class TpuSegmentExecutor:
     def collect(self, query: QueryContext, segment: ImmutableSegment,
                 plan: SegmentPlan, outs):
         """Materialize device outputs (blocks) and decode the intermediate."""
-        outs = unpack_outputs(outs) if isinstance(outs, PackedOuts) \
-            else [np.asarray(o) for o in outs]
+        outs = fetch_outputs(outs)
         mode = plan.program.mode
         if mode == "selection":
             return self._selection_result(query, segment, plan, outs[0])

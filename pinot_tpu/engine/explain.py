@@ -192,8 +192,8 @@ _ANALYZE_ATTRS = ("segment", "numSegments", "segments", "device",
                   "fused", "workers", "leaf_pushdown", "rows_in", "rows_out",
                   "shuffled_rows", "shuffled_bytes", "join_impl",
                   "cross_stage_bytes", "device_partition_ms",
-                  "host_crossings", "compileMs",
-                  "deviceExecMs", "crossChipCombineMs", "transferBytes",
+                  "host_crossings", "program", "compileMs",
+                  "hostFetches", "fetchBytes", "transferBytes",
                   "cache")
 
 

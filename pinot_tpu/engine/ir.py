@@ -328,3 +328,130 @@ def sparse_groupby_path(p: Program) -> str:
     payloads = sum(1 for a in p.aggs
                    if a.kind in ("sum", "sumsq", "min", "max"))
     return "sparse-sort+gather" if payloads >= 2 else "sparse-sort"
+
+
+# ---------------------------------------------------------------------------
+# Program label: the stable device-side name of a query shape
+# ---------------------------------------------------------------------------
+
+_MODE_TAGS = {"selection": "sel", "aggregation": "agg", "group_by": "gby",
+              "group_by_sparse": "gbs"}
+# a label is part of an XLA module's name and of every profiler event of
+# the module: long enough to tell SSB's shapes apart, short enough to read
+_LABEL_MAX = 96
+
+
+def _value_tokens(node, out: list) -> None:
+    if isinstance(node, Col):
+        out.append(f"c{node.slot}")
+    elif isinstance(node, IdsCol):
+        out.append(f"i{node.slot}")
+    elif isinstance(node, DictGather):
+        out.append(f"d{node.ids_slot}")
+    elif isinstance(node, ConstParam):
+        out.append("p")
+    elif isinstance(node, ParamGather):
+        out.append("pg")
+        _value_tokens(node.ids, out)
+    elif isinstance(node, Bin):
+        out.append(node.op)
+        _value_tokens(node.a, out)
+        _value_tokens(node.b, out)
+    elif isinstance(node, Un):
+        out.append(node.op)
+        _value_tokens(node.a, out)
+    elif isinstance(node, Cast):
+        out.append("as" + node.to.lower())
+        _value_tokens(node.a, out)
+    elif isinstance(node, Where):
+        out.append("if")
+        _value_tokens(node.cond, out)
+        _value_tokens(node.a, out)
+        _value_tokens(node.b, out)
+    elif isinstance(node, NullCol):
+        out.append(f"n{node.null_slot}")
+    elif isinstance(node, FilterVal):
+        out.append("fv")
+        _filter_tokens(node.filter, out)
+    elif isinstance(node, MvLutReduce):
+        out.append(f"mv{node.op}{node.ids_slot}")
+    else:
+        out.append("x")
+
+
+def _filter_tokens(node, out: list) -> None:
+    if isinstance(node, FConst):
+        out.append("true" if node.value else "false")
+    elif isinstance(node, Interval):
+        lo = None if node.lo_param is None else \
+            ("ge" if node.lo_inclusive else "gt")
+        hi = None if node.hi_param is None else \
+            ("le" if node.hi_inclusive else "lt")
+        out.append("rng" if lo and hi else (lo or hi or "any"))
+        _value_tokens(node.vexpr, out)
+    elif isinstance(node, Lut):
+        out.append(f"lut{node.ids_slot}")
+    elif isinstance(node, Isin):
+        out.append("in")
+        _value_tokens(node.vexpr, out)
+    elif isinstance(node, Null):
+        out.append(f"null{node.null_slot}")
+    elif isinstance(node, MaskParam):
+        out.append("mask")
+    elif isinstance(node, (FAnd, FOr)):
+        out.append(("and" if isinstance(node, FAnd) else "or")
+                   + str(len(node.children)))
+        for c in node.children:
+            _filter_tokens(c, out)
+    elif isinstance(node, FNot):
+        out.append("not")
+        _filter_tokens(node.child, out)
+    else:
+        out.append("x")
+
+
+_LABELS: dict = {}
+
+
+def program_label(p: Program) -> str:
+    """The name a Program's executables and profiler events carry on the
+    device: its mode, the filter tree's operators in prefix order, the
+    group keys and the aggregation functions, each with the SLOT it reads
+    (`i0` a dict-id plane, `c2` a raw plane, `d1` a dictionary gather).
+    Made from the Program alone and from nothing that varies between two
+    runs of one SQL shape — no literal (they live in `params`), no
+    cardinality, no hash — so two PRs' traces can be laid side by side.
+    Two shapes may share a label (a label is cut at 96 characters; the
+    executables stay one per compiled family either way).
+
+        SELECT SUM(a * b) WHERE x = 1 AND y BETWEEN 2 AND 3 AND z < 4
+        -> agg_and3_rng_i0_rng_i1_lt_c2_sum_mul_c3_d1
+    """
+    label = _LABELS.get(p)
+    if label is not None:
+        return label
+    out = [_MODE_TAGS.get(p.mode, "prg")]
+    if p.filter is not None:
+        _filter_tokens(p.filter, out)
+    if p.group_vexprs:
+        out.append("by")
+        for v in p.group_vexprs:
+            _value_tokens(v, out)
+    elif p.group_slots:
+        out.append("by" + "x".join(str(s) for s in p.group_slots))
+    if p.mv_group_slot is not None:
+        out.append(f"mv{p.mv_group_slot}")
+    if p.keys_presorted:
+        out.append("presorted")
+    for a in p.aggs:
+        out.append(a.kind.replace("_", ""))
+        if a.vexpr is not None:
+            _value_tokens(a.vexpr, out)
+        elif a.ids_slot is not None:
+            out.append(f"i{a.ids_slot}")
+    label = "_".join(out)
+    if len(label) > _LABEL_MAX:
+        label = label[:_LABEL_MAX - 4].rstrip("_") + "_etc"
+    if len(_LABELS) < 4096:  # same bound as the compile-cache guard
+        _LABELS[p] = label
+    return label

@@ -29,6 +29,7 @@ from .combine import (combine_aggregation, combine_group_by,
                       combine_selection, trim_group_by)
 from ..ops.kernels import PackedOuts, fetch_packed_batch, unpack_outputs
 from .executor import (BatchFamilyMismatch, TpuSegmentExecutor,
+                       device_fetch, fetch_outputs,
                        batch_family_key, dispatch_counters,
                        reset_dispatch_counters)
 from .host_executor import HostSegmentExecutor
@@ -250,9 +251,10 @@ class QueryExecutor:
 
     def _execute_analyze(self, query: QueryContext,
                          tracker=None) -> BrokerResponse:
-        """EXPLAIN ANALYZE: run the query for real with an analyze-flagged
-        trace (caches stay live) and return the span tree rendered as the
-        annotated plan table, counters carried over from the actual run."""
+        """EXPLAIN ANALYZE: run the query for real under a trace (which
+        changes nothing of the run: caches stay live) and return the span
+        tree rendered as the annotated plan table, counters carried over
+        from the actual run."""
         import copy
 
         from .explain import analyze_table
@@ -263,8 +265,7 @@ class QueryExecutor:
         sub.query_options["trace"] = True
         owns = TRACING.active_trace() is None
         if owns:
-            trace = TRACING.start_trace(
-                f"analyze:{query.table_name}", analyze=True)
+            trace = TRACING.start_trace(f"analyze:{query.table_name}")
         else:
             trace = TRACING.active_trace()
         try:
@@ -288,7 +289,7 @@ class QueryExecutor:
         materialization and the broker's row→column round trip. Returns
         (source-column arrays, stats) or None when the shape or backend
         doesn't qualify — the caller falls back to the row path, which owns
-        ordering, deadlines, tracing and null handling."""
+        ordering, deadlines and null handling."""
         import numpy as np
 
         if self.backend == "host":
@@ -297,8 +298,7 @@ class QueryExecutor:
                 or query.group_by_expressions or query.order_by_expressions
                 or query.having_filter is not None or query.offset
                 or query.null_handling
-                or query.query_options.get("timeoutMs") is not None
-                or query.query_options.get("trace") in (True, "true", 1)):
+                or query.query_options.get("timeoutMs") is not None):
             return None
         if not query.select_expressions or not all(
                 e.is_identifier and e.identifier != "*"
@@ -336,8 +336,7 @@ class QueryExecutor:
             for seg, outs in pending:
                 if remaining <= 0:
                     break
-                mats = unpack_outputs(outs) if isinstance(outs, PackedOuts) \
-                    else [np.asarray(o) for o in outs]
+                mats = fetch_outputs(outs)
                 bits = unpack_bitmap(np.asarray(mats[0]), seg.num_docs)
                 doc_ids = np.nonzero(bits)[0]
                 if len(doc_ids) > remaining:
@@ -384,9 +383,23 @@ class QueryExecutor:
         # QueryOptimizer runs once at the broker; here once per query on the
         # server path so every engine entry benefits). Idempotent, so a
         # re-dispatched QueryContext is safe to re-optimize.
-        from ..query.optimizer import optimize_filter
+        from contextlib import ExitStack
 
         t_start = time.perf_counter()
+        # BUILD_QUERY_PLAN: canonicalize, prune, route, plan per segment,
+        # cache lookups, family grouping. _run_segments closes the span
+        # (``planned``) where the first dispatch follows; an error closes
+        # it here.
+        with ExitStack() as plan_scope:
+            plan_scope.enter_context(
+                TRACING.scope(ServerQueryPhase.BUILD_QUERY_PLAN))
+            return self._execute_segments(query, segments, tracker, t_start,
+                                          plan_scope.close)
+
+    def _execute_segments(self, query: QueryContext, segments: list,
+                          tracker, t_start: float, planned):
+        from ..query.optimizer import optimize_filter
+
         query.filter = optimize_filter(query.filter)
         # per-query dispatch/compile counters (engine/executor.py): every
         # device dispatch for this query happens on this thread
@@ -410,7 +423,8 @@ class QueryExecutor:
             deadline = time.perf_counter() + float(timeout_ms) / 1000
         cstats = {"hit": 0, "miss": 0}
         intermediates = self._run_segments(query, kept, tracker, deadline,
-                                           timeout_ms, cstats)
+                                           timeout_ms, cstats, planned)
+        planned()  # no segment left to run: nothing closed it yet
         with TRACING.scope(ServerQueryPhase.SERVER_COMBINE):
             combined = self._combine(query, intermediates)
         num_dispatches, num_compiles = dispatch_counters()
@@ -448,7 +462,8 @@ class QueryExecutor:
         }
 
     def _run_segments(self, query: QueryContext, kept: list, tracker,
-                      deadline, timeout_ms, cstats=None) -> list:
+                      deadline, timeout_ms, cstats=None,
+                      planned=lambda: None) -> list:
         """Two-phase multi-segment execution: dispatch every device kernel
         first (async — the device queue fills and runs back-to-back), run
         host-fallback segments while the device works, then collect. This
@@ -466,7 +481,7 @@ class QueryExecutor:
 
         if len(kept) > 1 and self.backend != "host":
             merged = self._try_sparse_device_combine(query, kept, tracker,
-                                                     check, cstats)
+                                                     check, cstats, planned)
             if merged is not None:
                 return merged
 
@@ -497,14 +512,9 @@ class QueryExecutor:
         # segment partial-result cache (cache/partial.py): a hit fills the
         # intermediate directly and the segment never reaches dispatch; a
         # miss is remembered so the collected result is inserted below.
-        # Traced runs bypass — the dispatch spans ARE the observability
-        # product and must describe real device work. EXPLAIN ANALYZE is
-        # the exception: it must report the cache behaviour of a real run.
+        # A traced run looks the cache up like any other and says so in a
+        # SEGMENT_CACHE(hit) span.
         cache_on = device_entries and self._segment_cache_enabled(query)
-        if cache_on and TRACING.active_trace() is not None \
-                and not TRACING.analyze_active():
-            with TRACING.scope("SEGMENT_CACHE(bypass:trace)"):
-                cache_on = False
         cache_inserts: list = []  # (idx, cache key, segment name)
         if cache_on:
             from ..cache.partial import GLOBAL_PARTIAL_CACHE
@@ -526,6 +536,12 @@ class QueryExecutor:
                     cache_inserts.append(
                         (idx, key, getattr(run_segment, "name", "?")))
                 uncached.append(e)
+            hits = len(device_entries) - len(uncached)
+            if hits:
+                with TRACING.scope("SEGMENT_CACHE(hit)") as sp:
+                    if sp is not None:
+                        sp.set_attribute("segments", hits)
+                        sp.set_attribute("cache", "hit")
             device_entries = uncached
 
         # stacked segment batching: one vmapped dispatch per batch FAMILY
@@ -537,17 +553,17 @@ class QueryExecutor:
         fam_hosts: dict = {}    # fkey → HOST arrays from a coalesced group
         msig = self._mesh_sig(query)
         # cross-query coalescing (engine/coalesce.py): only armed when the
-        # opt-in hold window is set AND the family has repeat traffic;
-        # traced queries never coalesce (their spans must describe their
-        # own device work)
+        # opt-in hold window is set AND the family has repeat traffic
         from .coalesce import coalesce_enabled
         from ..realtime.device_plane import (RealtimeUploadError,
                                              note_realtime_device_query)
 
-        co_on = coalesce_enabled(query) and TRACING.active_trace() is None
+        co_on = coalesce_enabled(query)
         rt_device = False  # any consuming segment answered on device
-        for fkey, positions in self._batch_families(
-                query, [(e[2], e[4]) for e in device_entries], mesh=msig):
+        families = self._batch_families(
+            query, [(e[2], e[4]) for e in device_entries], mesh=msig)
+        planned()
+        for fkey, positions in families:
             entries = [device_entries[p] for p in positions]
             if fkey is not None and len(entries) > 1:
                 segs_f = [e[2] for e in entries]
@@ -564,7 +580,8 @@ class QueryExecutor:
                             lambda: self.tpu.dispatch_plan_batch(
                                 segs_all, plans_all, mesh=_m),
                             keep_segment=_keep, cache=self.tpu.cache)
-                        return fetch_packed_batch([pack])[0]
+                        with device_fetch():
+                            return fetch_packed_batch([pack])[0]
 
                     co = self.coalescer.offer(query.table_name, fkey,
                                               segs_f, plans_f, msig,
@@ -684,11 +701,14 @@ class QueryExecutor:
 
             if solo or fam_keys:
                 try:
-                    fetched = with_oom_retry(
-                        lambda: fetch_packed_batch(
-                            [p[5] for p in solo]
-                            + [fam_packs[k] for k in fam_keys]),
-                        cache=self.tpu.cache, retry_fn=_refetch)
+                    # where the untraced path waits for the device: queue
+                    # behind other requests, execution, pack, copy
+                    with device_fetch():
+                        fetched = with_oom_retry(
+                            lambda: fetch_packed_batch(
+                                [p[5] for p in solo]
+                                + [fam_packs[k] for k in fam_keys]),
+                            cache=self.tpu.cache, retry_fn=_refetch)
                 except RealtimeUploadError:
                     # double fault: OOM relief dropped the realtime planes
                     # mid-query and the re-dispatch's re-upload faulted too.
@@ -874,7 +894,8 @@ class QueryExecutor:
                              "min": "min", "max": "max"}
 
     def _try_sparse_device_combine(self, query: QueryContext, kept, tracker,
-                                   check, cstats=None):
+                                   check, cstats=None,
+                                   planned=lambda: None):
         """Server-level merge ON DEVICE for multi-segment single-key sparse
         group-bys: dispatch every segment's kernel, translate each key
         column to dictionary VALUE space on device (dictionaries are
@@ -934,8 +955,7 @@ class QueryExecutor:
         # per-segment value-space tables kept DEVICE-resident against the
         # HBM budget, so partial overlap still skips member dispatches and
         # feeds the device combine directly.
-        cache_on = self._segment_cache_enabled(query) \
-            and (TRACING.active_trace() is None or TRACING.analyze_active())
+        cache_on = self._segment_cache_enabled(query)
         keys = None
         merged_key = None
         if cache_on:
@@ -974,8 +994,10 @@ class QueryExecutor:
                         if tab is not None:
                             cached_tabs[i] = tab
             msig = self._mesh_sig(query)
-            for fkey, positions in self._batch_families(
-                    query, list(zip(segs, plans)), mesh=msig):
+            families = self._batch_families(
+                query, list(zip(segs, plans)), mesh=msig)
+            planned()
+            for fkey, positions in families:
                 positions = [i for i in positions if i not in cached_tabs]
                 if not positions:
                     continue
@@ -1033,7 +1055,9 @@ class QueryExecutor:
                 tuple(seg_keys), tuple(seg_counts), tuple(seg_states),
                 kinds)
             # one flat D2H transfer for the whole query
-            outs_np = unpack_outputs(kernels.pack_outputs(merged))
+            pack = kernels.pack_outputs(merged)
+            with device_fetch():
+                outs_np = unpack_outputs(pack)
         except TimeoutError:
             raise
         except Exception as e:

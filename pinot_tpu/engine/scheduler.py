@@ -23,6 +23,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Optional
 
 from ..spi.metrics import SERVER_METRICS, ServerMeter, ServerTimer
+from ..spi.trace import TRACING, ServerQueryPhase
 
 
 class QueryKilledError(Exception):
@@ -204,9 +205,10 @@ class QueryScheduler:
             self._pending += 1
         t0 = time.perf_counter()
         try:
-            if not self._sem.acquire(timeout=timeout_s):
-                SERVER_METRICS.add_meter(ServerMeter.QUERIES_REJECTED)
-                raise QueryRejectedError("scheduler wait timeout")
+            with TRACING.scope(ServerQueryPhase.SCHEDULER_WAIT):
+                if not self._sem.acquire(timeout=timeout_s):
+                    SERVER_METRICS.add_meter(ServerMeter.QUERIES_REJECTED)
+                    raise QueryRejectedError("scheduler wait timeout")
         finally:
             with self._lock:
                 self._pending -= 1
@@ -242,7 +244,7 @@ class PriorityQueryScheduler(QueryScheduler):
                **kwargs):
         deadline = time.monotonic() + timeout_s
         t_wait = time.perf_counter()
-        with self._cv:
+        with TRACING.scope(ServerQueryPhase.SCHEDULER_WAIT), self._cv:
             if self._pending >= self.max_pending:
                 SERVER_METRICS.add_meter(ServerMeter.QUERIES_REJECTED)
                 raise QueryRejectedError("scheduler queue full")

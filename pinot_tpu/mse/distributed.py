@@ -494,9 +494,7 @@ class MseWorkerService:
         if opts.get("trace") not in (True, "true", 1) \
                 or TRACING.active_trace() is not None:
             return self._run_stage_inner(request)
-        trace = TRACING.start_trace(
-            f"mse:{self.server.instance_id}",
-            analyze=opts.get("analyze") in (True, "true", 1))
+        trace = TRACING.start_trace(str(request.get("query_id")))
         try:
             stats = self._run_stage_inner(request)
             stats["trace"] = trace.to_json()
@@ -1014,7 +1012,7 @@ class DistributedMseDispatcher:
         from ..cluster.transport import RpcClient
 
         # EXPLAIN ANALYZE (or an explicit trace option) arms a dispatcher
-        # trace; workers see trace/analyze in their options and ship spans
+        # trace; workers see trace in their options and ship spans
         # back for the merge in the gather loop. Armed here — after worker
         # placement, which can raise — so the finally below always unwinds
         # the thread-local.
@@ -1022,15 +1020,13 @@ class DistributedMseDispatcher:
         own_trace = False
         if (analyze or (query.options or {}).get("trace") in
                 (True, "true", 1)) and TRACING.active_trace() is None:
-            trace = TRACING.start_trace(f"mse:{query_id}", analyze=analyze)
+            trace = TRACING.start_trace(str(query_id))
             own_trace = True
         else:
             trace = TRACING.active_trace()
         if trace is not None:
             query.options = dict(query.options or {})
             query.options["trace"] = True
-            if getattr(trace, "analyze", False):
-                query.options["analyze"] = True
 
         stats_agg = {"num_docs_scanned": 0, "total_docs": 0,
                      "leaf_ssqe_pushdowns": 0, "stages": len(stages),

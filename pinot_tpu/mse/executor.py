@@ -128,13 +128,12 @@ class MultistageExecutor:
             query = parse_relational(sql)
             # the MSE entry owns the span tree: stage spans (runtime.py)
             # and nested leaf-engine dispatch spans all join this trace.
-            # EXPLAIN ANALYZE arms it unconditionally (analyze-flagged so
-            # cache layers stay live) — the annotated plan IS the trace.
+            # EXPLAIN ANALYZE arms it unconditionally — the annotated plan
+            # IS the trace (a trace changes nothing of the run).
             analyze = query.explain == "analyze"
             if (analyze or query.options.get("trace") in (True, "true", 1)) \
                     and TRACING.active_trace() is None:
-                trace = TRACING.start_trace(f"mse:{id(query):x}",
-                                            analyze=analyze)
+                trace = TRACING.start_trace(f"mse:{id(query):x}")
             planner = LogicalPlanner(query, self._catalog(),
                                      partition_catalog=self._partition_catalog)
             plan = planner.plan()

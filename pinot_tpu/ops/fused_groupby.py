@@ -345,6 +345,7 @@ def _fused_limb_sums(fp: FusedPlan, planes_in, scalars, num_segments: int,
         out_shape=jax.ShapeDtypeStruct(
             (nsb, num_planes * s1, mxu_groupby.LANES), jnp.int32),
         interpret=interpret,
+        name="fused_filter_groupby",
     )(scalars, *planes2)
     total = out.astype(jnp.int64).sum(axis=0)
     return total.reshape(num_planes, s1 * mxu_groupby.LANES)[:, :num_segments]

@@ -21,7 +21,9 @@ Design notes (SURVEY.md §7):
 
 from __future__ import annotations
 
-from functools import partial
+import threading
+from contextlib import contextmanager
+from functools import partial, wraps
 
 import jax
 import jax.numpy as jnp
@@ -176,9 +178,62 @@ def _apply_packed(arrays: tuple, packed: tuple) -> tuple:
     if not packed:
         return arrays
     out = list(arrays)
-    for slot, _width in packed:
-        out[slot] = out[slot].astype(jnp.int32)
+    with jax.named_scope("widen"):
+        for slot, _width in packed:
+            out[slot] = out[slot].astype(jnp.int32)
     return tuple(out)
+
+
+# ---------------------------------------------------------------------------
+# Stable names on the device
+# ---------------------------------------------------------------------------
+
+_NAMED_JITS: dict = {}
+
+
+def jit_named(fn, name: str, **jit_kwargs):
+    """`jax.jit(fn)` under the name ``name``: the XLA module, and with it
+    every event of the program in a profiler trace, reads `jit_<name>`
+    (XLA appends its own fingerprint). One jitted callable per (fn, name);
+    its executables are keyed by the static arguments as ever, so a
+    compiled family stays one executable."""
+    key = (fn, name)
+    jitted = _NAMED_JITS.get(key)
+    if jitted is None:
+        if len(_NAMED_JITS) >= 4096:  # the compile-cache guard's bound
+            _NAMED_JITS.clear()
+
+        @wraps(fn)  # keeps the signature static_argnames resolve against
+        def named(*args, **kwargs):
+            return fn(*args, **kwargs)
+
+        named.__name__ = named.__qualname__ = name
+        jitted = _NAMED_JITS.setdefault(key, jax.jit(named, **jit_kwargs))
+    return jitted
+
+
+class ProgramJit:
+    """A jitted callable of (program, ...) whose XLA module is named for
+    the program: `jit_<prefix>_<ir.program_label(program)>` where a plain
+    `jax.jit` would name every query shape after the one Python function.
+    Calls and `.lower()` go to the label's own `jax.jit`."""
+
+    def __init__(self, fn, prefix: str, static_argnames: tuple):
+        self._fn, self._prefix = fn, prefix
+        self._static = static_argnames
+        self.__name__ = fn.__name__
+        self.__doc__ = fn.__doc__
+
+    def _jit(self, program):
+        return jit_named(self._fn,
+                         f"{self._prefix}_{ir.program_label(program)}",
+                         static_argnames=self._static)
+
+    def __call__(self, program, *args, **kwargs):
+        return self._jit(program)(program, *args, **kwargs)
+
+    def lower(self, program, *args, **kwargs):
+        return self._jit(program).lower(program, *args, **kwargs)
 
 
 class PackedOuts:
@@ -288,26 +343,36 @@ def _word_planes(o) -> list:
     return [w]
 
 
-@jax.jit
-def _pack_flat(outs: tuple):
+def _pack_flat_impl(outs: tuple):
     """Concatenate the outputs into one flat uint32 device buffer, PLANAR:
     each output contributes its word planes back to back (all low words,
     then all high words, ...). Interleaving words on device — the
     value-major byte stream — is what the chip's compiler cannot do in
     usable time (`_word_planes`), so the host transposes instead."""
-    chunks = [w for o in outs for w in _word_planes(o)]
-    return jnp.concatenate(chunks) if len(chunks) > 1 else chunks[0]
+    with jax.named_scope("pack"):
+        chunks = [w for o in outs for w in _word_planes(o)]
+        return jnp.concatenate(chunks) if len(chunks) > 1 else chunks[0]
 
 
-def pack_outputs(outs: tuple) -> PackedOuts:
+_pack_flat = jit_named(_pack_flat_impl, "_pack_flat")
+
+
+def pack_outputs(outs: tuple, label: str = "") -> PackedOuts:
+    """``label`` (ir.program_label of the program that made ``outs``) names
+    the pack's XLA module `jit_pack_<label>`; without one it is
+    `jit__pack_flat`."""
     metas = [(np.dtype(str(o.dtype)), tuple(o.shape)) for o in outs]
-    return PackedOuts(_pack_flat(outs), metas)
+    pack = jit_named(_pack_flat_impl, f"pack_{label}") if label \
+        else _pack_flat
+    return PackedOuts(pack(outs), metas)
 
 
 # lifetime count of device→host materializations at the packed-output
 # fetch sites (single-stage packed outputs + the MSE fused-join group
 # table); the perf guards pin a warm query to exactly ONE per dispatch
 _HOST_FETCHES = [0]
+# a traced request's own fetches and bytes (engine/executor.device_fetch)
+_FETCH_TLS = threading.local()
 
 
 def host_fetches() -> int:
@@ -315,15 +380,30 @@ def host_fetches() -> int:
     return _HOST_FETCHES[0]
 
 
-def count_host_fetch() -> None:
+def count_host_fetch(nbytes: int = 0) -> None:
     """Record one deliberate device→host crossing. Every fetch site in the
     engine calls this right before its np.asarray so the structure guards
     can pin 'exactly one crossing per stage' without monkeypatching jax."""
     _HOST_FETCHES[0] += 1
+    tally = getattr(_FETCH_TLS, "tally", None)
+    if tally is not None:
+        tally[0] += 1
+        tally[1] += nbytes
+
+
+@contextmanager
+def fetch_tally():
+    """[fetches, bytes] of the crossings this thread counts inside."""
+    outer = getattr(_FETCH_TLS, "tally", None)
+    tally = _FETCH_TLS.tally = [0, 0]
+    try:
+        yield tally
+    finally:
+        _FETCH_TLS.tally = outer
 
 
 def unpack_outputs(p: PackedOuts) -> list:
-    count_host_fetch()
+    count_host_fetch(4 * int(p.flat.shape[0]))
     flat = np.asarray(p.flat)  # the query's single device→host transfer
     return _split_flat(flat.view(np.uint8), p.metas)
 
@@ -398,7 +478,7 @@ def fetch_packed_batch(packs: list) -> list:
             for i in idxs:
                 out[i] = unpack_outputs(packs[i])
             continue
-        _HOST_FETCHES[0] += 1
+        count_host_fetch(nbytes * len(idxs))
         flat = np.asarray(_concat_flats(
             tuple(packs[i].flat for i in idxs))).view(np.uint8)
         for j, i in enumerate(idxs):
@@ -407,11 +487,9 @@ def fetch_packed_batch(packs: list) -> list:
     return out
 
 
-@partial(jax.jit, static_argnames=("program", "padded", "packed", "fused",
-                                   "fused_lut_meta"))
-def run_program(program: ir.Program, arrays: tuple, params: tuple, num_docs, padded: int,
-                row_offset=0, packed: tuple = (), fused: str = "",
-                fused_lut_meta: tuple = ()):
+def _run_program(program: ir.Program, arrays: tuple, params: tuple, num_docs, padded: int,
+                 row_offset=0, packed: tuple = (), fused: str = "",
+                 fused_lut_meta: tuple = ()):
     """Execute a Program over padded column planes. Returns a tuple:
 
     selection   → (mask bitmap, packed little-endian)
@@ -432,16 +510,24 @@ def run_program(program: ir.Program, arrays: tuple, params: tuple, num_docs, pad
 
         fp = fused_groupby.plan(program, arrays, fused_lut_meta)
         if fp is not None:
-            return fused_groupby.execute(
-                fp, program, arrays, params, num_docs, padded, row_offset,
-                interpret=(fused == "interpret"))
+            # filter and group-by in ONE Pallas kernel (its own `name=`)
+            with jax.named_scope("group_by_dense"):
+                return fused_groupby.execute(
+                    fp, program, arrays, params, num_docs, padded,
+                    row_offset, interpret=(fused == "interpret"))
     arrays = _apply_packed(arrays, packed)
     return _run_program_impl(program, arrays, params, num_docs, padded, row_offset)
 
 
-@partial(jax.jit, static_argnames=("program", "padded", "packed"))
-def run_program_batch(program: ir.Program, arrays: tuple, params: tuple,
-                      num_docs, padded: int, packed: tuple = ()):
+# the XLA module of a program is `jit_scan_<label>`, solo, batched or
+# sharded (parallel/mesh.py) alike
+run_program = ProgramJit(
+    _run_program, "scan",
+    ("program", "padded", "packed", "fused", "fused_lut_meta"))
+
+
+def _run_program_batch(program: ir.Program, arrays: tuple, params: tuple,
+                       num_docs, padded: int, packed: tuple = ()):
     """Execute one Program over a stacked FAMILY of segments in a single
     dispatch: every plane in `arrays` and every param in `params` carries a
     leading batch dim [S, ...] (one row per member segment) and `num_docs`
@@ -463,14 +549,19 @@ def run_program_batch(program: ir.Program, arrays: tuple, params: tuple,
     return jax.vmap(one)(arrays, params, num_docs)
 
 
+run_program_batch = ProgramJit(_run_program_batch, "scan",
+                               ("program", "padded", "packed"))
+
+
 def _run_program_impl(program: ir.Program, arrays: tuple, params: tuple, num_docs, padded: int,
                       row_offset=0):
     n = padded
-    valid = (jnp.arange(n, dtype=jnp.int32) + row_offset) < num_docs
-    if program.filter is not None:
-        mask = valid & _eval_filter(program.filter, arrays, params, n)
-    else:
-        mask = valid
+    # the scopes name the program's parts in a profiler trace (an XLA
+    # fusion is filed under the scope of its root instruction)
+    with jax.named_scope("filter"):
+        mask = (jnp.arange(n, dtype=jnp.int32) + row_offset) < num_docs
+        if program.filter is not None:
+            mask &= _eval_filter(program.filter, arrays, params, n)
 
     if program.mode == "selection":
         # ship the mask as a BITMAP (n/8 uint8), not one byte per row: a
@@ -499,21 +590,26 @@ def _run_program_impl(program: ir.Program, arrays: tuple, params: tuple, num_doc
         mask = (mask[:, None] & (mv != program.mv_group_card)).reshape(-1)
         n = n * width
         if program.mode == "group_by_sparse":
-            outs = _run_sparse_group_by(program, arrays, params, mask, n)
+            with jax.named_scope("group_by_sparse"):
+                outs = _run_sparse_group_by(program, arrays, params, mask, n)
         else:
-            outs = _dense_group_by_entry(program, arrays, params, mask, n)
+            with jax.named_scope("group_by_dense"):
+                outs = _dense_group_by_entry(program, arrays, params, mask, n)
         return outs + (scanned_docs,)
 
     if program.mode == "group_by_sparse":
-        return _run_sparse_group_by(program, arrays, params, mask, n)
+        with jax.named_scope("group_by_sparse"):
+            return _run_sparse_group_by(program, arrays, params, mask, n)
 
     if program.mode != "group_by":
         # un-grouped aggregation: NO scatter at all — plain masked
         # reductions shaped (value, trash) to keep the output contract.
         # Scatters to a 2-slot table were pure overhead (and 64-bit
         # scatters are emulated on TPU)
-        return _run_ungrouped(program, arrays, params, mask, n)
-    return _dense_group_by_entry(program, arrays, params, mask, n)
+        with jax.named_scope("aggregate"):
+            return _run_ungrouped(program, arrays, params, mask, n)
+    with jax.named_scope("group_by_dense"):
+        return _dense_group_by_entry(program, arrays, params, mask, n)
 
 
 def _dense_group_by_entry(program: ir.Program, arrays, params, mask, n):

@@ -234,6 +234,7 @@ def _pallas_limb_sums(planes, gid, num_segments: int, interpret: bool = False):
         out_shape=jax.ShapeDtypeStruct((nsb, num_planes * s1, LANES),
                                        jnp.int32),
         interpret=interpret,
+        name="mxu_limb_groupby",
     )(gid2, *planes2)
 
     # (nsb, P*S1, 128) --sum--> (P*S1, 128) --> (P, S1*128) --> trim

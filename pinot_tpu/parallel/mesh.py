@@ -19,7 +19,6 @@ __graft_entry__.dryrun_multichip.
 from __future__ import annotations
 
 import os
-import time
 from functools import lru_cache, partial
 
 import jax
@@ -28,7 +27,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..engine import ir
-from ..ops.kernels import PackedOuts, _apply_packed, _pack_flat, _run_program_impl
+from ..ops.kernels import (PackedOuts, ProgramJit, _apply_packed, _pack_flat,
+                           _run_program_impl, jit_named)
 
 ROW_AXIS = "sp"  # intra-segment row sharding (sequence-parallel analogue)
 SEGMENT_AXIS = "dp"  # across segments (data-parallel analogue)
@@ -215,9 +215,8 @@ def mesh_devices(ndev: int) -> list:
     return list(jax.devices()[:ndev])
 
 
-@partial(jax.jit, static_argnames=("program", "padded", "packed", "ndev"))
-def _batch_sharded_call(program: ir.Program, arrays: tuple, params: tuple,
-                        num_docs, padded: int, packed: tuple, ndev: int):
+def _batch_sharded(program: ir.Program, arrays: tuple, params: tuple,
+                   num_docs, padded: int, packed: tuple, ndev: int):
     mesh = segment_mesh(ndev)
 
     def shard_fn(arrays_l, params_l, num_docs_l):
@@ -243,6 +242,11 @@ def _batch_sharded_call(program: ir.Program, arrays: tuple, params: tuple,
     return fn(arrays, params, num_docs)
 
 
+# `jit_scan_<label>`, as the solo and the batched program are named
+_batch_sharded_call = ProgramJit(_batch_sharded, "scan",
+                                 ("program", "padded", "packed", "ndev"))
+
+
 def run_program_batch_sharded(program: ir.Program, arrays: tuple, params: tuple,
                               num_docs, padded: int, ndev: int,
                               packed: tuple = ()):
@@ -258,24 +262,31 @@ def run_program_batch_sharded(program: ir.Program, arrays: tuple, params: tuple,
                                num_docs, padded, tuple(packed), ndev)
 
 
-@partial(jax.jit, static_argnames=("s_real",))
 def _pack_sliced(outs: tuple, s_real: int):
     # drop the ragged pad rows on device, then byte-pack exactly like the
     # solo path so the host sees identical flat bytes
     return _pack_flat(tuple(o[:s_real] for o in outs))
 
 
-def pack_outputs_gathered(outs: tuple, s_real: int) -> PackedOuts:
+def _pack_jit(fn, label: str, static: tuple):
+    """The mesh's output packs, named `jit_pack_<label>` like the solo
+    pack (kernels.pack_outputs)."""
+    return jit_named(fn, f"pack_{label}" if label else fn.__name__,
+                     static_argnames=static)
+
+
+def pack_outputs_gathered(outs: tuple, s_real: int,
+                          label: str = "") -> PackedOuts:
     """Device-side cross-chip combine for the packed (dense) path: slice the
     pad rows, byte-pack on device, and commit the flat to device 0 so it
     concatenates with solo packs and crosses to host exactly once."""
     metas = [(np.dtype(str(o.dtype)), (s_real,) + tuple(o.shape[1:]))
              for o in outs]
-    flat = jax.device_put(_pack_sliced(tuple(outs), s_real), jax.devices()[0])
+    pack = _pack_jit(_pack_sliced, label, ("s_real",))
+    flat = jax.device_put(pack(tuple(outs), s_real), jax.devices()[0])
     return PackedOuts(flat, metas)
 
 
-@partial(jax.jit, static_argnames=("s_real", "ndev"))
 def _pack_collective(outs: tuple, s_real: int, ndev: int):
     mesh = segment_mesh(ndev)
 
@@ -299,7 +310,8 @@ def _pack_collective(outs: tuple, s_real: int, ndev: int):
     return fn(tuple(outs))
 
 
-def pack_outputs_collective(outs: tuple, s_real: int, ndev: int) -> PackedOuts:
+def pack_outputs_collective(outs: tuple, s_real: int, ndev: int,
+                            label: str = "") -> PackedOuts:
     """Mesh-collective variant of pack_outputs_gathered: the shuffle to one
     chip happens INSIDE the sharded program (all_gather over the segment
     axis) and every chip byte-packs the full stack, instead of funneling raw
@@ -307,8 +319,8 @@ def pack_outputs_collective(outs: tuple, s_real: int, ndev: int) -> PackedOuts:
     one pack kernel; the flat is replicated, so the host still crosses once."""
     metas = [(np.dtype(str(o.dtype)), (s_real,) + tuple(o.shape[1:]))
              for o in outs]
-    flat = jax.device_put(_pack_collective(tuple(outs), s_real, ndev),
-                          jax.devices()[0])
+    pack = _pack_jit(_pack_collective, label, ("s_real", "ndev"))
+    flat = jax.device_put(pack(tuple(outs), s_real, ndev), jax.devices()[0])
     return PackedOuts(flat, metas)
 
 
@@ -319,20 +331,6 @@ def gather_outputs(outs: tuple, s_real: int) -> tuple:
     with device-0-resident dictionaries."""
     dev0 = jax.devices()[0]
     return tuple(jax.device_put(o[:s_real], dev0) for o in outs)
-
-
-def block_per_device(outs: tuple, ndev: int, t0: float) -> list:
-    """Block each mesh device's output shards in device order; returns
-    [(device_id, ms_since_t0)] — the per-chip deviceExecMs attribution for
-    traced dispatches (monotone: chip i's stamp includes chips 0..i-1)."""
-    stamps = []
-    for d in jax.devices()[:ndev]:
-        for o in outs:
-            for sh in getattr(o, "addressable_shards", ()):
-                if sh.device == d:
-                    sh.data.block_until_ready()
-        stamps.append((d.id, round((time.perf_counter() - t0) * 1000.0, 3)))
-    return stamps
 
 
 def shard_segment_arrays(arrays: tuple, mesh: Mesh, padded: int, slots=None):

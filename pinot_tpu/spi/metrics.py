@@ -109,9 +109,6 @@ class BrokerMeter:
 class ServerTimer:
     QUERY_PROCESSING_TIME_MS = "queryProcessingTimeMs"
     SCHEDULER_WAIT_MS = "schedulerWaitMs"
-    # on-device cross-chip result merge for mesh-sharded family dispatches
-    # (engine/executor.py _dispatch_batch_sharded; traced runs only)
-    CROSS_CHIP_COMBINE_MS = "crossChipCombineMs"
     # tiered storage: wall time to fetch+verify+load one cold segment
     COLD_LOAD_MS = "coldLoadMs"
     # continuous batching: how long a coalesced query waited in the hold

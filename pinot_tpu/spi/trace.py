@@ -1,17 +1,35 @@
-"""Tracing SPI: pluggable per-query tracers + hierarchical phase spans.
+"""Tracing SPI: per-query hierarchical phase spans.
 
-Reference analogue: pinot-spi/.../spi/trace/Tracing.java:45 (registerable
-Tracer, InvocationScope recordings, per-request registration in
+Reference analogue: pinot-spi/.../spi/trace/Tracing.java:45
+(InvocationScope recordings, per-request registration in
 ServerQueryExecutorV1Impl.execute:143-156) and the phase timers
 (pinot-common/.../metrics/ServerQueryPhase.java:29-36). Traces attach to
 the broker response when the `trace` query option is set, exactly like the
 reference's `trace=true`.
 
-Spans form a tree (broker reduce -> server execution -> per-family device
-dispatch) but `to_json()` stays a FLAT list — consumers that only care
-about phase names/durations keep working — with `spanId`/`parentId`
-conveying the hierarchy and an `attributes` dict carrying device-phase
-detail (compileMs, deviceExecMs, transferBytes, HBM snapshot).
+Spans RECORD; they never change what runs: a traced request takes the
+path an untraced one takes (no added sync, no cache bypass, no change of
+grouping). Per server shard the tree is
+
+    QUERY_PROCESSING            entry of the server's handler to the blob
+      SCHEDULER_WAIT            admission (engine/scheduler.py)
+      BUILD_QUERY_PLAN          prune, route, plan, cache lookups, families
+      family_dispatch           gather + enqueue, no sync
+        GATHER_STACK            member planes to [S, ...] stacks
+      DEVICE_FETCH              the host blocks until results are host arrays
+      SERVER_COMBINE
+      RESPONSE_SERIALIZATION    datatable.encode
+
+under the broker's BROKER_SCATTER / BROKER_REDUCE. `to_json()` stays a
+FLAT list — consumers that only care about phase names/durations keep
+working — with `spanId`/`parentId` conveying the hierarchy, `startNs` (the
+epoch clock, the one the JAX profiler stamps its events with) aligning
+the spans of broker and servers, and an `attributes` dict carrying
+device-phase detail (compileMs, transferBytes, hostFetches, HBM snapshot).
+While a trace is active every scope is also a
+`jax.profiler.TraceAnnotation`, so a profiler session running at the time
+records the same interval, with `query_id` and `span_id`, on the host
+plane of its own trace, beside the device's operations.
 """
 
 from __future__ import annotations
@@ -21,20 +39,26 @@ import os
 import threading
 import time
 import zlib
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Optional
 
 
 class ServerQueryPhase:
-    """Reference: ServerQueryPhase enum values."""
+    """Reference: ServerQueryPhase enum values (those this engine emits)."""
 
-    REQUEST_DESERIALIZATION = "REQUEST_DESERIALIZATION"
     SCHEDULER_WAIT = "SCHEDULER_WAIT"
     BUILD_QUERY_PLAN = "BUILD_QUERY_PLAN"
     QUERY_PLAN_EXECUTION = "QUERY_PLAN_EXECUTION"
     RESPONSE_SERIALIZATION = "RESPONSE_SERIALIZATION"
     QUERY_PROCESSING = "QUERY_PROCESSING"
     SERVER_COMBINE = "SERVER_COMBINE"
+
+
+# this engine's own span names, beside the reference's phases: the host
+# blocked on the device's results, and the [S, ...] stacking of a family
+DEVICE_FETCH = "DEVICE_FETCH"
+GATHER_STACK = "GATHER_STACK"
+FAMILY_DISPATCH = "family_dispatch"
 
 
 # Process-wide span-allocation counter: the tracing-off perf guard asserts
@@ -93,15 +117,16 @@ def sample_decision(query_id: str, rate: float) -> bool:
 class Span:
     """One recorded scope: a node in the query's span tree."""
 
-    __slots__ = ("name", "start_ms", "duration_ms", "span_id", "parent_id",
-                 "seq", "attributes")
+    __slots__ = ("name", "start_ms", "start_ns", "duration_ms", "span_id",
+                 "parent_id", "seq", "attributes")
 
-    def __init__(self, name: str, start_ms: float, span_id: int,
-                 parent_id: Optional[int], seq: int):
+    def __init__(self, name: str, start_ms: float, start_ns: int,
+                 span_id: int, parent_id: Optional[int], seq: int):
         global _SPAN_ALLOCS
         _SPAN_ALLOCS += 1
         self.name = name
         self.start_ms = start_ms
+        self.start_ns = start_ns
         self.duration_ms = 0.0
         self.span_id = span_id
         self.parent_id = parent_id
@@ -113,6 +138,7 @@ class Span:
 
     def to_json(self) -> dict:
         out = {"operator": self.name, "startMs": self.start_ms,
+               "startNs": self.start_ns,
                "durationMs": self.duration_ms, "spanId": self.span_id}
         if self.parent_id is not None:
             out["parentId"] = self.parent_id
@@ -125,13 +151,15 @@ class Trace:
     """One query's recorded spans (flat store; tree via parentId)."""
 
     def __init__(self, query_id: str):
+        # the broker's queryId (shard suffix stripped): every participant's
+        # root spans carry it, so the spans of one request find each other
         self.query_id = query_id
         self.spans: list[Span] = []
-        # EXPLAIN ANALYZE arms tracing but must observe the REAL execution,
-        # caches included — cache layers consult this flag instead of
-        # unconditionally bypassing when a trace is active
-        self.analyze = False
+        # one reading of the epoch clock per trace; spans add their
+        # perf_counter offset to it, so `startNs` is monotonic within a
+        # trace and comparable across the processes of one host
         self._t0 = time.perf_counter()
+        self._t0_ns = time.time_ns()
         # list.append and itertools.count.__next__ are GIL-atomic, so
         # combine workers on adopted traces need no lock here
         self._ids = itertools.count(1)
@@ -139,10 +167,13 @@ class Trace:
 
     def new_span(self, name: str, start: float,
                  parent: Optional[Span] = None) -> Span:
-        span = Span(name, round((start - self._t0) * 1000, 3),
-                    next(self._ids),
+        offset = start - self._t0
+        span = Span(name, round(offset * 1000, 3),
+                    self._t0_ns + int(offset * 1e9), next(self._ids),
                     None if parent is None else parent.span_id,
                     next(self._seq))
+        if parent is None:
+            span.attributes["queryId"] = self.query_id
         self.spans.append(span)
         return span
 
@@ -170,74 +201,65 @@ class Trace:
             (parent["children"] if parent else roots).append(node)
         return roots
 
-    def phase_ms(self, name: str) -> float:
-        return sum(s.duration_ms for s in self.spans if s.name == name)
-
 
 def phase_breakdown(trace_json: list) -> dict:
     """Roll a flat span list up into the device-phase totals bench.py
-    emits: compile vs device-execute vs host-combine time and host->device
-    transfer volume (keys sum over every span carrying the attribute)."""
-    out = {"compileMs": 0.0, "deviceExecMs": 0.0, "hostCombineMs": 0.0,
-           "crossChipCombineMs": 0.0, "transferBytes": 0, "shuffledBytes": 0}
+    emits: compile time, the host's wait for the device, host-combine time
+    and host->device transfer volume. `deviceWaitMs` is the sum of the
+    DEVICE_FETCH spans: how long the host stood blocked until results were
+    host arrays — queueing behind other requests' programs, execution, the
+    output pack and the copy, NOT device time of this query alone."""
+    out = {"compileMs": 0.0, "deviceWaitMs": 0.0, "hostCombineMs": 0.0,
+           "transferBytes": 0, "shuffledBytes": 0}
     for span in trace_json:
         attrs = span.get("attributes") or {}
         out["compileMs"] += attrs.get("compileMs", 0.0)
-        if not str(span.get("operator", "")).startswith("mesh_device"):
-            # per-chip mesh spans re-attribute the parent family_dispatch's
-            # deviceExecMs per device; only the parent counts toward totals
-            out["deviceExecMs"] += attrs.get("deviceExecMs", 0.0)
-        out["crossChipCombineMs"] += attrs.get("crossChipCombineMs", 0.0)
         out["transferBytes"] += attrs.get("transferBytes", 0)
         out["shuffledBytes"] += attrs.get("shuffled_bytes", 0)
+        if span.get("operator") == DEVICE_FETCH:
+            out["deviceWaitMs"] += span.get("durationMs", 0.0)
         if span.get("operator") in (ServerQueryPhase.SERVER_COMBINE,
                                     "BROKER_REDUCE"):
             out["hostCombineMs"] += span.get("durationMs", 0.0)
-    for k in ("compileMs", "deviceExecMs", "hostCombineMs",
-              "crossChipCombineMs"):
+    for k in ("compileMs", "deviceWaitMs", "hostCombineMs"):
         out[k] = round(out[k], 3)
     if not out["shuffledBytes"]:
         # MSE-only phase: single-stage queries keep the classic four-key shape
         del out["shuffledBytes"]
-    if not out["crossChipCombineMs"]:
-        # mesh-only phase: solo dispatches keep the classic key shape
-        del out["crossChipCombineMs"]
     return out
 
 
-class Tracer:
-    """Override to ship scopes elsewhere (reference: pluggable Tracer)."""
+_ANNOTATION = None
 
-    def new_trace(self, query_id: str) -> Trace:
-        return Trace(query_id)
+
+def _annotation(name: str, **kwargs):
+    """The profiler's own host-plane span for ``name``. jax is imported on
+    first use (never at module load: spi/ stays importable without it);
+    with no profiler session running a TraceAnnotation is one atomic read
+    in C++."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation as _ANNOTATION
+        except Exception:  # no jax in this process: spans still record
+            _ANNOTATION = lambda name, **kw: nullcontext()  # noqa: E731
+    return _ANNOTATION(name, **kwargs)
 
 
 class _Tracing:
     """Per-thread active trace registry (reference: Tracing.ThreadLocal)."""
 
     def __init__(self):
-        self._tracer = Tracer()
         self._local = threading.local()
 
-    def register_tracer(self, tracer: Tracer) -> None:
-        self._tracer = tracer
-
-    def start_trace(self, query_id: str, analyze: bool = False) -> Trace:
-        trace = self._tracer.new_trace(query_id)
-        trace.analyze = analyze
+    def start_trace(self, query_id: str) -> Trace:
+        trace = Trace(query_id)
         self._local.trace = trace
         self._local.stack = []
         return trace
 
     def active_trace(self) -> Optional[Trace]:
         return getattr(self._local, "trace", None)
-
-    def analyze_active(self) -> bool:
-        """True when the active trace belongs to an EXPLAIN ANALYZE run —
-        cache layers stay ON (the annotated plan must show the cache
-        behaviour a real run would have)."""
-        trace = self.active_trace()
-        return trace is not None and getattr(trace, "analyze", False)
 
     def current_span(self) -> Optional[Span]:
         stack = getattr(self._local, "stack", None)
@@ -261,9 +283,11 @@ class _Tracing:
     @contextmanager
     def scope(self, name: str):
         """Records a span into the active trace, nested under the current
-        span; yields the Span so callers can attach attributes. No-op when
-        tracing is off — the hot path pays one thread-local read and
-        yields None (zero Span allocations)."""
+        span; yields the Span so callers can attach attributes. The same
+        interval is entered as a profiler TraceAnnotation carrying the
+        trace's query id and the span's id. No-op when tracing is off —
+        the hot path pays one thread-local read and yields None (zero Span
+        allocations, no annotation)."""
         trace = self.active_trace()
         if trace is None:
             yield None
@@ -273,7 +297,9 @@ class _Tracing:
         stack = self._local.stack
         stack.append(span)
         try:
-            yield span
+            with _annotation(name, query_id=trace.query_id,
+                             span_id=span.span_id):
+                yield span
         finally:
             span.duration_ms = round((time.perf_counter() - start) * 1000, 3)
             if stack and stack[-1] is span:

@@ -16,10 +16,11 @@ shape, with server spans namespaced ``<instance>:<id>`` /
   each shard's completion → the broker reduce, and shard roots → any
   parentless MSE stage span executing on that shard.
 
-Server spans carry timestamps relative to their OWN trace start; the
-exporter re-bases each shard onto the broker timeline at the broker's
-scatter span (wire latency is not separately measured, so alignment is
-approximate by construction — good enough to read, wrong to micro-time).
+Every span carries `startNs`, its start on the epoch clock (one reading
+per trace plus monotonic offsets, spi/trace.py), so the spans of broker
+and servers lie on one timeline as recorded: the export's `ts` is
+microseconds since the earliest span of the request. Spans from before
+`startNs` existed fall back to their trace-local `startMs`.
 
 The output loads directly in Perfetto (ui.perfetto.dev) or
 chrome://tracing; ``GET /debug/traces/{queryId}?format=chrome`` serves it.
@@ -44,6 +45,12 @@ def _process_of(span: dict) -> str:
     return "broker"
 
 
+def _start_ms(span: dict) -> float:
+    """A span's start in ms on the request's one timeline."""
+    ns = span.get("startNs")
+    return span["startMs"] if ns is None else ns / 1e6
+
+
 def _assign_lanes(spans: list) -> dict:
     """Greedy flame-graph lane assignment within one process: a span may
     share a lane only with spans that strictly contain it (its open
@@ -51,13 +58,13 @@ def _assign_lanes(spans: list) -> dict:
     B/E events nest like a call stack. Returns span index → lane."""
     order = sorted(
         range(len(spans)),
-        key=lambda i: (spans[i]["startMs"],
-                       -(spans[i]["startMs"]
+        key=lambda i: (_start_ms(spans[i]),
+                       -(_start_ms(spans[i])
                          + spans[i].get("durationMs", 0.0))))
     lanes: list = []  # per lane: stack of (start, end) open intervals
     assignment = {}
     for i in order:
-        s0 = spans[i]["startMs"]
+        s0 = _start_ms(spans[i])
         e0 = s0 + spans[i].get("durationMs", 0.0)
         placed = None
         for lane_no, stack in enumerate(lanes):
@@ -101,10 +108,10 @@ def to_chrome_trace(spans: list, query_id: str = "") -> dict:
                     if s.get("operator") == SCATTER_SPAN), None)
     reduce_ = next((s for s in broker_spans
                     if s.get("operator") == REDUCE_SPAN), None)
-    anchor = scatter or (min(broker_spans, key=lambda s: s["startMs"])
+    anchor = scatter or (min(broker_spans, key=_start_ms)
                          if broker_spans else None)
-    # shard timelines re-base onto the broker's scatter start
-    shard_offset_ms = anchor["startMs"] if anchor is not None else 0.0
+    # ts 0 is the request's earliest span
+    origin_ms = min((_start_ms(s) for s in spans), default=0.0)
 
     events: list = []
     # (process, local span index) → (pid, tid, begin ts µs, end ts µs)
@@ -116,18 +123,17 @@ def to_chrome_trace(spans: list, query_id: str = "") -> dict:
         events.append({"name": "process_sort_index", "ph": "M", "pid": pid,
                        "tid": 0, "args": {"sort_index": pid}})
         lanes = _assign_lanes(pspans)
-        offset = 0.0 if pname == "broker" else shard_offset_ms
         # outer-before-inner emit order (same order the lane assigner
         # used) keeps same-timestamp B events parent-first
         order = sorted(
             range(len(pspans)),
-            key=lambda i: (pspans[i]["startMs"],
-                           -(pspans[i]["startMs"]
+            key=lambda i: (_start_ms(pspans[i]),
+                           -(_start_ms(pspans[i])
                              + pspans[i].get("durationMs", 0.0))))
         for rank, i in enumerate(order):
             span = pspans[i]
             tid = lanes[i]
-            ts = round((span["startMs"] + offset) * 1000.0, 3)
+            ts = round((_start_ms(span) - origin_ms) * 1000.0, 3)
             dur = round(span.get("durationMs", 0.0) * 1000.0, 3)
             args = _json_safe_attrs(span.get("attributes"))
             args["spanId"] = str(span.get("spanId"))

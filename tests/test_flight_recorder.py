@@ -264,9 +264,21 @@ def test_chrome_export_synthetic_two_process():
         {"operator": "segment:seg_1", "startMs": 2.0, "durationMs": 3.0,
          "spanId": "Server_0:3", "parentId": "Server_0:1"},
     ]
+    # every span says where it lies on the epoch clock: the server's trace
+    # began 1.5 ms into the broker's, half a millisecond into the scatter
+    base = 1_790_000_000_000_000_000
+    for s in spans:
+        shard = isinstance(s["spanId"], str)
+        s["startNs"] = base + int((s["startMs"] + (1.5 if shard else 0.0))
+                                  * 1e6)
     ct = to_chrome_trace(spans, query_id="qtest")
     dur, flows, procs = _validate_chrome(ct)
     assert ct["otherData"]["queryId"] == "qtest"
+    # placed by startNs, not re-based onto the scatter span: ts 0 is the
+    # request's earliest span (BROKER_SCATTER), the shard's root 500 us on
+    root = next(e for e in dur if e["name"] == "SERVER_QUERY"
+                and e["ph"] == "B")
+    assert root["ts"] == pytest.approx(500.0)
     assert set(procs.values()) == {"broker", "Server_0"}
     assert len(dur) == 2 * len(spans)
     names = {f[0]["name"] for f in flows.values()}
@@ -337,6 +349,10 @@ def test_sampled_production_query_retained(cluster, monkeypatch):
     production query — retrievable afterwards at /debug/traces/{queryId}
     with a schema-valid chrome export whose flows connect the processes."""
     _store, broker, _servers = cluster
+    # warm the statement first: a first run compiles, crosses the slow
+    # threshold and would be retained as `slow`, not `sampled`
+    warm = broker.execute_sql("SET resultCache = false; " + SQL)
+    assert not warm.exceptions, warm.exceptions
     monkeypatch.setenv(SAMPLE_ENV, "1.0")
     resp = broker.execute_sql("SET resultCache = false; " + SQL)
     assert not resp.exceptions, resp.exceptions

@@ -415,12 +415,29 @@ def test_result_cache_opt_outs(cluster):
         {"tableName": "p", "replication": 1})
     cluster.controller.add_segment(table, "s0", {
         "location": cluster.seg("s0", [1]), "numDocs": 1})
-    for sql in ("SET resultCache = false; SELECT SUM(v) FROM p",
-                "SET trace = true; SELECT SUM(v) FROM p"):
-        r = cluster.broker.execute_sql(sql)
-        assert not r.exceptions, r.exceptions
-        assert r.cache_outcome == "bypass"
+    r = cluster.broker.execute_sql(
+        "SET resultCache = false; SELECT SUM(v) FROM p")
+    assert not r.exceptions, r.exceptions
+    assert r.cache_outcome == "bypass"
     assert cluster.broker.result_cache.stats()["entries"] == 0
+    # `SET trace = true` is NOT an opt-out: a traced query takes the path
+    # an untraced one takes. It misses, is stored WITHOUT its trace, and a
+    # traced repeat is a hit that says so in a span
+    r = cluster.broker.execute_sql("SET trace = true; SELECT SUM(v) FROM p")
+    assert not r.exceptions, r.exceptions
+    assert r.cache_outcome == "miss" and r.trace_info
+    assert cluster.broker.result_cache.stats()["entries"] == 1
+    plain = cluster.broker.execute_sql("SELECT SUM(v) FROM p")
+    assert plain.cache_outcome == "hit" and plain.trace_info is None
+    traced = cluster.broker.execute_sql(
+        "SET trace = true; SELECT SUM(v) FROM p")
+    assert traced.cache_outcome == "hit"
+    assert traced.result_table.rows == r.result_table.rows
+    assert [s["operator"] for s in traced.trace_info] == ["RESULT_CACHE(hit)"]
+    assert traced.trace_info[0]["attributes"]["cache"] == "hit"
+    # the shared cached copy stayed plain
+    again = cluster.broker.execute_sql("SELECT SUM(v) FROM p")
+    assert again.cache_outcome == "hit" and again.trace_info is None
     # non-deterministic SQL bypasses at the key level (decision tree)
     try:
         q = parse_sql("SELECT SUM(v) FROM p WHERE v < NOW()")

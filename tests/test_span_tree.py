@@ -1,0 +1,276 @@
+"""One span tree per request that names every wait, on the profiler's
+clock, with stable device names.
+
+- the tree of a traced query on a two-server cluster: every span of the
+  server-side tree once per shard, children inside their parent, `startNs`
+  inside the client's wall and ordered broker -> server -> broker;
+- under a profiler session the spans are TraceAnnotations on its host
+  plane, with the spans' names, ids and clock;
+- the program label: equal for two literals of one SQL shape, different
+  for another shape, the name of the lowered module and of its ops' scopes.
+"""
+
+from __future__ import annotations
+
+import re
+import tempfile
+import time
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+from pinot_tpu.cluster import (Broker, ClusterController, PropertyStore,
+                               ServerInstance)
+from pinot_tpu.engine.ir import program_label
+from pinot_tpu.engine.query_executor import QueryExecutor, parse_sql
+from pinot_tpu.query.optimizer import optimize_filter
+from pinot_tpu.segment.builder import SegmentBuilder
+from pinot_tpu.segment.loader import load_segment
+from pinot_tpu.spi.data_types import Schema
+
+ST = Schema.build("sptab", dimensions=[("spk", "INT"), ("spy", "INT")],
+                  metrics=[("spv", "INT"), ("spd", "INT")])
+NOCACHE = "SET resultCache = false; SET segmentCache = false; "
+SQL = "SELECT spk, SUM(spv) FROM sptab WHERE spy = 3 GROUP BY spk LIMIT 50"
+SERVER_TREE = ("QUERY_PROCESSING", "SCHEDULER_WAIT", "BUILD_QUERY_PLAN",
+               "family_dispatch", "GATHER_STACK", "DEVICE_FETCH",
+               "SERVER_COMBINE", "RESPONSE_SERIALIZATION")
+SLACK_MS = 1.0  # two traces read the epoch clock microseconds apart
+
+
+def _segments(d: Path, n: int) -> list:
+    rng = np.random.default_rng(5)
+    paths = []
+    for i in range(n):
+        cols = {"spk": rng.integers(0, 16, 600).astype(np.int32),
+                "spy": rng.integers(0, 7, 600).astype(np.int32),
+                "spv": rng.integers(0, 100, 600).astype(np.int32),
+                "spd": rng.integers(0, 11, 600).astype(np.int32)}
+        SegmentBuilder(ST, segment_name=f"sptab_{i}").build(
+            cols, d / f"sptab_{i}")
+        paths.append(d / f"sptab_{i}")
+    return paths
+
+
+@pytest.fixture(scope="module")
+def cluster():
+    d = Path(tempfile.mkdtemp(prefix="sptree_"))
+    store = PropertyStore()
+    controller = ClusterController(store)
+    servers = [ServerInstance(store, f"Server_{i}", backend="tpu")
+               for i in range(2)]
+    for s in servers:
+        s.start()
+    controller.add_schema(ST.to_json())
+    t = controller.create_table({"tableName": "sptab", "replication": 1})
+    for i, path in enumerate(_segments(d, 8)):
+        controller.add_segment(t, f"sptab_{i}", {"location": str(path),
+                                                 "numDocs": 600})
+    broker = Broker(store)
+    warm = broker.execute_sql(NOCACHE + SQL)
+    assert not warm.exceptions, warm.exceptions
+    yield broker
+    for s in servers:
+        s.stop()
+
+
+def _end(span) -> float:
+    return span["startNs"] / 1e6 + span["durationMs"]
+
+
+def test_span_tree_of_a_traced_query_on_two_servers(cluster):
+    wall0 = time.time_ns()
+    resp = cluster.execute_sql("SET trace = true; " + NOCACHE + SQL)
+    wall1 = time.time_ns()
+    assert not resp.exceptions, resp.exceptions
+    assert resp.num_servers_queried == 2
+    spans = resp.trace_info
+    by_id = {s["spanId"]: s for s in spans}
+    assert len(by_id) == len(spans)
+    broker = [s for s in spans if not isinstance(s["spanId"], str)]
+    scatter = next(s for s in broker if s["operator"] == "BROKER_SCATTER")
+    reduce_ = next(s for s in broker if s["operator"] == "BROKER_REDUCE")
+    shards = {}
+    for s in spans:
+        if isinstance(s["spanId"], str):
+            shards.setdefault(s["spanId"].rsplit(":", 1)[0], []).append(s)
+    assert sorted(shards) == ["Server_0", "Server_1"]
+    for shard, own in shards.items():
+        names = [s["operator"] for s in own]
+        for name in SERVER_TREE:
+            assert names.count(name) == 1, (shard, name, names)
+        root = next(s for s in own if s["operator"] == "QUERY_PROCESSING")
+        assert "parentId" not in root and root["server"] == shard
+        assert root["attributes"]["queryId"] == resp.query_id
+        by_name = {s["operator"]: s for s in own}
+        for child in SERVER_TREE[1:]:
+            parent = "family_dispatch" if child == "GATHER_STACK" \
+                else "QUERY_PROCESSING"
+            assert by_id[by_name[child]["parentId"]]["operator"] == parent
+        # every child lies inside its parent, and siblings sum to no more
+        for s in own:
+            if "parentId" not in s:
+                continue
+            parent = by_id[s["parentId"]]
+            assert s["startNs"] >= parent["startNs"]
+            assert _end(s) <= _end(parent) + 0.01
+        for parent in own:
+            kids = [s for s in own if s.get("parentId") == parent["spanId"]]
+            assert sum(k["durationMs"] for k in kids) \
+                <= parent["durationMs"] + 0.01 * len(kids)
+        # no sync under the dispatch: the wait has a span of its own
+        assert "deviceExecMs" not in by_name["family_dispatch"]["attributes"]
+        assert by_name["family_dispatch"]["attributes"]["program"] \
+            == "gby_rng_i0_by1_sum_d2"
+        assert by_name["DEVICE_FETCH"]["attributes"]["hostFetches"] == 1
+        # one clock: broker -> server -> broker
+        assert scatter["startNs"] / 1e6 <= root["startNs"] / 1e6 + SLACK_MS
+        assert _end(root) <= _end(scatter) + SLACK_MS
+        assert _end(root) <= reduce_["startNs"] / 1e6 + SLACK_MS
+    for s in broker:
+        assert s["attributes"]["queryId"] == resp.query_id
+    for s in spans:
+        assert wall0 - SLACK_MS * 1e6 <= s["startNs"]
+        assert _end(s) * 1e6 <= wall1 + SLACK_MS * 1e6
+    # the layers add up to the whole, no span counted twice, none missing
+    for shard, own in shards.items():
+        by_name = {s["operator"]: s for s in own}
+        covered = sum(by_name[n]["durationMs"] for n in (
+            "SCHEDULER_WAIT", "BUILD_QUERY_PLAN", "family_dispatch",
+            "DEVICE_FETCH", "SERVER_COMBINE", "RESPONSE_SERIALIZATION"))
+        assert covered <= by_name["QUERY_PROCESSING"]["durationMs"] + 0.05
+
+
+def test_traced_repeat_hits_the_segment_cache_and_says_so(cluster):
+    sql = "SET resultCache = false; " + SQL.replace("spy = 3", "spy = 4")
+    first = cluster.execute_sql(sql)
+    assert not first.exceptions and first.num_device_dispatches == 2
+    traced = cluster.execute_sql("SET trace = true; " + sql)
+    assert not traced.exceptions
+    assert traced.num_device_dispatches == 0
+    assert traced.num_segments_cache_hit == 8
+    assert traced.result_table.rows == first.result_table.rows
+    ops = [s["operator"] for s in traced.trace_info]
+    assert ops.count("SEGMENT_CACHE(hit)") == 2
+    assert "family_dispatch" not in ops and "DEVICE_FETCH" not in ops
+    hit = next(s for s in traced.trace_info
+               if s["operator"] == "SEGMENT_CACHE(hit)")
+    assert hit["attributes"] == {"segments": 4, "cache": "hit"}
+
+
+# -- the profiler's clock ------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def engine():
+    d = Path(tempfile.mkdtemp(prefix="sptree_qe_"))
+    qe = QueryExecutor(backend="tpu")
+    qe.add_table(ST, [load_segment(p) for p in _segments(d, 4)])
+    r = qe.execute_sql(NOCACHE + SQL)
+    assert not r.exceptions, r.exceptions
+    return qe
+
+
+def test_spans_are_annotations_on_the_profilers_host_plane(engine, tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        resp = engine.execute_sql("SET trace = true; " + NOCACHE + SQL)
+    finally:
+        jax.profiler.stop_trace()
+    assert not resp.exceptions, resp.exceptions
+    profile = ProfileData.from_file(
+        str(next(tmp_path.rglob("*.xplane.pb"))))
+    start_ns, found = None, {}
+    for plane in profile.planes:
+        stats = dict(plane.stats)
+        start_ns = stats.get("profile_start_time", start_ns)
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                st = dict(ev.stats)
+                if "span_id" in st:
+                    found[st["span_id"]] = (ev.name, st["query_id"],
+                                            ev.start_ns, ev.duration_ns)
+    spans = {s["spanId"]: s for s in resp.trace_info}
+    assert set(found) == set(spans)
+    root = next(s for s in resp.trace_info if "parentId" not in s)
+    for span_id, (name, query_id, rel_ns, dur_ns) in found.items():
+        span = spans[span_id]
+        assert name == span["operator"]
+        assert query_id == root["attributes"]["queryId"]
+        assert dur_ns / 1e6 == pytest.approx(span["durationMs"], abs=1.0)
+        # the profiler stamps events with the epoch clock, as `startNs` is
+        assert start_ns is not None
+        assert abs(start_ns + rel_ns - span["startNs"]) < 2e6, name
+    assert {"family_dispatch", "GATHER_STACK", "DEVICE_FETCH",
+            "BUILD_QUERY_PLAN"} <= {v[0] for v in found.values()}
+
+
+# -- stable names on the device -------------------------------------------------
+
+
+def _plan(engine, sql):
+    query = parse_sql(sql)
+    query.filter = optimize_filter(query.filter)
+    segment = engine.tables["sptab"].segments[0]
+    return segment, engine.tpu.plan(query, segment)
+
+
+Q11 = ("SELECT SUM(spv * spd) FROM sptab WHERE spy = {y} AND spd BETWEEN {d} "
+       "AND {d2} AND spv < {q}")
+Q21 = "SELECT SUM(spv), spy, spk FROM sptab WHERE spd = {d} GROUP BY spy, spk"
+
+
+def test_program_label_is_the_shape_not_the_literals(engine):
+    _, a = _plan(engine, Q11.format(y=3, d=1, d2=3, q=25))
+    _, b = _plan(engine, Q11.format(y=5, d=4, d2=6, q=24))
+    _, c = _plan(engine, Q21.format(d=2))
+    assert program_label(a.program) == program_label(b.program) \
+        == "agg_and3_rng_i0_rng_i1_rng_i2_sum_mul_d2_d1"
+    assert program_label(c.program) == "gby_rng_i0_by1x2_sum_d3"
+    assert re.fullmatch(r"[a-z0-9_]+", program_label(c.program))
+
+
+def test_label_names_the_module_and_scopes_name_its_ops(engine):
+    from pinot_tpu.ops import kernels
+
+    segment, plan = _plan(engine, Q21.format(d=2))
+    view = engine.tpu.cache.view(segment)
+    arrays, packed = plan.gather_arrays_packed(view)
+    params = tuple(np.asarray(p) for p in plan.params)
+    label = program_label(plan.program)
+    lowered = kernels.run_program.lower(
+        plan.program, arrays, params, np.int32(segment.num_docs),
+        view.padded, packed=packed)
+    assert f"module @jit_scan_{label} " in lowered.as_text()
+    names = set(re.findall(r'op_name="([^"]+)"',
+                           lowered.compile().as_text()))
+    scopes = {n.split("/")[1] for n in names
+              if n.startswith(f"jit(scan_{label})/") and n.count("/") >= 2}
+    assert {"filter", "group_by_dense"} <= scopes
+    # the batched program and the pack carry the same label
+    segs = engine.tables["sptab"].segments
+    plans = [_plan(engine, Q21.format(d=2))[1] for _ in segs]
+    views, arrays_b, params_b, packed_b, num_docs = \
+        engine.tpu._gather_batch(list(segs), plans)
+    batched = kernels.run_program_batch.lower(
+        plan.program, arrays_b, params_b, num_docs, views[0].padded,
+        packed=packed_b)
+    assert f"module @jit_scan_{label} " in batched.as_text()
+    assert "vmap(filter)" in batched.compile().as_text()
+    outs = kernels.run_program(plan.program, arrays, params,
+                               np.int32(segment.num_docs), view.padded,
+                               packed=packed)
+    pack = kernels.jit_named(kernels._pack_flat_impl, f"pack_{label}")
+    text = pack.lower(outs).as_text()
+    assert f"module @jit_pack_{label} " in text
+    assert kernels.unpack_outputs(kernels.pack_outputs(outs, label))[0].sum() \
+        == np.asarray(outs[0]).sum()

@@ -1,4 +1,5 @@
-"""CI perf-structure guard: tracing OFF must cost nothing on the hot path.
+"""CI perf-structure guard: tracing OFF must cost nothing on the hot path,
+and tracing ON must take the same path.
 
 Call-count instrumentation, not wall-clock, so it can't flake: after the
 query is warm (compile guard satisfied, fused validation settled, planes
@@ -6,8 +7,10 @@ resident in HBM), an untraced run must perform ZERO extra
 ``jax.block_until_ready`` / ``jax.device_get`` calls and allocate ZERO
 trace spans — the only tracing cost allowed is the single thread-local
 read in ``TRACING.scope``/``active_trace``. A traced run of the same query
-is then required to increment both counters, proving the guard actually
-watches the instrumented sites.
+must then make EXACTLY as many of those calls, and as many device→host
+fetches and dispatches, as the untraced one — spans record, they never
+add a sync, skip a cache or change a grouping — and still allocate spans,
+proving the guard actually watches the instrumented sites.
 """
 
 from __future__ import annotations
@@ -84,15 +87,43 @@ def test_tracing_off_adds_zero_syncs_and_zero_spans(warm_engine, monkeypatch):
         "tracing-off path must allocate zero Span objects")
 
 
-def test_traced_run_does_sync_and_allocate(warm_engine, monkeypatch):
-    """Sanity: the guard watches live sites — tracing ON must trip both."""
+@pytest.mark.parametrize("options", [
+    "", "SET segmentCache = false; "], ids=["cache-hit", "device-work"])
+def test_traced_run_syncs_exactly_as_untraced_and_allocates(
+        warm_engine, monkeypatch, options):
+    """A traced warm run takes the untraced path: the same count of
+    block_until_ready / device_get calls, host fetches, dispatches and
+    segment-cache hits — and it still allocates spans."""
+    from pinot_tpu.ops.kernels import host_fetches
+
     sync = _CountingSync(monkeypatch)
-    spans_before = span_allocations()
-    r = warm_engine.execute_sql("SET trace = true; " + SQL)
-    assert not r.exceptions, r.exceptions
-    assert r.trace_info
-    assert sync.block_calls > 0
-    assert span_allocations() > spans_before
+
+    def run(prefix):
+        before = (sync.block_calls, sync.device_get_calls, host_fetches(),
+                  span_allocations())
+        r = warm_engine.execute_sql(prefix + options + SQL)
+        assert not r.exceptions, r.exceptions
+        after = (sync.block_calls, sync.device_get_calls, host_fetches(),
+                 span_allocations())
+        return r, tuple(a - b for a, b in zip(after, before))
+
+    plain, (p_block, p_get, p_fetch, p_spans) = run("")
+    traced, (t_block, t_get, t_fetch, t_spans) = run("SET trace = true; ")
+    assert plain.trace_info is None and traced.trace_info
+    assert (t_block, t_get, t_fetch) == (p_block, p_get, p_fetch)
+    assert traced.num_device_dispatches == plain.num_device_dispatches
+    assert traced.num_segments_cache_hit == plain.num_segments_cache_hit
+    assert traced.result_table.rows == plain.result_table.rows
+    assert p_spans == 0 and t_spans > 0
+    ops = [s["operator"] for s in traced.trace_info]
+    if options:
+        assert plain.num_device_dispatches == 1 and p_fetch == 1
+        assert "DEVICE_FETCH" in ops and "family_dispatch" in ops
+    else:
+        # the warm repeat is answered by the segment cache, traced or not,
+        # and the trace says so
+        assert plain.num_device_dispatches == 0
+        assert "SEGMENT_CACHE(hit)" in ops and "family_dispatch" not in ops
 
 
 # -- cluster-path guard: cost accounting + health rollup stay off the hot
