@@ -20,8 +20,8 @@ import jax
 import numpy as np
 import jax.numpy as jnp
 
-from ..ops.kernels import (PackedOuts, fetch_tally, pack_outputs, run_program,
-                           unpack_outputs)
+from ..ops.kernels import (PackedOuts, dict_lookups, fetch_tally, pack_outputs,
+                           run_program, unpack_outputs)
 from .aot_cache import AOT_READY, aot_call
 from ..query.context import QueryContext
 from ..segment.device_cache import (
@@ -193,6 +193,18 @@ def _attach_dispatch_stats(span, cache: DeviceSegmentCache) -> None:
         span.set_attribute("stackMisses", stats["stackMisses"])
     span.attributes.update(cache.hbm_stats())
     clear_transfer_stats()
+
+
+def _describe_dispatch(span, program, padded: int, arrays) -> None:
+    """What a traced dispatch's span says of its program: mode, label, row
+    bucket, and how the planes it is fed decode their dictionaries
+    (`dictLookups`, kernels.dict_lookups)."""
+    if span is None:
+        return
+    span.set_attribute("mode", program.mode)
+    span.set_attribute("program", program_label(program))
+    span.set_attribute("padded", padded)
+    span.set_attribute("dictLookups", dict_lookups(program, arrays))
 
 
 @contextmanager
@@ -401,12 +413,9 @@ class TpuSegmentExecutor:
         new_compile = _GUARD.note(gkey)
         _count_dispatch(new_compile)
         label = program_label(plan.program)
-        if span is not None:
-            span.set_attribute("mode", plan.program.mode)
-            span.set_attribute("program", label)
-            span.set_attribute("padded", view.padded)
-            if fused:
-                span.set_attribute("fused", fused)
+        _describe_dispatch(span, plan.program, view.padded, arrays)
+        if span is not None and fused:
+            span.set_attribute("fused", fused)
         if new_compile:
             t0 = time.perf_counter()
         nd = np.int32(segment.num_docs)
@@ -496,10 +505,7 @@ class TpuSegmentExecutor:
         gkey = (plan.program, view.padded, "", ())
         new_compile = _GUARD.note(gkey)
         _count_dispatch(new_compile)
-        if span is not None:
-            span.set_attribute("mode", plan.program.mode)
-            span.set_attribute("program", program_label(plan.program))
-            span.set_attribute("padded", view.padded)
+        _describe_dispatch(span, plan.program, view.padded, arrays)
         if new_compile:
             t0 = time.perf_counter()
         outs = run_program(plan.program, arrays, params,
@@ -630,10 +636,8 @@ class TpuSegmentExecutor:
                 asig, len(segments))
         new_compile = _GUARD.note(gkey)
         label = program_label(plan0.program)
+        _describe_dispatch(span, plan0.program, views[0].padded, arrays)
         if span is not None:
-            span.set_attribute("mode", plan0.program.mode)
-            span.set_attribute("program", label)
-            span.set_attribute("padded", views[0].padded)
             span.set_attribute("meshDevices", ndev)
         t0 = time.perf_counter()
         outs = pmesh.run_program_batch_sharded(
@@ -702,10 +706,7 @@ class TpuSegmentExecutor:
                 len(segments))
         new_compile = _GUARD.note(gkey)
         _count_dispatch(new_compile)
-        if span is not None:
-            span.set_attribute("mode", plan0.program.mode)
-            span.set_attribute("program", program_label(plan0.program))
-            span.set_attribute("padded", views[0].padded)
+        _describe_dispatch(span, plan0.program, views[0].padded, arrays)
         if new_compile:
             t0 = time.perf_counter()
         outs = aot_call(gkey, arrays, params_b, num_docs) \

@@ -17,7 +17,7 @@ per-query values (interval bounds, LUTs, IN-lists). The planner
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, fields
 from typing import Optional
 
 # Sparse group-by composite keys must stay strictly below this value: the
@@ -328,6 +328,25 @@ def sparse_groupby_path(p: Program) -> str:
     payloads = sum(1 for a in p.aggs
                    if a.kind in ("sum", "sumsq", "min", "max"))
     return "sparse-sort+gather" if payloads >= 2 else "sparse-sort"
+
+
+def dict_gathers(p: Program) -> tuple:
+    """Every DictGather of a Program (filter, group keys, aggregations), one
+    entry per occurrence in the tree."""
+    found = []
+
+    def walk(x):
+        if isinstance(x, DictGather):
+            found.append(x)
+        elif isinstance(x, tuple):
+            for c in x:
+                walk(c)
+        elif isinstance(x, (ValueExpr, FilterNode, AggOp, Program)):
+            for f in fields(x):
+                walk(getattr(x, f.name))
+
+    walk(p)
+    return tuple(found)
 
 
 # ---------------------------------------------------------------------------

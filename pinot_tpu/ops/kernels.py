@@ -35,13 +35,64 @@ from . import mxu_groupby
 jax.config.update("jax_enable_x64", True)
 
 
+# Longest dictionary plane (executor._dict_pad bucket) decoded by a select
+# chain; a longer one is gathered. On a v5e, Q1.1 over 16 x 2^22 rows: the
+# chain takes 13.4 ms at 16 entries, 19.9 at 256, 45.2 at 1,024 and 143.3 at
+# 4,096 where the gather takes 677-801 ms, so the chain's COMPILE time sets
+# the limit: 3.2 s at 256, 9.5 s at 512, 14.4 s at 1,024, 91.6 s at 4,096
+# for each DictGather of a program (the gather: 1.6 s), paid by the first
+# query of a family. PERF.md section 6, PR 26, has the sweep.
+DICT_SELECT_MAX = 256
+# Selects per XLA fusion of the chain. The compiler's time grows faster than
+# a fusion's length: 256 selects in one fusion cost it 97 s (sandbox), in
+# fusions of 64 10.7 s, of 32 3.2 s (chip), for a run time within 9 %.
+_DICT_SELECT_FUSE = 32
+
+
+def dict_lookup_form(plane_len: int) -> str:
+    """How a dictionary plane of `plane_len` entries is decoded: the one
+    rule that `_dict_lookup` lowers by and `dict_lookups` counts by. (An
+    empty plane has no entry to start a chain from.)"""
+    return "select" if 0 < plane_len <= DICT_SELECT_MAX else "gather"
+
+
+def _dict_lookup(table, ids):
+    """table[ids], the only lowering of ir.DictGather. The TPU gathers one
+    row at a time (9.7 ns a row, whatever the table's size), so a small
+    table is looked up in registers: its entries are static slices (one
+    scalar a segment under the family's vmap) chosen by a chain of selects
+    that XLA fuses into the reduction, as it does the filter. A select is
+    exact for every dtype (a one-hot product would turn 0 * inf into NaN).
+    Ids outside [0, len) — row padding, masked by the caller — read entry
+    0 here and a clamped entry in the gather."""
+    n = table.shape[0]
+    if dict_lookup_form(n) == "gather":
+        return table[ids]
+    out = jnp.broadcast_to(table[0], ids.shape)
+    for k in range(1, n):
+        out = jnp.where(ids == k, table[k], out)
+        if k % _DICT_SELECT_FUSE == 0:
+            # ends the fusion: the plane so far goes through HBM once
+            out = jax.lax.optimization_barrier(out)
+    return out
+
+
+def dict_lookups(program: ir.Program, arrays) -> str:
+    """`select:<n>,gather:<m>`: how many of the program's DictGather nodes
+    decode by each form, given the planes a dispatch feeds it (solo or
+    stacked: the plane's length is the last dim)."""
+    forms = [dict_lookup_form(arrays[g.dict_slot].shape[-1])
+             for g in ir.dict_gathers(program)]
+    return f"select:{forms.count('select')},gather:{forms.count('gather')}"
+
+
 def _eval_value(node: ir.ValueExpr, arrays, params):
     if isinstance(node, ir.Col):
         return arrays[node.slot]
     if isinstance(node, ir.IdsCol):
         return arrays[node.slot]
     if isinstance(node, ir.DictGather):
-        return arrays[node.dict_slot][arrays[node.ids_slot]]
+        return _dict_lookup(arrays[node.dict_slot], arrays[node.ids_slot])
     if isinstance(node, ir.ConstParam):
         return params[node.idx]
     if isinstance(node, ir.ParamGather):

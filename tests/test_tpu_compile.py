@@ -14,6 +14,7 @@ same tests.
 """
 
 import dataclasses
+import re
 import time
 
 import jax
@@ -22,6 +23,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from pinot_tpu.engine import ir
 from pinot_tpu.engine.plan import SegmentPlanner
 from pinot_tpu.ops import fused_groupby, kernels, mxu_groupby
 from pinot_tpu.query.parser.sql import parse_sql
@@ -104,12 +106,13 @@ def _spec(one_chip, shape, dtype):
 
 
 def _compile_program(one_chip, ssb, sql, padded, *, batch=0, fused="",
-                     sparse_groups=0):
+                     sparse_groups=0, dict_len=0):
     """Plan ``sql`` against the small segment, then lower run_program /
     run_program_batch with every row plane scaled to ``padded`` rows (and
     an [S] batch dim when ``batch``). ``sparse_groups`` scales a sparse
     program's key space, output groups and dictionary plane to a real
-    high-cardinality segment."""
+    high-cardinality segment; ``dict_len`` sets the dictionary planes'
+    length (a family's `executor._dict_pad` bucket)."""
     segment, view = ssb
     plan = SegmentPlanner(parse_sql(sql), segment).plan()
     arrays, packed = plan.gather_arrays_packed(view)
@@ -130,8 +133,8 @@ def _compile_program(one_chip, ssb, sql, padded, *, batch=0, fused="",
     def plane(a, kind):
         shape = list(a.shape)
         if kind == "dict":
-            if sparse_groups:
-                shape[0] = sparse_groups
+            if sparse_groups or dict_len:
+                shape[0] = sparse_groups or dict_len
         else:
             assert shape[0] == view.padded
             shape[0] = padded
@@ -321,6 +324,46 @@ def test_batch_family_compiles(one_chip, ssb, monkeypatch, sql, pallas):
     monkeypatch.setattr(mxu_groupby, "backend_platform", lambda: "tpu")
     _, text = _compile_program(one_chip, ssb, sql, R22, batch=16)
     assert ("tpu_custom_call" in text) == pallas
+
+
+_Q1_1 = ("SELECT SUM(lo_extendedprice * lo_discount) FROM t WHERE d_year = 1993 "
+         "AND lo_discount BETWEEN 1 AND 3 AND lo_quantity < 25")
+_Q1_3 = ("SELECT SUM(lo_extendedprice * lo_discount) FROM t WHERE p_brand = 6 "
+         "AND d_year = 1994 AND lo_discount BETWEEN 5 AND 7 "
+         "AND lo_quantity BETWEEN 26 AND 35")
+
+
+def _op_count(text: str, op: str) -> int:
+    return len(re.findall(rf"= \S+ {op}\(", text))
+
+
+@pytest.mark.parametrize("sql", [pytest.param(_Q1_1, id="q1.1"),
+                                 pytest.param(_Q1_3, id="q1.3")])
+def test_dictionary_product_sum_compiles_without_a_gather(one_chip, ssb, sql):
+    """SSB flight 1 over a 16 x 2^22-row family: `lo_discount` is
+    dictionary-encoded and SUM(lo_extendedprice * lo_discount) reads its
+    VALUES. At the family's plane of 16 entries the decode is a select
+    chain in the reduction's fusion: no gather (653 of 674.5 ms a dispatch
+    on the chip, PERF.md), and none of the loop the compiler expands a
+    gather into. A plane of 2 * DICT_SELECT_MAX keeps the gather, as
+    before. The time bound guards the cliff: one fusion of 256 selects
+    takes the chip's compiler 97 s, which is why the chain is cut into
+    fusions of `_DICT_SELECT_FUSE`."""
+    t0 = time.perf_counter()
+    program, text = _compile_program(one_chip, ssb, sql, R22, batch=16,
+                                     dict_len=16)
+    assert time.perf_counter() - t0 < 60  # about a second alone
+    assert program.mode == "aggregation"
+    assert len(ir.dict_gathers(program)) == 1
+    assert _op_count(text, "gather") == 0 and _op_count(text, "while") == 0
+    t0 = time.perf_counter()
+    _, text = _compile_program(one_chip, ssb, sql, R22, batch=16,
+                               dict_len=kernels.DICT_SELECT_MAX)
+    assert time.perf_counter() - t0 < 60  # 2 s alone, in fusions of 32
+    assert _op_count(text, "gather") == 0
+    _, text = _compile_program(one_chip, ssb, sql, R22, batch=16,
+                               dict_len=2 * kernels.DICT_SELECT_MAX)
+    assert _op_count(text, "gather") == 1
 
 
 # -- the output pack ---------------------------------------------------------
