@@ -15,7 +15,7 @@ _SAID = []
 def read(run, params):
     if run.trace is None:
         return None
-    trace = xplane.trace()
+    trace = xplane.trace(run.trace_dir)
     if trace is None:
         return None
     by_scope = xplane.seconds_by_scope(trace["ops"])

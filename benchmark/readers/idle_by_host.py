@@ -4,10 +4,10 @@ Idle is the time outside the union of the device's operations, as
 ``device_idle_pct`` counts it. Each gap that a module's start ends goes to
 the thread whose launch started the module and is split by the innermost
 span that thread had open; gaps inside one execution of a module are the
-device's own. The slice's two edges, before the first and after the last
-operation, are printed with the table and left out of the share: the
-device's tracer records nothing there (``device_idle_pct`` counts them as
-idle). The whole table is printed on standard error. None, never 0, when
+device's own. The profile's two edges, before the first and after the last
+operation, are printed with the table and left out of the share, as
+``device_idle_pct`` leaves them out: the device's tracer records nothing
+there. The whole table is printed on standard error. None, never 0, when
 the trace holds no annotation of the program's."""
 
 import xplane
@@ -16,7 +16,7 @@ import xplane
 def read(run, params):
     if run.trace is None:
         return None
-    trace = xplane.trace()
+    trace = xplane.trace(run.trace_dir)
     if trace is None:
         return None
     window = None

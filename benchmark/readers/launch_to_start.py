@@ -10,7 +10,7 @@ import xplane
 def read(run, params):
     if run.trace is None:
         return None
-    trace = xplane.trace()
+    trace = xplane.trace(run.trace_dir)
     if trace is None:
         return None
     return xplane.launch_to_start_ms(trace["host"], trace["modules"],

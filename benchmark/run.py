@@ -25,6 +25,7 @@ T_START = time.perf_counter()
 
 import argparse
 import faulthandler
+import functools
 import importlib.util
 import json
 import os
@@ -60,8 +61,10 @@ class BenchFailure(Exception):
     pass
 
 
+@functools.lru_cache(maxsize=None)
 def module_at(path: Path):
-    """A reader or a reference: a module found by its file name."""
+    """A reader or a reference: a module found by its file name, loaded
+    once (a reader that prints a table prints it once a run)."""
     if not path.is_file():
         raise FileNotFoundError(f"no module {path}")
     spec = importlib.util.spec_from_file_location(path.stem, path)
@@ -471,9 +474,12 @@ def run_cell(args, after=None) -> int:
 
             trace = trace_reduce.reduce_planes(
                 trace_reduce.read_xplane(trace_dir))
-            trace["window_s"] = sliced[1] - sliced[0]
+            # the traced window is the device tracer's, first operation to
+            # last: busy time and window are then taken over the same time
+            # (the host's slice lies inside it, a few ms shorter)
             device["busy_s"] = trace["busy_s"]
-            device["window_s"] = trace["window_s"]
+            device["window_s"] = trace["traced_s"]
+            device["slice_s"] = sliced[1] - sliced[0]
 
         # the reference runs last: the window is closed, the peak is read,
         # the server is stopped; its time is in no metric
@@ -497,7 +503,7 @@ def run_cell(args, after=None) -> int:
             records=records, slice=sliced, trace=trace, config=config,
             total_rows=total_rows, rows_per_segment=rows_per_segment,
             setup=setup, workload=workload, seed=args.seed, cell=cell,
-            want=want,
+            want=want, trace_dir=trace_dir,
             peak=peaks.get(device["kind"]), t0=t0, gc_pauses=gc_pauses)
         metrics = {}
         if correct and not args.rehearse:
@@ -518,9 +524,13 @@ def run_cell(args, after=None) -> int:
         result = {"correct": correct, "attempted": len(records) + len(hung),
                   "failed": failed, "metrics": metrics, "device": device}
         if trace is not None and correct:
-            result["breakdown"] = {"device_ops": trace["device_modules"]
-                                   or trace["device_ops"],
-                                   "idle_gaps": trace["idle_gaps"]}
+            import xplane
+
+            spans = xplane.trace(trace_dir)
+            result["breakdown"] = {
+                "device_ops": trace["device_modules"] or trace["device_ops"],
+                "idle_gaps": xplane.idle_gaps(
+                    spans["host"], spans["modules"], spans["ops"])}
         by_class = {}
         for r in records:
             by_class.setdefault(r.cls, []).append((r.end - r.start) * 1e3)

@@ -35,18 +35,24 @@ WORKLOAD = traffic.Workload(traffic.load("traffic", "flight12-streams3"),
 def run():
     records = [
         # wholly inside the slice [10, 14]
-        record("ssb_q1_1", {"Y": 1993, "D": 1, "D2": 3, "Q": 25}, 10.0, 10.1,
+        record("ssb_q1_1", {"Y": 1993, "D": 1, "D2": 3, "Q": 25, "L": 10},
+               10.0, 10.1,
                [("BROKER_SCATTER", 90.0), ("BROKER_REDUCE", 1.0),
-                ("family_dispatch", 20.0), ("SERVER_COMBINE", 2.0)], 1, 1),
+                ("QUERY_PROCESSING", 80.0), ("SCHEDULER_WAIT", 1.0),
+                ("family_dispatch", 20.0), ("DEVICE_FETCH", 30.0),
+                ("SERVER_COMBINE", 2.0)], 1, 1),
         # half inside: 13.9 .. 14.1
-        record("ssb_q2_1", {"CAT": "MFGR#12", "R": "ASIA"}, 13.9, 14.1,
+        record("ssb_q2_1", {"CAT": "MFGR#12", "R": "ASIA", "L": 1000},
+               13.9, 14.1,
                [("BROKER_SCATTER", 150.0), ("BROKER_REDUCE", 30.0),
+                ("QUERY_PROCESSING", 140.0),
                 ("family_dispatch", 40.0), ("family_dispatch", 10.0),
-                ("SERVER_COMBINE", 60.0)], 280, 3),
+                ("DEVICE_FETCH", 25.0), ("SERVER_COMBINE", 60.0)], 280, 3),
     ]
+    # the device's tracer covered 3.0 s of the host's 4 s slice
     return types.SimpleNamespace(
         records=records, slice=(10.0, 14.0),
-        trace={"busy_s": 0.03, "window_s": 4.0}, config=CONFIG,
+        trace={"busy_s": 0.03, "traced_s": 3.0}, config=CONFIG,
         total_rows=67_108_864, setup={"build_s": 20.5}, workload=WORKLOAD,
         peak=PEAK, t0=9.0, gc_pauses=[(0, 0.01), (2, 0.5)])
 
@@ -60,8 +66,8 @@ def read(name, run):
 def test_span_metrics_are_means_of_sums_and_differences(run):
     assert read("broker_self_ms", run) == pytest.approx(
         ((100 - 90 - 1) + (200 - 150 - 30)) / 2)
-    assert read("server_host_ms", run) == pytest.approx(
-        ((90 - 20 - 2) + (150 - 50 - 60)) / 2)
+    assert read("server_self_ms", run) == pytest.approx(
+        ((80 - 1 - 20 - 30 - 2) + (140 - 50 - 25 - 60)) / 2)
     assert read("host_combine_ms", run) == pytest.approx((3 + 90) / 2)
 
 
@@ -75,7 +81,8 @@ def test_counts_classes_setup_and_gc(run):
 
 
 def test_device_metrics_weigh_requests_by_their_share_of_the_slice(run):
-    assert read("device_idle_pct", run) == pytest.approx(99.25)
+    # idle over what the device's tracer covered, not over the host's slice
+    assert read("device_idle_pct", run) == pytest.approx(99.0)
     assert read("device_ms_per_query", run) == pytest.approx(30.0 / 1.5)
     q1 = 67_108_864 * 10 + 8
     q2 = 67_108_864 * 9 + 280 * 3 * 8

@@ -18,9 +18,10 @@ WORKLOAD = traffic.Workload(traffic.load("traffic", "flight12-streams3"),
 
 
 def test_q1_1_reads_four_columns_and_returns_one_cell():
-    sql = WORKLOAD.render("ssb_q1_1", {"Y": 1993, "D": 1, "D2": 3, "Q": 25})
+    sql = WORKLOAD.render("ssb_q1_1",
+                          {"Y": 1993, "D": 1, "D2": 3, "Q": 25, "L": 10})
     assert sql.endswith("WHERE d_year = 1993 AND lo_discount BETWEEN 1 AND "
-                        "3 AND lo_quantity < 25")
+                        "3 AND lo_quantity < 25 LIMIT 10")
     assert roofline.columns_read(sql, CONFIG) == [
         "d_year", "lo_discount", "lo_quantity", "lo_extendedprice"]
     # d_year 1 + lo_discount 1 + lo_quantity 4 + lo_extendedprice 4 bytes
@@ -29,7 +30,7 @@ def test_q1_1_reads_four_columns_and_returns_one_cell():
 
 
 def test_q2_1_reads_five_columns_and_a_literal_is_not_a_column():
-    sql = WORKLOAD.render("ssb_q2_1", {"CAT": "MFGR#12", "R": "ASIA"},
+    sql = WORKLOAD.render("ssb_q2_1", {"CAT": "MFGR#12", "R": "ASIA", "L": 1000},
                           "SET trace = true; ")
     assert roofline.columns_read(sql, CONFIG) == [
         "d_year", "p_category", "p_brand1", "s_region", "lo_revenue"]

@@ -128,6 +128,24 @@ def test_idle_gaps_go_to_the_launching_threads_innermost_span():
     assert xplane.attributed_pct({}) is None
 
 
+def test_breakdown_gaps_carry_the_host_span_and_the_module_that_ended_them():
+    holed = [op for op in OPS + WARM_OP if op[1] != 30 * MS] \
+        + [("%fusion.2", 32 * MS, 28 * MS, "jit(scan_agg_x)/aggregate/mul:")]
+    orphan = MODULES + [("jit_other(1)", 200 * MS, MS)]
+    gaps = xplane.idle_gaps(HOST, orphan,
+                            holed + [("%x", 200 * MS, MS, "jit(other)/x:")])
+    assert gaps == [
+        [f"{xplane.NO_LAUNCH} before:jit_other", pytest.approx(0.049)],
+        ["DEVICE_FETCH before:jit__pad", pytest.approx(0.035)],
+        [f"{xplane.NO_SPAN} before:jit__pad", pytest.approx(0.020)],
+        ["GATHER_STACK before:jit_scan_agg_x", pytest.approx(0.010)],
+        ["QUERY_PROCESSING before:jit_scan_agg_x", pytest.approx(0.005)],
+        ["QUERY_PROCESSING before:jit__pad", pytest.approx(0.005)],
+        ["family_dispatch before:jit_scan_agg_x", pytest.approx(0.002)],
+        ["(inside jit_scan_agg_x)", pytest.approx(0.002)]]
+    assert len(xplane.idle_gaps(HOST, orphan, holed, top=3)) == 3
+
+
 @pytest.mark.parametrize("tf_op,scope", [
     ("jit(scan_x)/vmap(filter)/and:", "filter"),
     ("jit(scan_x)/group_by_dense/jit(_where)/select_n:", "group_by_dense"),
@@ -264,12 +282,12 @@ def test_span_attr_means_a_span_attribute_over_traced_requests():
 
 
 def test_trace_readers_on_the_hand_built_trace(monkeypatch):
-    monkeypatch.setattr(xplane, "_TRACE", [
-        {"start_ns": 1_000, "stop_ns": 1_000 + 151 * MS, "host": HOST,
-         "modules": MODULES, "ops": OPS + WARM_OP}])
+    monkeypatch.setattr(xplane, "_TRACES", {"here": {
+        "start_ns": 1_000, "stop_ns": 1_000 + 151 * MS, "host": HOST,
+        "modules": MODULES, "ops": OPS + WARM_OP}})
     # a slice 0.0-0.2 s holding one whole request and half of another
     run = types.SimpleNamespace(
-        trace={"busy_s": 0.074}, slice=(0.0, 0.2),
+        trace={"busy_s": 0.074}, slice=(0.0, 0.2), trace_dir="here",
         records=[_record(0.0, 0.1, [1]), _record(0.15, 0.25, [1])])
     assert _reader("launch_to_start").read(
         run, {"span": "family_dispatch", "module_prefix": "jit_scan_"}) \
@@ -291,9 +309,10 @@ def test_trace_readers_on_the_hand_built_trace(monkeypatch):
         assert _reader(name).read(run, params) is None
 
 
-def test_trace_readers_where_there_is_no_trace_file(monkeypatch):
-    monkeypatch.setattr(xplane, "_TRACE", [None])
+def test_trace_readers_where_there_is_no_trace_file(tmp_path, monkeypatch):
+    monkeypatch.setattr(xplane, "_TRACES", {})
     run = types.SimpleNamespace(trace={"busy_s": 1.0}, slice=(0.0, 1.0),
+                                trace_dir=tmp_path,
                                 records=[_record(0.0, 0.5, [1])])
     assert _reader("idle_by_host").read(run, {}) is None
     assert _reader("launch_to_start").read(
@@ -301,13 +320,15 @@ def test_trace_readers_where_there_is_no_trace_file(monkeypatch):
     assert _reader("device_by_scope").read(run, {"scopes": ["filter"]}) is None
 
 
-def test_trace_finds_the_newest_bench_trace_dir(tmp_path, monkeypatch):
-    monkeypatch.setattr(xplane.tempfile, "gettempdir", lambda: str(tmp_path))
-    monkeypatch.setattr(xplane, "_TRACE", [])
-    assert xplane.trace() is None
-    d = tmp_path / "bench_trace_x" / "plugins" / "profile" / "t"
+def test_trace_reads_the_runs_own_trace_dir_and_no_other(tmp_path,
+                                                         monkeypatch):
+    monkeypatch.setattr(xplane, "_TRACES", {})
+    mine, other = tmp_path / "bench_trace_a", tmp_path / "bench_trace_b"
+    mine.mkdir()
+    d = other / "plugins" / "profile" / "t"
     d.mkdir(parents=True)
     (d / "h.xplane.pb").write_bytes(_plane("/host:CPU", {}, {}, []))
-    monkeypatch.setattr(xplane, "_TRACE", [])
-    assert xplane.trace() == {"start_ns": None, "stop_ns": None, "host": {},
-                              "modules": [], "ops": []}
+    # a newer trace of another run beside it is not this run's
+    assert xplane.trace(mine) is None
+    assert xplane.trace(other) == {"start_ns": None, "stop_ns": None,
+                                   "host": {}, "modules": [], "ops": []}

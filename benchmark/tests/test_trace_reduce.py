@@ -29,18 +29,16 @@ def test_union_counts_overlap_once_and_skips_gaps():
     assert trace_reduce.union_seconds([]) == 0.0
 
 
+def test_traced_seconds_run_from_the_first_operation_to_the_last():
+    assert trace_reduce.traced_seconds(OPS) == pytest.approx(24_000 / 1e9)
+    assert trace_reduce.traced_seconds([]) == 0.0
+
+
 def test_seconds_by_name_sums_and_ranks():
     assert trace_reduce.seconds_by_name(MODULES) == [
         ["jit_run_program_batch", pytest.approx(9_000 / 1e9)],
         ["jit_pack_flat", pytest.approx(5_000 / 1e9)]]
     assert len(trace_reduce.seconds_by_name(OPS, top=2)) == 2
-
-
-def test_idle_gaps_are_named_by_what_ended_them():
-    gaps = dict(map(tuple, trace_reduce.idle_gaps(MODULES)))
-    assert gaps == {"before:jit_pack_flat": pytest.approx(12_000 / 1e9),
-                    "before:jit_run_program_batch":
-                        pytest.approx(5_000 / 1e9)}
 
 
 def test_reduce_averages_busy_time_over_chips():
@@ -51,6 +49,7 @@ def test_reduce_averages_busy_time_over_chips():
     out = trace_reduce.reduce_planes(planes)
     assert out["chips"] == 2
     assert out["busy_s"] == pytest.approx((12_000 + 4_000) / 2 / 1e9)
+    assert out["traced_s"] == pytest.approx((24_000 + 4_000) / 2 / 1e9)
     assert out["device_modules"][0][0] == "jit_run_program_batch"
 
 

@@ -1,6 +1,6 @@
 """From the profiler's trace to two things: when the device was busy, and
-which XLA module the busy time belongs to. Everything finer waits for the
-tracing issue (stable names per kernel, host spans on the profiler's clock).
+which XLA module the busy time belongs to. What is finer (scopes, host
+spans on the profiler's clock) is ``xplane.py``'s.
 
 Pure functions over (name, start_ns, duration_ns) events so that a small
 hand-built trace checks them; ``read_xplane`` is the only part that touches
@@ -30,6 +30,19 @@ def union_seconds(events: list) -> float:
     return busy / 1e9
 
 
+def traced_seconds(events: list) -> float:
+    """From the start of the first event to the end of the last: the time
+    the device's tracer covered, and so the time that busy seconds are a
+    share of. (The profile's own window, from the call that starts it to
+    the end of the call that stops it, is about a third of a second
+    longer and holds no operation there; the host's slice between those
+    two calls lies inside the traced time.)"""
+    if not events:
+        return 0.0
+    return (max(start + dur for _, start, dur in events)
+            - min(start for _, start, _ in events)) / 1e9
+
+
 def seconds_by_name(events: list, top: int = 10) -> list:
     sums = {}
     for name, _, dur in events:
@@ -38,38 +51,27 @@ def seconds_by_name(events: list, top: int = 10) -> list:
     return [[name, ns / 1e9] for name, ns in ranked]
 
 
-def idle_gaps(events: list, top: int = 10) -> list:
-    """Idle time between consecutive busy stretches, summed by the name of
-    the event that ended the gap: what the device then waited for."""
-    sums, end = {}, None
-    for name, start, dur in sorted(events, key=lambda e: e[1]):
-        if end is not None and start > end:
-            key = f"before:{name}"
-            sums[key] = sums.get(key, 0) + (start - end)
-        end = max(end or 0, start + dur)
-    ranked = sorted(sums.items(), key=lambda kv: -kv[1])[:top]
-    return [[name, ns / 1e9] for name, ns in ranked]
-
-
 def reduce_planes(planes: dict) -> dict:
     """``planes``: {plane name: {line name: [(name, start_ns, dur_ns)]}}
-    for the device planes only. Busy seconds are averaged over the chips."""
+    for the device planes only. Busy and traced seconds are averaged over
+    the chips."""
     if not planes:
         raise ValueError("the trace holds no device plane")
-    busy, ops, modules = [], [], []
+    busy, traced, ops, modules = [], [], [], []
     for name, lines in sorted(planes.items()):
         if OPS_LINE not in lines:
             raise ValueError(f"plane {name} has no {OPS_LINE!r} line; lines: "
                              f"{sorted(lines)}")
         busy.append(union_seconds(lines[OPS_LINE]))
+        traced.append(traced_seconds(lines[OPS_LINE]))
         ops += lines[OPS_LINE]
         modules += lines.get(MODULES_LINE, [])
     return {
         "busy_s": sum(busy) / len(busy),
+        "traced_s": sum(traced) / len(traced),
         "chips": len(busy),
         "device_ops": seconds_by_name(ops),
         "device_modules": seconds_by_name(modules),
-        "idle_gaps": idle_gaps(modules or ops),
     }
 
 

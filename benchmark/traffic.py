@@ -45,15 +45,17 @@ def space(qclass: dict) -> int:
 
 def literals(qclass: dict, index: int) -> dict:
     """Set number ``index`` of a class's literals: every ``params`` entry
-    from its ``range`` (whole numbers, both ends) or ``values``, then the
-    ``derived`` ones in file order: ``{"of": p, "plus": k}`` or
-    ``{"format": "..."}`` over the literals so far (``"int": true`` reads
-    the text as a number)."""
+    from its ``range`` (whole numbers, both ends) or ``values`` (a value
+    that is an object gives several literals that go together, under their
+    own names), then the ``derived`` ones in file order: ``{"of": p,
+    "plus": k}`` or ``{"format": "..."}`` over the literals so far
+    (``"int": true`` reads the text as a number)."""
     p = {}
     for name, spec in qclass.get("params", {}).items():
         if "values" in spec:
             index, i = divmod(index, len(spec["values"]))
-            p[name] = spec["values"][i]
+            value = spec["values"][i]
+            p.update(value if isinstance(value, dict) else {name: value})
         else:
             lo, hi = spec["range"]
             index, i = divmod(index, hi - lo + 1)

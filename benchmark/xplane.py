@@ -33,7 +33,6 @@ from __future__ import annotations
 import re
 import struct
 import sys
-import tempfile
 from pathlib import Path
 
 HOST_PLANE = "/host:CPU"
@@ -209,20 +208,18 @@ def read_xplane(path) -> dict:
             "modules": modules, "ops": ops}
 
 
-_TRACE: list = []
+_TRACES: dict = {}
 
 
-def trace():
-    """This process's trace, read once: ``run`` carries no ``trace_dir``,
-    and the newest ``bench_trace_*`` under the temporary directory is this
-    process's own while the readers run. None where there is none."""
-    if not _TRACE:
-        dirs = sorted(Path(tempfile.gettempdir()).glob("bench_trace_*"),
-                      key=lambda p: p.stat().st_mtime)
-        files = sorted(dirs[-1].rglob("*.xplane.pb"),
-                       key=lambda p: p.stat().st_mtime) if dirs else []
-        _TRACE.append(read_xplane(files[-1]) if files else None)
-    return _TRACE[0]
+def trace(trace_dir):
+    """The trace the run wrote under ``trace_dir`` (``run.trace_dir``), read
+    once per process. None where the directory holds none."""
+    key = str(trace_dir)
+    if key not in _TRACES:
+        files = sorted(Path(trace_dir).rglob("*.xplane.pb"),
+                       key=lambda p: p.stat().st_mtime)
+        _TRACES[key] = read_xplane(files[-1]) if files else None
+    return _TRACES[key]
 
 
 # -- pure functions ------------------------------------------------------------
@@ -318,45 +315,69 @@ def busy_stretches(ops: list) -> list:
     return [(a, b) for a, b in out]
 
 
-def idle_table(host: dict, modules: list, ops: list,
-               window: tuple = None) -> dict:
-    """{name: idle seconds} over the gaps between the device's busy
+def idle_by_span_and_module(host: dict, modules: list, ops: list) -> dict:
+    """{(name, module): idle ns} over the gaps between the device's busy
     stretches (the union of its operations, as ``device_idle_pct`` counts
-    busy). A gap that a module's start ends goes to the thread that
-    launched the module and is split by the innermost span that thread had
-    open (NO_SPAN where none, NO_LAUNCH where the launch is not in the
-    trace). A gap between two operations of one module's execution is the
-    device's own (``(inside <module>)``), and with ``window`` (start_ns,
-    end_ns) the time before the first and after the last operation is
-    listed too."""
+    busy); ``module`` is the one whose operation ended the gap. A gap that
+    a module's start ends goes to the thread that launched the module and
+    is split by the innermost span that thread had open (NO_SPAN where
+    none, NO_LAUNCH where the launch is not in the trace). A gap between
+    two operations of one module's execution is the device's own
+    (``(inside <module>)``)."""
     matched = match_launches(host, modules)
     spans = annotations(host)
     runs = sorted((start, start + dur, i)
                   for i, (_, start, dur) in enumerate(modules))
-    table = {}
+    out = {}
 
-    def add(name, ns):
+    def add(name, module, ns):
         if ns > 0:
-            table[name] = table.get(name, 0.0) + ns / 1e9
+            out[name, module] = out.get((name, module), 0) + ns
 
     stretches = busy_stretches(ops)
     for (_, lo), (hi, _) in zip(stretches, stretches[1:]):
         # the execution that the operation after the gap belongs to
         run = max((r for r in runs if r[0] <= hi < r[1]), default=None)
+        module = module_base(modules[run[2]][0]) if run else ""
         if run is None:
-            add(NO_LAUNCH, hi - lo)
+            add(NO_LAUNCH, module, hi - lo)
         elif run[0] <= lo:
-            add(f"(inside {module_base(modules[run[2]][0])})", hi - lo)
+            add(f"(inside {module})", module, hi - lo)
         elif run[2] in matched:
             for name, ns in _innermost(spans.get(matched[run[2]][0], []),
                                        lo, hi).items():
-                add(name, ns)
+                add(name, module, ns)
         else:
-            add(NO_LAUNCH, hi - lo)
+            add(NO_LAUNCH, module, hi - lo)
+    return out
+
+
+def idle_table(host: dict, modules: list, ops: list,
+               window: tuple = None) -> dict:
+    """{name: idle seconds}: ``idle_by_span_and_module`` summed over the
+    modules; with ``window`` (start_ns, end_ns) the time before the first
+    and after the last operation is listed too."""
+    table = {}
+    for (name, _), ns in idle_by_span_and_module(host, modules, ops).items():
+        table[name] = table.get(name, 0.0) + ns / 1e9
+    stretches = busy_stretches(ops)
     if window is not None and stretches:
-        add(SLICE_EDGES, stretches[0][0] - window[0])
-        add(SLICE_EDGES, window[1] - stretches[-1][1])
+        for ns in (stretches[0][0] - window[0], window[1] - stretches[-1][1]):
+            if ns > 0:
+                table[SLICE_EDGES] = table.get(SLICE_EDGES, 0.0) + ns / 1e9
     return table
+
+
+def idle_gaps(host: dict, modules: list, ops: list, top: int = 10) -> list:
+    """[[``<span> before:<module>``, seconds]], longest first: the result
+    line's ``breakdown.idle_gaps``, the device's idle time by what the host
+    was doing (the span open on the launching thread) and by the module
+    whose start ended the gap."""
+    ranked = sorted(idle_by_span_and_module(host, modules, ops).items(),
+                    key=lambda kv: -kv[1])[:top]
+    return [[name if name.startswith("(inside ") or not module else
+             f"{name} before:{module}", ns / 1e9]
+            for (name, module), ns in ranked]
 
 
 def _innermost(spans: list, lo: int, hi: int) -> dict:
