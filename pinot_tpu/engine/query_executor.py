@@ -66,6 +66,17 @@ def _estimate_bytes(inter) -> int:
     return 64
 
 
+def _groups_in(intermediates) -> int:
+    """Groups the segments' results bring to the combine (a traced
+    request's SERVER_COMBINE says so as `groupsFetched`)."""
+    from .results import GroupArrays
+
+    return sum(im.num_groups if isinstance(im, GroupArrays)
+               else len(im.groups)
+               for im in intermediates
+               if isinstance(im, GroupByIntermediate))
+
+
 @dataclass
 class Table:
     name: str
@@ -425,7 +436,10 @@ class QueryExecutor:
         intermediates = self._run_segments(query, kept, tracker, deadline,
                                            timeout_ms, cstats, planned)
         planned()  # no segment left to run: nothing closed it yet
-        with TRACING.scope(ServerQueryPhase.SERVER_COMBINE):
+        with TRACING.scope(ServerQueryPhase.SERVER_COMBINE) as span:
+            if span is not None:
+                span.set_attribute("groupsFetched",
+                                   _groups_in(intermediates))
             combined = self._combine(query, intermediates)
         num_dispatches, num_compiles = dispatch_counters()
         # the declared server-phase timer (reference ServerQueryPhase

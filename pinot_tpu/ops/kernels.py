@@ -621,7 +621,8 @@ def _run_program_impl(program: ir.Program, arrays: tuple, params: tuple, num_doc
         # Padded buckets (and row shards of them) are always 8-divisible.
         # bitorder matches every other packed bitmap in the repo
         # (segment/bitpack.py, aggregation.py occupancy words: little).
-        return (jnp.packbits(mask, bitorder="little"),)
+        with jax.named_scope("select"):
+            return (jnp.packbits(mask, bitorder="little"),)
 
     if program.mv_group_slot is not None and program.mode in (
             "group_by", "group_by_sparse"):

@@ -17,7 +17,7 @@ grouping). Per server shard the tree is
       family_dispatch           gather + enqueue, no sync
         GATHER_STACK            member planes to [S, ...] stacks
       DEVICE_FETCH              the host blocks until results are host arrays
-      SERVER_COMBINE
+      SERVER_COMBINE            `groupsFetched`: groups the segments bring
       RESPONSE_SERIALIZATION    datatable.encode
 
 under the broker's BROKER_SCATTER / BROKER_REDUCE. `to_json()` stays a

@@ -128,10 +128,12 @@ def test_pauseless_successor_consumes_during_commit(monkeypatch, tmp_path):
     observed = {"overlap": False}
 
     def slow_commit(mgr):
-        # committer dawdles between build and commitEnd: the successor must
-        # already be consuming (ingestion never paused)
+        # committer dawdles between build and commitEnd until the successor
+        # is seen consuming (ingestion never paused); under several test
+        # workers the successor's thread can take seconds to start, so it
+        # waits for the overlap, up to 10 s, not for one second
         t0 = time.time()
-        while time.time() - t0 < 1.0:
+        while time.time() - t0 < 10.0:
             with m._lock:
                 if m._committing and m._consuming:
                     observed["overlap"] = True
@@ -153,7 +155,9 @@ def test_pauseless_successor_consumes_during_commit(monkeypatch, tmp_path):
                             "n": 1} for i in range(10)])
         assert wait_until(
             lambda: sum(s.num_docs for s in m.segments) == 35)
-        assert observed["overlap"]  # committing + consuming coexisted
+        # committing + consuming coexisted (the committer reaches its hook
+        # once its build is done, which can be after the successor's rows)
+        assert wait_until(lambda: observed["overlap"])
         assert wait_until(lambda: len(m._segment_names) >= 1)
         assert wait_until(lambda: not m._committing)  # commit landed
         # everything stays queryable, exactly once
