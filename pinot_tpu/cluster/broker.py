@@ -19,13 +19,15 @@ import zlib
 from concurrent.futures import FIRST_COMPLETED, ThreadPoolExecutor, wait
 from typing import Optional
 
-from ..engine.combine import combine_aggregation, combine_group_by, combine_selection
+from ..engine.combine import (combine_aggregation, combine_group_arrays,
+                              combine_group_by, combine_selection)
 from ..engine.aggregation import semantics_for
 from ..engine.reduce import BrokerReducer
 from ..engine.perf_ledger import ALERTS, PERF_LEDGER
 from ..engine.results import (
     AggIntermediate,
     BrokerResponse,
+    GroupArrays,
     GroupByIntermediate,
     SelectionIntermediate,
 )
@@ -1570,6 +1572,14 @@ class Broker:
         aggish = [r for r in per_server if isinstance(r, AggIntermediate)]
         selish = [r for r in per_server if isinstance(r, SelectionIntermediate)]
         if groupish:
+            if all(isinstance(r, GroupArrays) for r in groupish):
+                # columnar tables merge as columns (the servers' own merge
+                # of their segments' tables; one server's passes through),
+                # and the reducer then orders columns: a dict of groups
+                # costs microseconds a group, seconds at 300 thousand
+                merged = combine_group_arrays(groupish)
+                if merged is not None:
+                    return merged
             return combine_group_by(groupish, semantics)
         if aggish:
             return combine_aggregation(aggish, semantics)

@@ -75,11 +75,14 @@ class VecAgg:
     fast path: extract pulls per-component numpy columns for ALL groups at
     once; spec gives each component's cross-segment merge op; fin_tag is a
     picklable finalize recipe evaluated by the broker reducer
-    (("id", c) | ("div", a, b) | ("sub", a, b) over component indices)."""
+    (("id", c) | ("div", a, b) | ("sub", a, b) over component indices);
+    outs names, per component, the kernel output the component is read
+    from (the device merge ranks a component where it stands)."""
 
     spec: tuple  # per component: "add" | "min" | "max"
     extract: Callable  # (outs, gids) -> tuple[np.ndarray, ...]
     fin_tag: tuple
+    outs: tuple = ()  # per component: index into the kernel's outputs
 
 
 @dataclass
@@ -420,14 +423,15 @@ def _lower_mv_value_agg(ctx: AggPlanContext, name: str, label: str,
         spec, tag = VEC_RECIPES["count"]
         return LoweredAgg(
             label, sem, lambda outs, g: int(outs[i][g]),
-            vec=VecAgg(spec, lambda outs, gids: (outs[i][gids],), tag))
+            vec=VecAgg(spec, lambda outs, gids: (outs[i][gids],), tag, (i,)))
     if name in ("summv", "minmv", "maxmv"):
         i = op(name[:-2])
         spec, tag = VEC_RECIPES[name[:-2]]
         return LoweredAgg(
             label, sem, lambda outs, g: float(outs[i][g]),
             vec=VecAgg(spec,
-                       lambda outs, gids: (outs[i][gids].astype(float),), tag))
+                       lambda outs, gids: (outs[i][gids].astype(float),), tag,
+                       (i,)))
     if name == "minmaxrangemv":
         i_min, i_max = op("min"), op("max")
         spec, tag = VEC_RECIPES["minmaxrange"]
@@ -437,7 +441,7 @@ def _lower_mv_value_agg(ctx: AggPlanContext, name: str, label: str,
             vec=VecAgg(spec,
                        lambda outs, gids: (outs[i_min][gids].astype(float),
                                            outs[i_max][gids].astype(float)),
-                       tag))
+                       tag, (i_min, i_max)))
     # avgmv: (sum of entries, COUNT OF ENTRIES — not docs)
     i_s, i_c = op("sum"), op("count")
     spec, tag = VEC_RECIPES["avg"]
@@ -446,7 +450,7 @@ def _lower_mv_value_agg(ctx: AggPlanContext, name: str, label: str,
         lambda outs, g: (float(outs[i_s][g]), int(outs[i_c][g])),
         vec=VecAgg(spec,
                    lambda outs, gids: (outs[i_s][gids].astype(float),
-                                       outs[i_c][gids]), tag))
+                                       outs[i_c][gids]), tag, (i_s, i_c)))
 
 
 _FILTERABLE = frozenset(("count", "sum", "min", "max", "avg", "minmaxrange"))
@@ -534,7 +538,7 @@ def lower_aggregation(ctx: AggPlanContext, expr: ExpressionContext,
         spec, tag = VEC_RECIPES["count"]
         return LoweredAgg(
             label, sem, lambda outs, g: int(outs[i][g]),
-            vec=VecAgg(spec, lambda outs, gids: (outs[i][gids],), tag))
+            vec=VecAgg(spec, lambda outs, gids: (outs[i][gids],), tag, (i,)))
 
     if name in ("sum", "min", "max"):
         i = _scalar_op(ctx, name, data[0], _cond)
@@ -543,7 +547,7 @@ def lower_aggregation(ctx: AggPlanContext, expr: ExpressionContext,
             label, sem, lambda outs, g: float(outs[i][g]),
             vec=VecAgg(spec,
                        lambda outs, gids, _i=i: (outs[_i][gids].astype(float),),
-                       tag))
+                       tag, (i,)))
 
     if name in ("countmv", "summv", "minmv", "maxmv", "avgmv", "minmaxrangemv"):
         return _lower_mv_value_agg(ctx, name, label, sem, data[0])
@@ -558,7 +562,7 @@ def lower_aggregation(ctx: AggPlanContext, expr: ExpressionContext,
             vec=VecAgg(spec,
                        lambda outs, gids: (outs[i_min][gids].astype(float),
                                            outs[i_max][gids].astype(float)),
-                       tag))
+                       tag, (i_min, i_max)))
 
     if name == "avg":
         i = _scalar_op(ctx, "sum", data[0], _cond)
@@ -571,7 +575,7 @@ def lower_aggregation(ctx: AggPlanContext, expr: ExpressionContext,
             vec=VecAgg(spec,
                        lambda outs, gids, _i=i, _c=c: (
                            outs[_i][gids].astype(float), outs[_c][gids]),
-                       tag))
+                       tag, (i, c)))
 
     # branches below don't have device null-skipping forms; under advanced
     # null handling a nullable operand routes to the host engine (which

@@ -188,6 +188,26 @@ DEFAULT_MIN_TRIM_SIZE = 5_000
 DEFAULT_TRIM_THRESHOLD = 1_000_000
 
 
+def trim_rule(query):
+    """(trim size, threshold, [(ORDER BY expression, ascending), ...]) of
+    the ordered server-level trim, or None where the query is never
+    trimmed: no ORDER BY, a HAVING, or a size or threshold SET to 0. The
+    expressions are canonical strings with aliases resolved, to be looked
+    up among the group keys and the aggregations."""
+    if not query.is_group_by or not query.order_by_expressions:
+        return None
+    opts = query.query_options
+    min_trim = int(opts.get("minServerGroupTrimSize", DEFAULT_MIN_TRIM_SIZE))
+    threshold = int(opts.get("groupTrimThreshold", DEFAULT_TRIM_THRESHOLD))
+    if min_trim <= 0 or threshold <= 0 or query.having_filter is not None:
+        return None
+    alias_map = {a: str(se) for se, a in
+                 zip(query.select_expressions, query.aliases) if a}
+    order = [(alias_map.get(str(ob.expression), str(ob.expression)),
+              ob.ascending) for ob in query.order_by_expressions]
+    return max((query.limit or 0) * 5, min_trim), threshold, order
+
+
 def trim_group_by(combined, query, semantics):
     """Trim an ordered group-by intermediate to max(5*limit, minTrimSize)
     groups when the group count exceeds the trim threshold (reference:
@@ -198,14 +218,10 @@ def trim_group_by(combined, query, semantics):
     aggregation — anything else (post-aggregation arithmetic, HAVING) keeps
     the full set, correctness over memory.
     """
-    if not query.is_group_by or not query.order_by_expressions:
+    rule = trim_rule(query)
+    if rule is None:
         return combined
-    opts = query.query_options
-    min_trim = int(opts.get("minServerGroupTrimSize", DEFAULT_MIN_TRIM_SIZE))
-    threshold = int(opts.get("groupTrimThreshold", DEFAULT_TRIM_THRESHOLD))
-    if min_trim <= 0 or threshold <= 0 or query.having_filter is not None:
-        return combined
-    trim_size = max((query.limit or 0) * 5, min_trim)
+    trim_size, threshold, order_exprs = rule
     num_groups = combined.num_groups if isinstance(combined, GroupArrays) \
         else len(combined.groups)
     if num_groups <= max(trim_size, 0) or num_groups <= threshold:
@@ -213,8 +229,6 @@ def trim_group_by(combined, query, semantics):
 
     group_strs = [str(g) for g in query.group_by_expressions]
     agg_strs = [str(a) for a in query.aggregations]
-    alias_map = {a: str(se) for se, a in
-                 zip(query.select_expressions, query.aliases) if a}
 
     if isinstance(combined, GroupArrays):
         colmap = {s: c for s, c in zip(group_strs, combined.key_cols)}
@@ -224,13 +238,11 @@ def trim_group_by(combined, query, semantics):
                                  combined.state_cols):
             colmap[s] = _apply_fin_tag(tag, comps)
         order = []
-        for ob in query.order_by_expressions:
-            key = str(ob.expression)
-            key = alias_map.get(key, key)
+        for key, ascending in order_exprs:
             col = colmap.get(key)
-            if col is None or (not ob.ascending and col.dtype == object):
+            if col is None or (not ascending and col.dtype == object):
                 return combined  # unsupported order expr: no trim
-            order.append((col, ob.ascending))
+            order.append((col, ascending))
         perm = np.arange(num_groups)
         for col, asc in reversed(order):
             vals = col[perm]
@@ -258,13 +270,9 @@ def trim_group_by(combined, query, semantics):
             return semantics[i].finalize(states[i])
         return None
 
-    order_exprs = []
-    for ob in query.order_by_expressions:
-        key = str(ob.expression)
-        key = alias_map.get(key, key)
-        if key not in group_strs and key not in agg_strs:
-            return combined
-        order_exprs.append((key, ob.ascending))
+    if any(key not in group_strs and key not in agg_strs
+           for key, _ in order_exprs):
+        return combined
 
     def rank(item):
         key, states = item

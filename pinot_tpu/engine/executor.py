@@ -311,6 +311,28 @@ def batch_family_key(segment: ImmutableSegment, plan: SegmentPlan,
     return key
 
 
+def batch_families(pairs: list, mesh: tuple = (), batch: bool = True):
+    """(plans, [(fkey, positions)]) of one query's (segment, plan) pairs:
+    the plans with their sorted tables sized alike (plan.share_table_size:
+    segments of one table then share a Program), grouped into batch
+    families by `batch_family_key`, in first-seen order; fkey is None for
+    a pair that cannot batch (unpredictable slot shapes, or `batch` off).
+    The one place where a query's plans become families: the dispatcher,
+    the device combine and EXPLAIN all come through here."""
+    from .plan import share_table_size
+
+    plans = share_table_size([plan for _, plan in pairs])
+    if len(pairs) < 2 or not batch:
+        return plans, [(None, [i]) for i in range(len(pairs))]
+    groups: dict = {}
+    for pos, ((segment, _), plan) in enumerate(zip(pairs, plans)):
+        fkey = batch_family_key(segment, plan, mesh)
+        groups.setdefault(("__solo__", pos) if fkey is None else fkey,
+                          []).append(pos)
+    return plans, [(None if k[0] == "__solo__" else k, positions)
+                   for k, positions in groups.items()]
+
+
 class TpuSegmentExecutor:
     """Executes one QueryContext against one segment on the device."""
 

@@ -106,6 +106,9 @@ def explain_plan(query, table, pruner, backend: str = "auto",
                 if p.mode == "group_by":
                     path = "mxu"
         desc += f", path:{path}"
+        if getattr(query, "explain", False) == "implementation" \
+                and plan.group_table_reason:
+            desc += f", why:{plan.group_table_reason}"
     kid = add(desc + ")", cid)
 
     if getattr(query, "explain", False) == "implementation" and \
@@ -131,16 +134,13 @@ def explain_plan(query, table, pruner, backend: str = "auto",
     if getattr(query, "explain", False) == "implementation" and \
             backend != "host" and len(kept) > 1:
         # stacked segment batching: families = device dispatches
-        # (query_executor._batch_families over the same host-side key the
-        # dispatcher groups by)
-        from .executor import batch_family_key
+        from .executor import batch_families
 
         if str(query.query_options.get("segmentBatch")).lower() in (
                 "false", "0", "off"):
             add("SEGMENT_BATCH(disabled)", cid)
         else:
-            fams: set = set()
-            planned = 0
+            members: list = []  # (segment, plan)
             for seg in kept:
                 pq, ps = query, seg
                 if use_star_tree and getattr(
@@ -154,12 +154,13 @@ def explain_plan(query, table, pruner, backend: str = "auto",
                     pl = SegmentPlanner(pq, ps).plan()
                 except UnsupportedQueryError:
                     continue
-                fk = batch_family_key(ps, pl)
-                fams.add(fk if fk is not None else ("solo", id(ps)))
-                planned += 1
-            if planned:
+                members.append((ps, pl))
+            if members:
+                # the dispatcher's own grouping (sorted tables of one
+                # query are sized alike before they are grouped)
+                _, fams = batch_families(members)
                 add(f"SEGMENT_BATCH(families:{len(fams)}, "
-                    f"segments:{planned})", cid)
+                    f"segments:{len(members)})", cid)
 
     for a in query.aggregations:
         # SQL-level functions; COUNT(*) answers from the shared per-group

@@ -297,7 +297,7 @@ class Program:
     # sparse mode: the SINGLE group key is a dict column whose id plane is
     # nondecreasing over the segment (ColumnMetadata.is_sorted — sorted
     # ingestion order, e.g. an order-key or time column). The kernel then
-    # skips lax.sort entirely: group runs are already contiguous, so edges
+    # never sorts the rows by key: group runs are already contiguous, so edges
     # come straight from transitions in the raw id plane (the reference's
     # SortedGroupByOperator analogue).
     keys_presorted: bool = False
@@ -319,7 +319,7 @@ class Program:
 def sparse_groupby_path(p: Program) -> str:
     """The sparse kernel variant a Program lowers to — mirrors the branch
     taken by ops/kernels._run_sparse_group_by so EXPLAIN IMPLEMENTATION can
-    name it without tracing the kernel: `sparse-presorted` skips lax.sort,
+    name it without tracing the kernel: `sparse-presorted` sorts no row by key,
     `sparse-sort+gather` sorts (key[, distinct_ids], iota32) and gathers the
     >=2 payload operands through the permutation, `sparse-sort` carries a
     single payload through the sort network directly."""

@@ -16,7 +16,7 @@ as default (BASELINE.json north star).
 from __future__ import annotations
 
 import re
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Optional
 
 import numpy as np
@@ -27,6 +27,7 @@ from ..query.filter import FilterContext, FilterNodeType, Predicate, PredicateTy
 from ..query.transforms import IRBuilder, eval_expr_np, get_transform
 from ..segment.device_cache import SegmentDeviceView
 from ..segment.loader import ImmutableSegment
+from ..ops import mxu_groupby
 from ..spi.data_types import DataType
 from . import ir
 from .aggregation import (DENSE_GROUP_LIMIT, AggPlanContext, LoweredAgg,
@@ -50,6 +51,16 @@ def _key_space_bucket(num_groups: int) -> int:
     bucket = 1 << max(0, int(num_groups) - 1).bit_length()
     i32_top = (1 << 31) - 2
     return i32_top if num_groups <= i32_top < bucket else bucket
+
+
+def table_bucket(card: int) -> int:
+    """Slots of a sort-based table that holds every key of a dictionary of
+    `card` entries: `card` rounded up to an eighth of its power of two, so
+    that segments whose dictionaries differ by a few entries share one
+    Program (and `executor.batch_family_key`) at no more than an eighth of
+    empty slots."""
+    step = 1 << max(0, int(card).bit_length() - 4)
+    return -(-int(card) // step) * step
 
 
 def _vexpr_uses_slots(ve, slots: set) -> bool:
@@ -169,6 +180,8 @@ class SegmentPlan:
     # (SET useFusedKernel = false — reference pattern: per-query engine
     # toggles like useStarTree applied by the plan maker)
     fused_ok: bool = True
+    # why the group table is dense or sorted (EXPLAIN IMPLEMENTATION)
+    group_table_reason: str = ""
 
     def gather_arrays(self, view: SegmentDeviceView) -> tuple:
         return self.gather_arrays_packed(view, allow_packed=False)[0]
@@ -201,6 +214,27 @@ class SegmentPlan:
             else:  # pragma: no cover
                 raise ValueError(kind)
         return tuple(out), tuple(packed)
+
+
+def share_table_size(plans: list) -> list:
+    """Sorted group tables of one query's segments, sized alike. A sorted
+    table's slots follow the segment's own dictionary (`table_bucket`) or
+    numGroupsLimit, whichever is less, so segments of one table can differ
+    in `num_groups` and `key_space` and in nothing else, and would then
+    compile and dispatch one by one. A table with more slots than its
+    segment can fill answers the same, and a table cut at numGroupsLimit is
+    never the smaller one, so every plan takes the largest of each; plans
+    that differ in more are returned as they are."""
+    programs = [pl.program for pl in plans]
+    if len(plans) < 2 or any(p.mode != "group_by_sparse" for p in programs):
+        return plans
+    size = dict(num_groups=max(p.num_groups for p in programs),
+                key_space=max(p.key_space for p in programs))
+    shared = [replace(p, **size) for p in programs]
+    if any(p != shared[0] for p in shared):
+        return plans
+    return [pl if pl.program == p else replace(pl, program=p)
+            for pl, p in zip(plans, shared)]
 
 
 class SegmentPlanner(AggPlanContext):
@@ -750,6 +784,41 @@ class SegmentPlanner(AggPlanContext):
             return ir.FNot(node) if p.type == PredicateType.NOT_IN else node
         raise UnsupportedQueryError(f"predicate {p.type} on raw column not lowered")
 
+    def _sorted_table_rule(self, group_exprs, group_dims, num_groups,
+                           any_derived, mv_group_slot, lowered):
+        """(sorted, reason) for a group-by the dense table could hold. One
+        identifier key over an integer dictionary with every aggregation a
+        columnar count/sum/min/max — the shapes the server merges and cuts
+        on the device (query_executor._try_sparse_device_combine) — gets a
+        sorted table from the first size the limb kernel leaves
+        (mxu_groupby.MAX_GROUPS slots): above it the dense table is filled
+        by one 32-bit scatter per limb over every row, whatever its size,
+        and the chip sweep (tools/groupby_crossover_sweep.py, PERF.md
+        section 6, PR 30) found the sort-based kernel ahead from there on at
+        every filter factor, so the crossover is no constant of its own.
+        Matrix ops, derived, MV, string and multiple keys stay dense: the
+        device merge takes none of them."""
+        if mxu_groupby.supports(num_groups + 1, 1):
+            return False, (f"{num_groups} keys fit the limb kernel's "
+                           f"{mxu_groupby.MAX_GROUPS} slots")
+        if len(group_exprs) != 1:
+            return False, f"{len(group_exprs)} keys: only one key is sorted"
+        if any_derived or not group_exprs[0].is_identifier:
+            return False, "a derived key"
+        if mv_group_slot is not None:
+            return False, "a multi-value key"
+        values = getattr(group_dims[0].dictionary, "values", None)
+        if values is None or not np.issubdtype(
+                np.asarray(values).dtype, np.integer):
+            return False, "the key's dictionary is not of integers"
+        for op in self.ops:
+            if op.kind not in _SPARSE_AGG_KINDS:
+                return False, f"{op.kind} needs the dense table"
+        if not self.ops or any(la.vec is None for la in lowered):
+            return False, "an aggregation without a columnar state"
+        return True, (f"one integer key of {num_groups} entries, above the "
+                      f"limb kernel's {mxu_groupby.MAX_GROUPS} slots")
+
     # -- top-level plan ----------------------------------------------------
     def plan(self) -> SegmentPlan:
         q = self.query
@@ -839,6 +908,10 @@ class SegmentPlanner(AggPlanContext):
                     dense_ok = False
                     dense_reason = f"{op.kind} occupancy {num_groups}x{width}"
             sparse = not dense_ok
+            # a table sorted BY THE RULE holds every key of the dictionary:
+            # nothing is trimmed in a segment, as in the dense table it
+            # replaces (numGroupsLimit keeps its meaning for the other two)
+            whole_table = False
             if not sparse and group_exprs and self.query.query_options.get(
                     "sparseGroupBy") in (True, "true", 1):
                 # per-query escape hatch (SET sparseGroupBy = true): route a
@@ -847,6 +920,11 @@ class SegmentPlanner(AggPlanContext):
                 # combine machinery without multi-million-cardinality data
                 sparse = True
                 dense_reason = "sparseGroupBy=true"
+            elif not sparse and group_exprs:
+                whole_table, dense_reason = self._sorted_table_rule(
+                    group_exprs, group_dims, num_groups, any_derived,
+                    mv_group_slot, lowered)
+                sparse = whole_table
             if sparse:
                 n_distinct = sum(1 for op in self.ops
                                  if op.kind == "distinct_bitmap")
@@ -909,7 +987,9 @@ class SegmentPlanner(AggPlanContext):
             if sparse and group_exprs:
                 # output capacity = numGroupsLimit: groups beyond it are
                 # trimmed on device (reference InstancePlanMakerImplV2:245-270)
-                limit = int(q.query_options.get(
+                capacity = table_bucket(num_groups) if whole_table \
+                    else num_groups
+                limit = capacity if whole_table else int(q.query_options.get(
                     "numGroupsLimit", DEFAULT_NUM_GROUPS_LIMIT))
                 # ORDER-BY pushdown: when the query orders by an ASC prefix
                 # of the group keys, the kernel's keep-smallest-L trim is
@@ -923,7 +1003,7 @@ class SegmentPlanner(AggPlanContext):
                     limit = trim
                     exact_trim = True
                 mode = "group_by_sparse"
-                out_groups = min(num_groups, max(1, limit))
+                out_groups = min(capacity, max(1, limit))
                 if out_groups > SPARSE_GROUPS_LIMIT:
                     # bound device output allocation the same way the dense
                     # path bounds its table
@@ -941,7 +1021,7 @@ class SegmentPlanner(AggPlanContext):
                 group_strides=tuple(strides),
                 num_groups=out_groups,
                 group_vexprs=tuple(group_vexprs) if any_derived else (),
-                key_space=(_key_space_bucket(num_groups)
+                key_space=(_key_space_bucket(capacity)
                            if mode == "group_by_sparse" else 0),
                 exact_trim=exact_trim,
                 keys_presorted=(keys_presorted
@@ -955,7 +1035,9 @@ class SegmentPlanner(AggPlanContext):
             )
             return SegmentPlan(program, self._slots, self._params,
                                lowered, group_dims,
-                               fused_ok=self._fused_ok())
+                               fused_ok=self._fused_ok(),
+                               group_table_reason=dense_reason
+                               if group_exprs else "")
 
         # selection: kernel computes the mask; host materializes rows.
         # Transform select/order expressions evaluate host-side over the

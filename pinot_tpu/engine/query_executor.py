@@ -27,9 +27,11 @@ from ..spi.data_types import Schema
 from .aggregation import UnsupportedQueryError, semantics_for
 from .combine import (combine_aggregation, combine_group_by,
                       combine_selection, trim_group_by)
+from .ir import program_label
+from .plan import table_bucket
 from ..ops.kernels import PackedOuts, fetch_packed_batch, unpack_outputs
 from .executor import (BatchFamilyMismatch, TpuSegmentExecutor,
-                       device_fetch, fetch_outputs,
+                       batch_families, device_fetch, fetch_outputs,
                        batch_family_key, dispatch_counters,
                        reset_dispatch_counters)
 from .host_executor import HostSegmentExecutor
@@ -75,6 +77,34 @@ def _groups_in(intermediates) -> int:
                else len(im.groups)
                for im in intermediates
                if isinstance(im, GroupByIntermediate))
+
+
+def _cut_order(query: QueryContext, plan):
+    """((output index | None, descending, ties_high), trim size, threshold)
+    where the ordered server-level trim (combine.trim_rule) ranks by
+    something the device merge can rank: ONE count or state column that an
+    aggregation finalizes to as it is, then the group key (or nothing: the
+    host's stable sort lets the lower key win a tie too), or the key
+    alone. None for any other ORDER BY: the host trims what it fetches."""
+    from .combine import trim_rule
+
+    rule = trim_rule(query)
+    if rule is None or len(query.group_by_expressions) != 1:
+        return None
+    trim_size, threshold, order = rule
+    key = str(query.group_by_expressions[0])
+    (expr, ascending), rest = order[0], order[1:]
+    if expr == key:
+        return (None, not ascending, False), trim_size, threshold
+    aggs = [str(a) for a in query.aggregations]
+    if expr not in aggs or (rest and rest[0][0] != key):
+        return None
+    vec = plan.lowered_aggs[aggs.index(expr)].vec
+    if vec.fin_tag[0] != "id":
+        return None
+    col = vec.outs[vec.fin_tag[1]]
+    ties_high = bool(rest) and not rest[0][1]
+    return (col, not ascending, ties_high), trim_size, threshold
 
 
 @dataclass
@@ -440,6 +470,12 @@ class QueryExecutor:
             if span is not None:
                 span.set_attribute("groupsFetched",
                                    _groups_in(intermediates))
+                if "groups_combined" in cstats:
+                    # a device merge: the groups that entered it (summed
+                    # over segments) and the size it was cut to, or 0
+                    span.set_attribute("groupsCombined",
+                                       cstats["groups_combined"])
+                    span.set_attribute("deviceCut", cstats["device_cut"])
             combined = self._combine(query, intermediates)
         num_dispatches, num_compiles = dispatch_counters()
         # the declared server-phase timer (reference ServerQueryPhase
@@ -574,8 +610,10 @@ class QueryExecutor:
 
         co_on = coalesce_enabled(query)
         rt_device = False  # any consuming segment answered on device
-        families = self._batch_families(
+        plans, families = self._batch_families(
             query, [(e[2], e[4]) for e in device_entries], mesh=msig)
+        device_entries = [e[:4] + (plan,)
+                          for e, plan in zip(device_entries, plans)]
         planned()
         for fkey, positions in families:
             entries = [device_entries[p] for p in positions]
@@ -883,25 +921,12 @@ class QueryExecutor:
         return (ndev,) if ndev > 1 else ()
 
     def _batch_families(self, query: QueryContext, pairs: list,
-                        mesh: tuple = ()) -> list:
-        """Group (segment, plan) pairs into batch families by the
-        host-side family key (engine/executor.py:batch_family_key).
-        Returns ordered (fkey, positions) groups; fkey is None for pairs
-        that can't batch (unpredictable slot shapes, or batching disabled)
-        — those take the per-segment path."""
-        if len(pairs) < 2 or not self._segment_batch_enabled(query):
-            return [(None, [i]) for i in range(len(pairs))]
-        groups: dict = {}
-        order: list = []
-        for pos, (segment, plan) in enumerate(pairs):
-            fkey = batch_family_key(segment, plan, mesh)
-            k = ("__solo__", pos) if fkey is None else fkey
-            if k not in groups:
-                groups[k] = []
-                order.append(k)
-            groups[k].append(pos)
-        return [(None if k[0] == "__solo__" else k, groups[k])
-                for k in order]
+                        mesh: tuple = ()):
+        """executor.batch_families under this query's options: (plans
+        with their sorted tables sized alike, ordered (fkey, positions)
+        groups; fkey None for pairs that take the per-segment path)."""
+        return batch_families(pairs, mesh,
+                              self._segment_batch_enabled(query))
 
     # device merge ops per sparse AggOp kind (count columns merge like sums)
     _SPARSE_COMBINE_KINDS = {"count": "add", "sum": "add", "sumsq": "add",
@@ -911,25 +936,24 @@ class QueryExecutor:
                                    check, cstats=None,
                                    planned=lambda: None):
         """Server-level merge ON DEVICE for multi-segment single-key sparse
-        group-bys: dispatch every segment's kernel, translate each key
-        column to dictionary VALUE space on device (dictionaries are
-        segment-local), merge the S tables with one sort/edge-reduce
-        (kernels.combine_sparse_group_tables), and fetch ONE merged table —
-        replacing S device→host table transfers + the host factorize/
-        scatter merge in combine_group_arrays. Restricted to shapes where
-        value-space keys are exact: one identifier group key over an
-        integer dictionary, vectorizable aggs only. Returns the 1-element
-        intermediates list, or None to fall back to the normal
-        per-segment collect + host merge (any failure here is recoverable
-        — nothing has been consumed)."""
+        group-bys: one batch dispatch of the segments' kernels, each key
+        column taken to dictionary VALUE space on device (dictionaries are
+        segment-local), the S tables merged by one sort that carries the
+        columns (kernels.merge_group_tables), and the merged table CUT on
+        the device where the ordered server-level trim would cut it
+        (combine.trim_group_by: same size, same conditions, the whole
+        ORDER BY) — so what crosses to the host is the cut, or the merged
+        groups, and never S tables for the host's factorize/scatter merge.
+        Restricted to shapes where value-space keys are exact: one
+        identifier group key over an integer dictionary, vectorizable aggs
+        only. Returns the 1-element intermediates list, or None to fall
+        back to the normal per-segment collect + host merge (any failure
+        here is recoverable — nothing has been consumed)."""
         if query.query_options.get("deviceCombine") in (False, "false", 0):
             return None
         import logging
 
         import numpy as np
-
-        from ..ops import kernels
-        from .results import GroupArrays
 
         plans, segs = [], []
         for segment in kept:
@@ -941,17 +965,18 @@ class QueryExecutor:
                 plans.append(self.tpu.plan(run_query, run_segment))
             except UnsupportedQueryError:
                 return None
+            if plans[-1].program.mode != "group_by_sparse":
+                return None
             segs.append(run_segment)
         p0 = plans[0].program
         kinds = tuple(self._SPARSE_COMBINE_KINDS.get(a.kind)
                       for a in p0.aggs)
         agg_kinds = tuple(a.kind for a in p0.aggs)
-        if p0.mode != "group_by_sparse" or not kinds or None in kinds:
+        if not kinds or None in kinds:
             return None
         for pl in plans:
             p = pl.program
-            if not (p.mode == "group_by_sparse"
-                    and p.group_strides == (1,)
+            if not (p.group_strides == (1,)
                     and len(p.group_slots) == 1
                     and not p.group_vexprs
                     and p.mv_group_slot is None
@@ -963,6 +988,11 @@ class QueryExecutor:
                         np.integer)
                     and all(la.vec is not None for la in pl.lowered_aggs)):
                 return None
+        # one table size for the query's segments (one Program, one
+        # family), before anything is keyed by a plan
+        msig = self._mesh_sig(query)
+        plans, families = self._batch_families(
+            query, list(zip(segs, plans)), mesh=msig)
         # two cache tiers for this path (cache/partial.py): the fully
         # merged host GroupArrays keyed by the ORDERED per-segment keys —
         # a hit is the whole warm repeat with ZERO device dispatches — and
@@ -995,83 +1025,12 @@ class QueryExecutor:
                             sp.set_attribute("cacheHitBytes",
                                              int(_estimate_bytes(hit)))
                     return [hit]
+            else:
+                keys = None
         try:
-            # one vmapped dispatch per batch family; members pull lazy
-            # device-side rows from the batched outputs (never fetched —
-            # the merged table below is the only D2H transfer)
-            member_outs: list = [None] * len(segs)
-            cached_tabs: dict = {}
-            if cache_on and keys is not None:
-                for i, k in enumerate(keys):
-                    if k is not None:
-                        tab = self.tpu.cache.get_partial(("sparse_tab",) + k)
-                        if tab is not None:
-                            cached_tabs[i] = tab
-            msig = self._mesh_sig(query)
-            families = self._batch_families(
-                query, list(zip(segs, plans)), mesh=msig)
-            planned()
-            for fkey, positions in families:
-                positions = [i for i in positions if i not in cached_tabs]
-                if not positions:
-                    continue
-                if fkey is not None and len(positions) > 1:
-                    try:
-                        # same batched-OOM discipline as _run_segments: a
-                        # transient OOM gets one eviction+retry, a persistent
-                        # one (or a family-key drift) falls back to the 1x-
-                        # footprint per-segment dispatch loop below instead
-                        # of abandoning the device combine entirely
-                        # (mesh-sharded dispatches arrive gathered to
-                        # device 0 so the table merge below colocates)
-                        outs_b, views_b = with_oom_retry(
-                            lambda: self.tpu.dispatch_plan_batch_raw(
-                                [segs[i] for i in positions],
-                                [plans[i] for i in positions], mesh=msig),
-                            keep_segment=segs[positions[0]],
-                            cache=self.tpu.cache)
-                    except (BatchFamilyMismatch, HbmExhaustedError):
-                        pass
-                    else:
-                        for row, i in enumerate(positions):
-                            member_outs[i] = (
-                                tuple(o[row] for o in outs_b), views_b[row])
-                        continue
-                for i in positions:
-                    member_outs[i] = self.tpu.dispatch_plan_raw(
-                        segs[i], plans[i])
-            seg_keys, seg_counts, seg_states = [], [], []
-            for done, (segment, pl) in enumerate(zip(segs, plans)):
-                check(done)
-                tab = cached_tabs.get(done)
-                if tab is not None:
-                    keys64, cnt, states = tab[0], tab[1], tuple(tab[2:])
-                    if cstats is not None:
-                        cstats["hit"] += 1
-                else:
-                    outs, view = member_outs[done]
-                    keys64 = kernels.ids_to_values_i64(
-                        outs[-1], view.dict_values(pl.group_dims[0].column))
-                    cnt = outs[0]
-                    states = tuple(outs[1:-1])
-                    if cache_on and keys is not None \
-                            and keys[done] is not None:
-                        self.tpu.cache.put_partial(
-                            ("sparse_tab",) + keys[done],
-                            (keys64, cnt) + states,
-                            segment_name=getattr(segment, "name", "?"))
-                        if cstats is not None:
-                            cstats["miss"] += 1
-                seg_keys.append(keys64)
-                seg_counts.append(cnt)
-                seg_states.append(states)
-            merged = kernels.combine_sparse_group_tables(
-                tuple(seg_keys), tuple(seg_counts), tuple(seg_states),
-                kinds)
-            # one flat D2H transfer for the whole query
-            pack = kernels.pack_outputs(merged)
-            with device_fetch():
-                outs_np = unpack_outputs(pack)
+            ga, stats = self._sparse_device_combine(
+                segs, plans, families, msig, kinds, _cut_order(query, plans[0]),
+                keys, check, cstats, planned)
         except TimeoutError:
             raise
         except Exception as e:
@@ -1084,20 +1043,6 @@ class QueryExecutor:
                 "sparse device combine failed (%s: %s); host merge "
                 "fallback", type(e).__name__, e)
             return None
-        counts = outs_np[0][:-1]
-        gids = np.nonzero(counts)[0]
-        trash = int(outs_np[0][-1])
-        dim = plans[0].group_dims[0]
-        key_col = outs_np[-1][gids].astype(dim.dictionary.values.dtype,
-                                           copy=False)
-        las = plans[0].lowered_aggs
-        ga = GroupArrays(
-            [key_col],
-            [la.vec.extract(outs_np, gids) for la in las],
-            [la.vec.spec for la in las],
-            [la.vec.fin_tag for la in las],
-            num_docs_scanned=int(counts.sum()) + trash,
-            groups_trimmed=trash > 0 and not p0.exact_trim)
         if merged_key is not None:
             from ..cache.partial import GLOBAL_PARTIAL_CACHE
 
@@ -1110,7 +1055,180 @@ class QueryExecutor:
             from ..realtime.device_plane import note_realtime_device_query
 
             note_realtime_device_query()
+        if cstats is not None:
+            cstats.update(stats)
         return [ga]
+
+    def _sparse_device_combine(self, segs, plans, families, msig, kinds,
+                               cut, keys, check, cstats, planned):
+        """The device half of `_try_sparse_device_combine`: (merged
+        GroupArrays, what the device merge counted). `cut` is the trim the
+        device may apply (`_cut_order`) or None. Raises on anything it
+        cannot do; the caller falls back."""
+        import numpy as np
+
+        from ..ops import kernels
+        from .combine import DEFAULT_TRIM_THRESHOLD
+        from .plan import DEFAULT_NUM_GROUPS_LIMIT
+        from .results import GroupArrays
+
+        column = plans[0].group_dims[0].column
+        dicts = [np.asarray(pl.group_dims[0].dictionary.values)
+                 for pl in plans]
+        filled = [d for d in dicts if len(d)]
+        lowest = min((int(d[0]) for d in filled), default=0)
+        highest = max((int(d[-1]) for d in filled), default=0)
+        # 64-bit sorts are emulated on the chip: merge on int32 keys
+        # wherever every dictionary's values fit (below the sentinel)
+        key32 = -(1 << 31) <= lowest and highest < (1 << 31) - 1
+        planned()
+        p0 = plans[0].program
+        slots = p0.num_groups
+        # a per-segment table in value space is kept on the device for a
+        # later request only at the sizes numGroupsLimit used to give: a
+        # table that holds a whole dictionary is four planes of a million
+        # slots a segment, for a hit that needs the same statement again
+        # over another set of segments
+        tabs_on = keys is not None and slots <= DEFAULT_NUM_GROUPS_LIMIT
+        cached_tabs: dict = {}
+        if tabs_on:
+            for i, k in enumerate(keys):
+                tab = self.tpu.cache.get_partial(("sparse_tab",) + k)
+                if tab is not None:
+                    cached_tabs[i] = tab
+        # one vmapped dispatch per batch family; its outputs stay on the
+        # device with their leading [S] dim (the merged table below is
+        # the only D2H transfer)
+        chunks: list = []  # (member positions, outs with a leading [S])
+        for fkey, positions in families:
+            positions = [i for i in positions if i not in cached_tabs]
+            if not positions:
+                continue
+            if fkey is not None and len(positions) > 1:
+                try:
+                    # same batched-OOM discipline as _run_segments: a
+                    # transient OOM gets one eviction+retry, a persistent
+                    # one (or a family-key drift) falls back to the 1x-
+                    # footprint per-segment dispatch loop below instead
+                    # of abandoning the device combine entirely
+                    # (mesh-sharded dispatches arrive gathered to
+                    # device 0 so the table merge below colocates)
+                    outs_b, _views = with_oom_retry(
+                        lambda: self.tpu.dispatch_plan_batch_raw(
+                            [segs[i] for i in positions],
+                            [plans[i] for i in positions], mesh=msig),
+                        keep_segment=segs[positions[0]],
+                        cache=self.tpu.cache)
+                except (BatchFamilyMismatch, HbmExhaustedError):
+                    pass
+                else:
+                    chunks.append((positions, tuple(outs_b)))
+                    continue
+            for i in positions:
+                outs, _view = self.tpu.dispatch_plan_raw(segs[i], plans[i])
+                chunks.append(([i], tuple(o[None] for o in outs)))
+        tables, how = [], []
+        done = 0
+        for positions, outs in chunks:
+            check(done)
+            done += len(positions)
+            members = [dicts[i] for i in positions]
+            if all(len(d) and int(d[-1]) - int(d[0]) == len(d) - 1
+                   for d in members):
+                # dictionaries of consecutive integers: value = first + id
+                source = np.asarray([d[0] for d in members], dtype=np.int64)
+                how.append("base")
+            else:
+                source = self._dict_plane_stack(
+                    [segs[i] for i in positions], column)
+                how.append("plane")
+            tables.append((outs[-1], source, outs[0], tuple(outs[1:-1])))
+            if keys is not None and cstats is not None:
+                cstats["miss"] += len(positions)
+            if tabs_on:
+                values = kernels.table_keys_to_values(
+                    outs[-1], source, how=how[-1])
+                for row, i in enumerate(positions):
+                    self.tpu.cache.put_partial(
+                        ("sparse_tab",) + keys[i],
+                        (values[row], outs[0][row])
+                        + tuple(o[row] for o in outs[1:-1]),
+                        segment_name=getattr(segs[i], "name", "?"))
+        for i, tab in sorted(cached_tabs.items()):
+            tables.append((tab[0][None], None, tab[1][None],
+                           tuple(t[None] for t in tab[2:])))
+            how.append("values")
+            if cstats is not None:
+                cstats["hit"] += 1
+        # what may cross blind: the merged groups are at most the slots
+        # merged and at most the integers between the dictionaries' ends
+        bound = min(len(segs) * slots, highest - lowest + 1)
+        order, trim_size, threshold = None, 0, 0
+        cut_slots = table_slots = 0
+        if cut is not None and bound > max(cut[1], cut[2]):
+            order, trim_size, threshold = cut
+            cut_slots = max(kernels.MIN_CUT_SLOTS,
+                            1 << (trim_size - 1).bit_length())
+        elif bound <= DEFAULT_TRIM_THRESHOLD:
+            table_slots = table_bucket(bound)
+        label = "merge_" + program_label(p0)
+
+        def merged(**size):
+            header, table = kernels.merge_group_tables(
+                tuple(tables), np.int64(trim_size), np.int64(threshold),
+                how=tuple(how), key32=key32, kinds=kinds, order=order,
+                **size)
+            return unpack_outputs(
+                kernels.pack_outputs((header,) + tuple(table), label))
+
+        with device_fetch():
+            # one flat D2H transfer for the whole query: the header and
+            # the cut, or the header and the merged table
+            header, *table_np = merged(cut_slots=cut_slots,
+                                       table_slots=table_slots)
+            groups, combined, scanned, trash, is_cut = (
+                int(x) for x in header)
+            if is_cut:
+                groups = trim_size
+            elif not table_slots:
+                # not cut after all (few groups, or a column the device
+                # cannot rank exactly), or too large a table to fetch
+                # blind: merged again into a table of the size the header
+                # gives, and fetched
+                _, *table_np = merged(
+                    cut_slots=0, table_slots=max(
+                        1 << 10, 1 << max(0, groups - 1).bit_length()))
+        gids = np.arange(groups)
+        dim = plans[0].group_dims[0]
+        key_col = table_np[-1][:groups].astype(dim.dictionary.values.dtype,
+                                               copy=False)
+        las = plans[0].lowered_aggs
+        ga = GroupArrays(
+            [key_col],
+            [la.vec.extract(table_np, gids) for la in las],
+            [la.vec.spec for la in las],
+            [la.vec.fin_tag for la in las],
+            num_docs_scanned=scanned,
+            groups_trimmed=trash > 0 and not p0.exact_trim)
+        return ga, {"groups_combined": combined,
+                    "device_cut": trim_size if is_cut else 0}
+
+    def _dict_plane_stack(self, segs: list, column: str):
+        """The segments' dictionary-values planes of `column` as one
+        (S, D) device array, zero-padded to the longest's bucket (pads are
+        never gathered) and kept with the family's other stacks."""
+        import jax.numpy as jnp
+
+        planes = [self.tpu._view_for(s).dict_values(column) for s in segs]
+        width = table_bucket(max(p.shape[0] for p in planes))
+
+        def build():
+            return jnp.stack([
+                p if p.shape[0] == width
+                else jnp.pad(p, (0, width - p.shape[0])) for p in planes])
+
+        pkey = ((column, "dict"), str(planes[0].dtype), (width,))
+        return self.tpu.cache.stacked_view(segs).plane(pkey, build)
 
     def _segment_route(self, query: QueryContext, segment):
         rewrite = None

@@ -582,7 +582,8 @@ def _run_program_batch(program: ir.Program, arrays: tuple, params: tuple,
     """Execute one Program over a stacked FAMILY of segments in a single
     dispatch: every plane in `arrays` and every param in `params` carries a
     leading batch dim [S, ...] (one row per member segment) and `num_docs`
-    is an (S,) vector. The body is `jax.vmap` of the exact per-segment
+    is an (S,) vector. The body is `jax.vmap` (`lax.map` for the sort-based
+    group-by) of the exact per-segment
     implementation, so each output gains a leading S dim and row s is
     bit-for-bit what `run_program(..., fused="")` would have produced for
     member s — the host slices outputs per segment after one transfer.
@@ -593,6 +594,25 @@ def _run_program_batch(program: ir.Program, arrays: tuple, params: tuple,
     take the reference `_run_program_impl` path.
     """
     arrays = _apply_packed(arrays, packed)
+
+    if program.mode == "group_by_sparse":
+        # the sort-based kernel runs member after member: a sort batched
+        # over 16 x 4,194,304 rows took the chip 1.8 times as long as 16
+        # sorts of one member each (PR 30: 572 and 600 ms a family against
+        # 314 and 374), and a member's `lax.cond` (the short tail of
+        # `_run_sparse_group_by`) stays a branch here where vmap would
+        # run both sides. The filter is batched like every family's; the
+        # scope is opened AROUND the loop because a trace files an op under
+        # the first scope of its name (`while/body/...` otherwise), and the
+        # profiler gives the loop's own op no name, so nothing counts twice
+        masks = jax.vmap(
+            lambda arrays_s, params_s, nd: _filter_mask(
+                program, arrays_s, params_s, nd, padded))(
+                    arrays, params, num_docs)
+        with jax.named_scope("group_by_sparse"):
+            return jax.lax.map(
+                lambda member: _run_masked(program, *member, padded),
+                (arrays, params, masks))
 
     def one(arrays_s, params_s, nd):
         return _run_program_impl(program, arrays_s, params_s, nd, padded)
@@ -606,14 +626,26 @@ run_program_batch = ProgramJit(_run_program_batch, "scan",
 
 def _run_program_impl(program: ir.Program, arrays: tuple, params: tuple, num_docs, padded: int,
                       row_offset=0):
-    n = padded
+    mask = _filter_mask(program, arrays, params, num_docs, padded, row_offset)
+    return _run_masked(program, arrays, params, mask, padded)
+
+
+def _filter_mask(program: ir.Program, arrays: tuple, params: tuple, num_docs,
+                 padded: int, row_offset=0):
+    """(padded,) bool: the rows below `num_docs` that pass the filter."""
     # the scopes name the program's parts in a profiler trace (an XLA
     # fusion is filed under the scope of its root instruction)
     with jax.named_scope("filter"):
-        mask = (jnp.arange(n, dtype=jnp.int32) + row_offset) < num_docs
+        mask = (jnp.arange(padded, dtype=jnp.int32) + row_offset) < num_docs
         if program.filter is not None:
-            mask &= _eval_filter(program.filter, arrays, params, n)
+            mask &= _eval_filter(program.filter, arrays, params, padded)
+    return mask
 
+
+def _run_masked(program: ir.Program, arrays: tuple, params: tuple, mask,
+                padded: int):
+    """The program's part after the filter, over the rows of `mask`."""
+    n = padded
     if program.mode == "selection":
         # ship the mask as a BITMAP (n/8 uint8), not one byte per row: a
         # 100M-row segment's selection leaf costs 12.5MB D2H instead of
@@ -849,8 +881,10 @@ def _run_sparse_group_by(program: ir.Program, arrays, params, mask, n):
         key   = Σ dict_ids[d] * stride[d]          (int64; masked → sentinel)
         sort  (key, agg inputs...) together        (lax.sort, one fused pass)
         first = key[i] != key[i-1]                 (segment boundaries)
-        gidx  = cumsum(first) - 1                  (dense 0-based group index)
-        out_k = segment_sum/min/max by gidx        (K+1 slots, K = groups limit)
+        scans = prefix sums / segmented scans      (running totals per group)
+        last  = key[i] != key[i+1]                 (a group's totals stand here)
+        out_k = the rows flagged `last`, moved to the first K slots by a
+                second sort keyed by the row index (`_GroupEnds`)
 
     Groups past numGroupsLimit (in key sort order) route to the trash slot —
     the same "stop creating new groups" trim semantics as the reference. The
@@ -860,8 +894,8 @@ def _run_sparse_group_by(program: ir.Program, arrays, params, mask, n):
     Two fast paths shave the sort cost (ir.sparse_groupby_path names the
     variant for EXPLAIN IMPLEMENTATION):
     - keys_presorted: the single group key plane is already nondecreasing in
-      doc order (sorted ingestion) — skip lax.sort entirely; group edges
-      come from transitions in the raw id plane.
+      doc order (sorted ingestion) — the rows are not sorted by key; group
+      edges come from transitions in the raw id plane.
     - sort-iota + gather: with >= 2 payload operands, sort only
       (key[, distinct_ids], iota32) and gather each payload through the
       permutation — (1+A)·n sorted bytes become ~2·n.
@@ -971,121 +1005,207 @@ def _run_sparse_group_by(program: ir.Program, arrays, params, mask, n):
             op[perm] for op in operands[num_sort_keys:])
     else:
         sorted_ops = jax.lax.sort(tuple(operands), num_keys=num_sort_keys)
-    skey_raw = sorted_ops[0]
-    valid = skey_raw < sentinel
-    if pack_card is not None:
-        # unpack: group key = high digits; the id low digit feeds the
-        # distinct branch. Sentinel rows' quotient stays huge (> any real
-        # key) so the sentinel-tail ordering survives the division.
-        skey = skey_raw // jnp.int32(pack_card)
-        packed_sids = skey_raw - skey * jnp.int32(pack_card)
-    else:
-        skey = skey_raw
-        packed_sids = None
-    first = jnp.concatenate(
-        [jnp.ones((1,), dtype=bool), skey[1:] != skey[:-1]]) & valid
-    gidx = _prefix_sum(first.astype(jnp.int32)) - 1
-    k = program.num_groups
+    def tail(sorted_ops):
+        n = sorted_ops[0].shape[0]
+        skey_raw = sorted_ops[0]
+        valid = skey_raw < sentinel
+        if pack_card is not None:
+            # unpack: group key = high digits; the id low digit feeds the
+            # distinct branch. Sentinel rows' quotient stays huge (> any real
+            # key) so the sentinel-tail ordering survives the division.
+            skey = skey_raw // jnp.int32(pack_card)
+            packed_sids = skey_raw - skey * jnp.int32(pack_card)
+        else:
+            skey = skey_raw
+            packed_sids = None
+        differs = skey[1:] != skey[:-1]
+        first = jnp.concatenate([jnp.ones((1,), dtype=bool), differs]) & valid
+        # a group's LAST row carries its totals: valid rows sort to the front,
+        # so a group ends where the next key differs (the sentinel tail's does)
+        last = jnp.concatenate([differs, jnp.ones((1,), dtype=bool)]) & valid
+        n_valid = valid.astype(jnp.int32).sum()
+        rows = jnp.arange(1, n + 1, dtype=jnp.int32)  # valid rows up to here
 
-    # ZERO scatters after the sort (each n-update scatter costs ~7.7ns/row
-    # on the TPU scatter unit — ~0.5s per payload at 64M rows): with keys
-    # sorted, group slot edges come from one vectorized binary search, and
-    # every per-group reduction becomes a prefix-scan diff / gather at the
-    # edges. Invalid rows sort to the sentinel tail; pin their gidx above
-    # every slot so edges never include them.
-    n_valid = valid.astype(jnp.int32).sum()
-    gidx_m = jnp.where(valid, gidx, jnp.int32(1 << 30))
-    edges = jnp.searchsorted(gidx_m, jnp.arange(k + 1, dtype=jnp.int32))
-    counts_k = (edges[1:] - edges[:-1]).astype(jnp.int64)
-    # trash slot counts valid-but-trimmed rows (invalid rows contribute 0),
-    # so the host can report every post-filter doc as scanned even when the
-    # numGroupsLimit trim drops groups
-    counts = jnp.concatenate(
-        [counts_k, (n_valid - edges[k]).astype(jnp.int64)[None]])
-    fi = edges[:k]
-    li = jnp.maximum(edges[1:] - 1, fi)  # clamp empty slots
-    occupied = counts_k > 0
+        cols = _running_columns(specs, sorted_ops, first)
+        table = _GroupEnds(last, skey, rows, cols, program.num_groups, n_valid)
 
-    def group_sums(prefix_incl, v_f64):
-        s = prefix_incl[li] - prefix_incl[fi] + v_f64[fi]
-        return jnp.where(occupied, s, 0.0)
+        outputs = [table.counts]
+        for spec, col in zip(specs, table.cols):
+            kind, oi = spec[0], spec[1]
+            agg = spec[2] if len(spec) > 2 else None
+            if kind == "count":
+                outputs.append(table.counts)
+            elif kind == "distinct":
+                card = agg.card
+                if oi is None:  # ids packed into the sort key's low digit
+                    sids = packed_sids
+                    uniq = jnp.concatenate(
+                        [jnp.ones((1,), dtype=bool),
+                         skey_raw[1:] != skey_raw[:-1]]) & valid
+                else:
+                    sids = sorted_ops[oi]  # dict ids, sorted within each group
+                    uniq = jnp.concatenate(
+                        [jnp.ones((1,), dtype=bool),
+                         differs | (sids[1:] != sids[:-1])]) & valid
+                bit = sids.astype(jnp.uint32)
+                words = []
+                for w in range(-(-card // 32)):
+                    # each (group, id) bit appears at most once (uniq-masked),
+                    # so the per-group OR equals the per-group SUM — one
+                    # wrapping uint32 cumsum + edge diffs (mod-2^32 prefix
+                    # differences are exact because every group sum < 2^32),
+                    # instead of a log2(n)-pass segmented scan
+                    val = jnp.where(uniq & ((bit >> 5) == jnp.uint32(w)),
+                                    jnp.uint32(1) << (bit & jnp.uint32(31)),
+                                    jnp.uint32(0))
+                    words.append(table.diff(table.at(_prefix_sum(val))))
+                outputs.append(_bitmap_rows(words))
+            elif col is None:
+                # unbounded int64 columns: f64 prefix DIFFS would round (the
+                # per-group result must stay exact) — keep the limb scatters
+                k = program.num_groups
+                gidx = _prefix_sum(first.astype(jnp.int32)) - 1
+                gid = jnp.where(valid & (gidx < k), gidx, jnp.int32(k))
+                outputs.append(_segment_sum_exact_i64(
+                    sorted_ops[oi], gid, k + 1, n, agg.vmin, agg.vmax,
+                    indices_are_sorted=True).astype(jnp.float64))
+            else:
+                outputs.append(table.output(kind, col))
+        outputs.append(table.keys)
+        return tuple(outputs)
 
-    outputs = [counts]
+    # valid rows sort to the front: where the filter keeps an eighth of the
+    # rows or fewer, everything after the sort (scans, and the table's sort)
+    # runs over the first eighth alone (a branch under `lax.map`, as a
+    # batch family runs; both sides under a vmap)
+    short = max(n // 8, 2048)  # whole blocks of `_sorted_prefix_f64`
+    if 2 * short > n:
+        return tail(sorted_ops)
+    return jax.lax.cond(
+        (sorted_ops[0] < sentinel).sum() <= short,
+        lambda ops: tail(tuple(o[:short] for o in ops)), tail, sorted_ops)
+
+
+def _bitmap_rows(words):
+    """(k+1, W) uint32 bitmap matrix from W per-slot word columns (k,);
+    the trash row is empty."""
+    matrix = jnp.stack(words, axis=1)
+    return jnp.concatenate(
+        [matrix, jnp.zeros((1, matrix.shape[1]), jnp.uint32)])
+
+
+def _running_columns(specs, ops, first) -> list:
+    """Per spec, the per-row column a group's slot value is read from at
+    the group's last row (None: count, distinct, or an int sum that keeps
+    its exact limb scatters): an inclusive prefix for exact int sums
+    (read as a difference, `_GroupEnds.diff`), a segmented running
+    reduction that restarts at `first` for the rest."""
+    cols = []
     for spec in specs:
         kind, oi = spec[0], spec[1]
         agg = spec[2] if len(spec) > 2 else None
-        if kind == "count":
-            outputs.append(counts)
-        elif kind == "distinct":
-            agg = spec[2]
-            card = agg.card
-            if oi is None:  # ids packed into the sort key's low digit
-                sids = packed_sids
-                uniq = jnp.concatenate(
-                    [jnp.ones((1,), dtype=bool),
-                     skey_raw[1:] != skey_raw[:-1]]) & valid
-            else:
-                sids = sorted_ops[oi]  # dict ids, sorted within each group
-                uniq = jnp.concatenate(
-                    [jnp.ones((1,), dtype=bool),
-                     (skey[1:] != skey[:-1]) | (sids[1:] != sids[:-1])]) & valid
-            bit = sids.astype(jnp.uint32)
-            cols = []
-            for w in range(-(-card // 32)):
-                # each (group, id) bit appears at most once (uniq-masked),
-                # so the per-group OR equals the per-group SUM — one
-                # wrapping uint32 cumsum + edge diffs (mod-2^32 prefix
-                # differences are exact because every group sum < 2^32),
-                # instead of a log2(n)-pass segmented scan
-                val = jnp.where(uniq & ((bit >> 5) == jnp.uint32(w)),
-                                jnp.uint32(1) << (bit & jnp.uint32(31)),
-                                jnp.uint32(0))
-                pw = _prefix_sum(val)
-                word = pw[li] - pw[fi] + val[fi]
-                cols.append(jnp.where(occupied, word, jnp.uint32(0)))
-            matrix = jnp.stack(cols, axis=1)  # (k, W) bitmap words
-            outputs.append(jnp.concatenate(
-                [matrix, jnp.zeros((1, matrix.shape[1]), jnp.uint32)]))
-        elif kind == "sum_i" and not _prefix_exact_gate(sorted_ops[oi], agg):
-            # unbounded int64 columns: f64 prefix DIFFS would round (the
-            # per-group result must stay exact) — keep the limb scatters
-            gid = jnp.where(valid & (gidx < k), gidx, jnp.int32(k))
-            outputs.append(_segment_sum_exact_i64(
-                sorted_ops[oi], gid, k + 1, n, agg.vmin, agg.vmax,
-                indices_are_sorted=True).astype(jnp.float64))
+        if kind in ("count", "distinct") or (
+                kind == "sum_i" and not _prefix_exact_gate(ops[oi], agg)):
+            cols.append(None)
         elif kind == "sum_i":
-            v = sorted_ops[oi]
-            sums = group_sums(_sorted_prefix_f64(v, agg), v.astype(jnp.float64))
-            outputs.append(jnp.concatenate([sums, jnp.zeros(1)]))
-        elif kind == "sum_f":
-            # f64 values: a GLOBAL prefix-diff would round each group to
+            cols.append(_sorted_prefix_f64(ops[oi], agg))
+        else:
+            # f64 sums: a GLOBAL prefix-diff would round each group to
             # ulp(global running total); the segmented tree scan keeps
             # rounding local to the group, like the scatter it replaces
-            s = _segmented_scan(sorted_ops[oi], first, jnp.add)[li]
-            outputs.append(jnp.concatenate(
-                [jnp.where(occupied, s, 0.0), jnp.zeros(1)]))
-        elif kind in ("min_i", "min_f"):
-            v = sorted_ops[oi]
-            smin = _segmented_scan(v, first, jnp.minimum)[li]
-            outputs.append(jnp.concatenate(
-                [jnp.where(occupied, smin.astype(jnp.float64), jnp.inf),
-                 jnp.full(1, jnp.inf)]))
-        else:  # max_i / max_f
-            v = sorted_ops[oi]
-            smax = _segmented_scan(v, first, jnp.maximum)[li]
-            outputs.append(jnp.concatenate(
-                [jnp.where(occupied, smax.astype(jnp.float64), -jnp.inf),
-                 jnp.full(1, -jnp.inf)]))
-    # surviving composite key per slot = the key at its left edge
-    keys_out = jnp.where(occupied,
-                         skey[jnp.clip(fi, 0, n - 1)].astype(jnp.int64),
-                         jnp.int64(-1))
-    outputs.append(keys_out)
-    return tuple(outputs)
+            op = {"sum": jnp.add, "min": jnp.minimum,
+                  "max": jnp.maximum}[kind[:3]]
+            cols.append(_segmented_scan(ops[oi], first, op))
+    return cols
+
+
+def _flagged_to_front(flag, cols, size: int):
+    """[row indices, *cols] of the rows where `flag` is set, in row order,
+    in the first slots of `size`-slot columns: ONE unstable sort keyed by
+    the row index (unflagged rows at the int32 sentinel) that carries the
+    columns. Slots past the last flagged row hold the sentinel index (and
+    whatever sorted there, or zeros past the rows' own count)."""
+    n = flag.shape[0]
+    row = jnp.where(flag, jnp.arange(n, dtype=jnp.int32), _I32_MAX)
+    out = jax.lax.sort((row,) + tuple(cols), num_keys=1, is_stable=False)
+    if size <= n:
+        return [o[:size] for o in out]
+    return [jnp.concatenate(
+        [o, jnp.full((size - n,), _I32_MAX if i == 0 else 0, o.dtype)])
+        for i, o in enumerate(out)]
+
+
+class _GroupEnds:
+    """A sort-based group table read off the rows that END a group.
+
+    Both sparse paths bring the rows of a group together (the sort, or the
+    segment's own order) and run prefix sums and segmented scans over
+    them, so a group's totals stand in the row that ends it. Those rows
+    move to the table's first slots by ONE unstable sort of (row index |
+    sentinel, key, running count, columns...): the sort network carries
+    the columns, and no slot is gathered. (The binary search this
+    replaces made log2(n) gathers a slot and one more per column: at a
+    table of a million slots that is tens of millions of gathers a
+    segment, each about 10 ns on the chip.) Groups past the table's k
+    slots are dropped in key order — the numGroupsLimit trim — and their
+    rows counted in the trash slot.
+
+    `last` flags the ending rows, `key` is the per-row key, `live` the
+    inclusive count of rows that take part, `cols` the per-row columns
+    (None entries pass through), `n_live` the count of all live rows."""
+
+    def __init__(self, last, key, live, cols, k: int, n_live):
+        n = last.shape[0]
+        out = _flagged_to_front(
+            last, [key, live] + [c for c in cols if c is not None], k)
+        self.row = out[0]
+        self.occupied = self.row < _I32_MAX
+        self.rowc = jnp.minimum(self.row, n - 1)
+        it = iter(out[3:])
+        self.cols = [None if c is None else next(it) for c in cols]
+        counts_k = self.diff(out[2]).astype(jnp.int64)
+        # trash slot counts live-but-trimmed rows, so the host can report
+        # every post-filter doc as scanned even when the numGroupsLimit
+        # trim drops groups
+        self.counts = jnp.concatenate(
+            [counts_k, (n_live.astype(jnp.int64) - counts_k.sum())[None]])
+        self.keys = jnp.where(self.occupied, out[1].astype(jnp.int64),
+                              jnp.int64(-1))
+
+    def diff(self, prefix):
+        """Per-slot totals from an inclusive prefix read at the ending
+        rows: the difference to the slot before (live rows between two
+        groups' ends belong to the later group; rows that take no part
+        add nothing to any prefix)."""
+        before = jnp.concatenate([jnp.zeros((1,), prefix.dtype), prefix[:-1]])
+        return jnp.where(self.occupied, prefix - before,
+                         jnp.zeros((), prefix.dtype))
+
+    def at(self, col):
+        """A per-row column NOT carried through the sort, gathered at the
+        ending rows (the bitmap words of a DISTINCT: a column a word)."""
+        return col[self.rowc]
+
+    def output(self, kind: str, col):
+        """(k+1,) f64 output column of a sum / min / max spec from its
+        carried running column (`_running_columns`)."""
+        if kind == "sum_i":
+            return self.padded(self.diff(col), 0.0)
+        if kind == "sum_f":
+            return self.padded(col, 0.0)
+        empty = jnp.inf if kind in ("min_i", "min_f") else -jnp.inf
+        return self.padded(col.astype(jnp.float64), empty)
+
+    def padded(self, slot_values, empty):
+        """(k+1,) output column: empty slots and the trash slot read
+        `empty`."""
+        v = jnp.where(self.occupied, slot_values, empty)
+        return jnp.concatenate([v, jnp.full((1,), empty, v.dtype)])
 
 
 def _presorted_sparse_tail(program: ir.Program, operands, specs, mask, n):
-    """Sorted-key fast path: ZERO lax.sort (reference SortedGroupByOperator).
+    """Sorted-key fast path: the rows are never sorted by key (reference
+    SortedGroupByOperator).
 
     The single key plane (operands[0], RAW — no sentinel) is nondecreasing
     over the segment (planner checked ColumnMetadata.is_sorted), so group
@@ -1095,7 +1215,7 @@ def _presorted_sparse_tail(program: ir.Program, operands, specs, mask, n):
     sentinel tail, so
 
     - a group exists only where a key run has >= 1 masked-in row, and the
-      run's FIRST such row opens the group — fully-masked runs must not
+      run's LAST row carries its totals — fully-masked runs must not
       consume numGroupsLimit slots, or an exact ORDER BY trim could drop a
       live group that a sorted-path run would keep;
     - per-group reductions skip masked rows via op identities (the operand
@@ -1103,57 +1223,33 @@ def _presorted_sparse_tail(program: ir.Program, operands, specs, mask, n):
 
     The padded tail (device planes pad dict id 0 past num_docs) would break
     the nondecreasing invariant, but those rows are always masked off
-    (run_program ANDs the doc-count iota mask), and masked rows only ever
-    contribute op identities here — a masked out-of-order row can at worst
-    sit inside the span [fi, li] of an earlier group, where its identity
-    value is harmless. Only MASKED-IN rows must be nondecreasing, which the
-    planner's is_sorted check guarantees.
+    (run_program ANDs the doc-count iota mask): they form runs of their own
+    after the last real row, or lengthen its run, and add op identities
+    either way. The one sort here is `_GroupEnds`': the ending rows move to
+    the table's first slots.
     """
     key = operands[0]
-    k = program.num_groups
-    first_key = jnp.concatenate(
-        [jnp.ones((1,), dtype=bool), key[1:] != key[:-1]])
-    # running masked-in row count within each key run (inclusive): the row
-    # where it first hits 1 opens that run's group
+    differs = key[1:] != key[:-1]
+    first_key = jnp.concatenate([jnp.ones((1,), dtype=bool), differs])
+    # running masked-in row count within each key run (inclusive): a run
+    # with none forms no group, and a run's LAST row carries its totals
     mrun = _segmented_scan(mask.astype(jnp.int32), first_key, jnp.add)
-    first = mask & (mrun == 1)
-    gidx = _prefix_sum(first.astype(jnp.int32)) - 1
-    # gidx is nondecreasing (-1 before the first live group), so slot edges
-    # still come from one vectorized binary search — same machinery as the
-    # sorted path, no scatters
-    edges = jnp.searchsorted(gidx, jnp.arange(k + 1, dtype=jnp.int32))
-    fi = edges[:k]
-    li = jnp.maximum(edges[1:] - 1, fi)
-    fic = jnp.clip(fi, 0, n - 1)
-    lic = jnp.clip(li, 0, n - 1)
-    occupied = jnp.arange(k, dtype=jnp.int32) < gidx[n - 1] + 1
-    # per-group masked-in row counts from one mask prefix sum: rows of later
-    # fully-masked runs inside [fi, li] contribute zero by construction
+    last = jnp.concatenate([differs, jnp.ones((1,), dtype=bool)]) \
+        & (mrun > 0)
+    # per-group masked-in row counts from one mask prefix sum: rows of
+    # fully-masked runs between two groups contribute zero by construction
     pm = _prefix_sum(mask.astype(jnp.int32))
-    counts_k = jnp.where(
-        occupied, pm[lic] - pm[fic] + mask[fic].astype(jnp.int32),
-        0).astype(jnp.int64)
-    n_valid = pm[n - 1].astype(jnp.int64)
-    counts = jnp.concatenate([counts_k, (n_valid - counts_k.sum())[None]])
-    # a group's span [fi, li] may run past its own key run into later
-    # FULLY-masked runs (which never opened a group) — segmented scans reset
-    # at those run boundaries, so scan-based reductions must read at the
-    # last row of the group's OWN run, not at li. Mask/value prefix-diffs
-    # don't care (masked rows contribute exact zeros globally).
-    run_id = _prefix_sum(first_key.astype(jnp.int32)) - 1  # nondecreasing
-    rlast = jnp.clip(
-        jnp.searchsorted(run_id, run_id[fic], side="right") - 1, 0, n - 1)
 
-    def group_sums(prefix_incl, v_f64):
-        s = prefix_incl[lic] - prefix_incl[fic] + v_f64[fic]
-        return jnp.where(occupied, s, 0.0)
+    # (masked rows hold their op's identity: the operand loop saw to it)
+    cols = _running_columns(specs, operands, first_key)
+    table = _GroupEnds(last, key, pm, cols, program.num_groups, pm[n - 1])
 
-    outputs = [counts]
-    for spec in specs:
+    outputs = [table.counts]
+    for spec, col in zip(specs, table.cols):
         kind, oi = spec[0], spec[1]
         agg = spec[2] if len(spec) > 2 else None
         if kind == "count":
-            outputs.append(counts)
+            outputs.append(table.counts)
         elif kind == "distinct":
             # ids are NOT sorted within a run here (no sort happened), so
             # the sorted path's uniq-row trick is unavailable — but OR is
@@ -1161,45 +1257,29 @@ def _presorted_sparse_tail(program: ir.Program, operands, specs, mask, n):
             # same per-group bitmap words without dedup
             card = agg.card
             bit = operands[oi].astype(jnp.uint32)
-            cols = []
+            words = []
             for w in range(-(-card // 32)):
                 val = jnp.where(mask & ((bit >> 5) == jnp.uint32(w)),
                                 jnp.uint32(1) << (bit & jnp.uint32(31)),
                                 jnp.uint32(0))
-                word = _segmented_scan(val, first_key, jnp.bitwise_or)[rlast]
-                cols.append(jnp.where(occupied, word, jnp.uint32(0)))
-            matrix = jnp.stack(cols, axis=1)
-            outputs.append(jnp.concatenate(
-                [matrix, jnp.zeros((1, matrix.shape[1]), jnp.uint32)]))
-        elif kind == "sum_i" and not _prefix_exact_gate(operands[oi], agg):
+                word = table.at(
+                    _segmented_scan(val, first_key, jnp.bitwise_or))
+                words.append(jnp.where(table.occupied, word, jnp.uint32(0)))
+            outputs.append(_bitmap_rows(words))
+        elif col is None:
             # unbounded int64 columns keep the exact limb scatters; indices
             # are NOT flagged sorted (masked rows scatter into the trash)
+            k = program.num_groups
+            first = mask & (mrun == 1)
+            gidx = _prefix_sum(first.astype(jnp.int32)) - 1
             gid = jnp.where(mask & (gidx >= 0) & (gidx < k),
                             gidx, jnp.int32(k))
             outputs.append(_segment_sum_exact_i64(
                 operands[oi], gid, k + 1, n, agg.vmin, agg.vmax,
                 indices_are_sorted=False).astype(jnp.float64))
-        elif kind == "sum_i":
-            v = operands[oi]  # masked rows already zeroed
-            sums = group_sums(_sorted_prefix_f64(v, agg),
-                              v.astype(jnp.float64))
-            outputs.append(jnp.concatenate([sums, jnp.zeros(1)]))
-        elif kind == "sum_f":
-            s = _segmented_scan(operands[oi], first_key, jnp.add)[rlast]
-            outputs.append(jnp.concatenate(
-                [jnp.where(occupied, s, 0.0), jnp.zeros(1)]))
-        elif kind in ("min_i", "min_f"):
-            smin = _segmented_scan(operands[oi], first_key, jnp.minimum)[rlast]
-            outputs.append(jnp.concatenate(
-                [jnp.where(occupied, smin.astype(jnp.float64), jnp.inf),
-                 jnp.full(1, jnp.inf)]))
-        else:  # max_i / max_f
-            smax = _segmented_scan(operands[oi], first_key, jnp.maximum)[rlast]
-            outputs.append(jnp.concatenate(
-                [jnp.where(occupied, smax.astype(jnp.float64), -jnp.inf),
-                 jnp.full(1, -jnp.inf)]))
-    keys_out = jnp.where(occupied, key[fic].astype(jnp.int64), jnp.int64(-1))
-    outputs.append(keys_out)
+        else:
+            outputs.append(table.output(kind, col))
+    outputs.append(table.keys)
     return tuple(outputs)
 
 
@@ -1484,86 +1564,234 @@ def _run_agg(agg: ir.AggOp, arrays, params, mask, gid, num_segments, n,
 # empty merged-table slots carry this key; above any real dictionary VALUE
 # (sparse value-space keys are int64 dictionary values, not composite ids)
 COMBINE_KEY_SENTINEL = 1 << 62
+# a cut keeps this many slots at least: `k` is an argument of the program,
+# the slots it is cut to are a shape (a power of two at or above `k`)
+MIN_CUT_SLOTS = 1 << 13
+# tables above this many slots get the merge's branch on how full they are:
+# a table cut at numGroupsLimit (100,000 by default) is full where it
+# matters, its merge is a few ms, and a second branch doubles the sorts the
+# chip's compiler is given (PR 30: 292 s for 146 on the sandbox's host)
+QUARTER_MERGE_ABOVE_SLOTS = 100_000
 
 
-@jax.jit
-def ids_to_values_i64(keys, dict_plane):
-    """Translate one segment's sparse key output (dict IDS; -1 = empty slot)
-    into dictionary VALUE space. Dictionaries are segment-local (the same id
+def _key_sentinel(dtype):
+    return jnp.asarray(_I32_MAX if dtype == jnp.int32
+                       else COMBINE_KEY_SENTINEL, dtype)
+
+
+def _table_values(keys, source, how: str, dtype):
+    """A family's sparse key outputs (S, K) (dict IDS; -1 = empty slot) in
+    dictionary VALUE space. Dictionaries are segment-local (the same id
     means different values in different segments — engine/results.py), so
-    cross-segment merge keys must be values. int64 holds every integer dict
-    exactly; empty slots map to the sort sentinel so they tail the merge."""
-    card = dict_plane.shape[0]
-    ids = jnp.clip(keys, 0, card - 1).astype(jnp.int32)
-    return jnp.where(keys >= 0, dict_plane[ids].astype(jnp.int64),
-                     jnp.int64(COMBINE_KEY_SENTINEL))
+    cross-segment merge keys must be values. `how` says what `source` is:
+    "base": dictionaries of consecutive integers, value = first value (S,)
+    + id, no plane read; "plane": the dictionaries' values (S, D),
+    zero-padded past each segment's own entries, which no id reaches,
+    gathered by id; "values": `keys` are int64 values already (a table kept
+    from an earlier request, `table_keys_to_values`). Empty slots map to
+    the sort sentinel so they tail the merge."""
+    if how == "values":  # int64, empty slots at COMBINE_KEY_SENTINEL
+        return jnp.where(keys < COMBINE_KEY_SENTINEL, keys,
+                         _key_sentinel(dtype)).astype(dtype)
+    if how == "base":
+        vals = keys + source[:, None]
+    else:
+        ids = jnp.clip(keys, 0, source.shape[1] - 1).astype(jnp.int32)
+        vals = jnp.take_along_axis(source, ids, axis=1)
+    return jnp.where(keys >= 0, vals.astype(dtype), _key_sentinel(dtype))
 
 
-@partial(jax.jit, static_argnames=("kinds",))
-def combine_sparse_group_tables(seg_keys, seg_counts, seg_states, kinds):
-    """Merge S per-segment sparse group tables ON DEVICE.
+@partial(jax.jit, static_argnames=("how",))
+def table_keys_to_values(keys, source, how: str):
+    """`_table_values` as a program of its own: the int64 value-space key
+    column of a table that is kept on the device for a later request."""
+    return _table_values(keys, source, how, jnp.int64)
 
-    Replaces the host-side factorize+scatter merge (combine.py
-    combine_group_arrays) for single-key sparse group-bys: per-segment
-    tables are already key-sorted, so the merge is the SAME
-    sort/edges/segmented-scan machinery as _run_sparse_group_by, over
-    S*K rows instead of n docs — and only the merged table crosses to host.
 
-    seg_keys:   S × (K,) int64 VALUE-space keys (ids_to_values_i64 output)
-    seg_counts: S × (K+1,) int64 count columns (slot K = trash)
-    seg_states: S × tuple of (K+1,) state columns (one per Program agg op,
-                in op order — count copies are int64, the rest f64)
-    kinds:      per state column: "add" | "min" | "max" (static)
+def _merge_group_tables(tables, k, threshold, *, how, key32: bool, kinds,
+                        order, cut_slots: int, table_slots: int):
+    """Merge a server's per-segment sparse group tables ON DEVICE, and cut
+    the merged table there where the ordered server-level trim would
+    (combine.trim_group_by): only what the host needs crosses.
 
-    Returns (counts(M+1) i64, *states(M+1), keys(M) i64) with M = S*K — the
-    per-segment output layout, so LoweredAgg.vec.extract decodes it
-    unchanged. All merged groups are kept (M slots hold the worst-case
-    union) for bit-for-bit parity with the host merge; the ordered
-    server-level trim still runs downstream on the single merged table.
-    """
-    key = jnp.concatenate(seg_keys)
-    cnt = jnp.concatenate([c[:-1] for c in seg_counts])
-    trash = sum(c[-1] for c in seg_counts)
-    states = [jnp.concatenate([s[i][:-1] for s in seg_states])
-              for i in range(len(kinds))]
+    tables:  one entry a batch family (or lone segment), each
+             (keys (S_i, K), source, counts (S_i, K+1), states): the
+             sparse kernel's key column with what takes it to value space
+             (`_table_values`, `how[i]`), its int64 count column (slot K =
+             trash) and its (S_i, K+1) state columns, one per Program agg
+             op in op order (count copies are int64, the rest f64)
+    k, threshold: the trim's size and threshold (arguments, not shapes)
+    key32:   merge on int32 keys (every dictionary's values fit; 64-bit
+             sorts are emulated on the chip)
+    kinds:   per state column "add" | "min" | "max"
+    order:   None, or (output index | None, descending, ties_high): the
+             trim's ORDER BY as the device ranks it — one count or state
+             column (None: the key alone), then the key (merged rows are
+             in ascending key order, so a tie falls by the lower row, or
+             by the higher under `ties_high`)
+    cut_slots:   slots of the cut table (0: no cut in this program)
+    table_slots: slots of the whole merged table (0: it is not made)
+
+    Returns (header, table):
+      header int64 (5,): merged groups, groups entering the merge, docs
+             scanned (trash included), trash, and whether `table` is the
+             CUT (1) — else it is the whole table, or () where neither was
+             asked for: the host reads the merged groups' count and asks
+             again for a table of that size.
+      table  (counts (T+1,), *states (T+1,), keys (T,)) in the per-segment
+             output layout, so LoweredAgg.vec.extract decodes it unchanged;
+             occupied slots first, in ascending key order.
+
+    A segment's table holds its groups in its first slots, and a filter
+    seldom leaves a group for every key of a dictionary: where no segment
+    filled more than a quarter of its slots, the merge runs over the first
+    quarters alone (`lax.cond`; the same program on a quarter of the rows).
+    Tables of up to QUARTER_MERGE_ABOVE_SLOTS slots merge whole."""
+    slots = tables[0][0].shape[1]
+
+    def merge(width: int):
+        dtype = jnp.int32 if key32 else jnp.int64
+        key = jnp.concatenate([
+            _table_values(t[0][:, :width], t[1], h, dtype).reshape(-1)
+            for t, h in zip(tables, how)])
+        cnt = jnp.concatenate([t[2][:, :width].reshape(-1) for t in tables])
+        cols = [jnp.concatenate([t[3][i][:, :width].reshape(-1)
+                                 for t in tables])
+                for i in range(len(kinds))]
+        return _merge_rows(key, cnt, cols, trash, k, threshold,
+                           kinds=kinds, order=order, cut_slots=cut_slots,
+                           table_slots=table_slots)
+
+    # the scope is opened around the branch: a trace files an op under the
+    # first scope of its name (`cond/branch_*` otherwise)
+    with jax.named_scope("combine"):
+        filled = jnp.max(jnp.stack(
+            [(t[2][:, :-1] > 0).sum(axis=1).max() for t in tables]))
+        trash = sum(t[2][:, -1].sum() for t in tables)
+        if slots <= QUARTER_MERGE_ABOVE_SLOTS:
+            return merge(slots)
+        quarter = slots // 4
+        return jax.lax.cond(filled <= quarter, lambda: merge(quarter),
+                            lambda: merge(slots))
+
+
+def _merge_rows(key, cnt, cols, trash, k, threshold, *, kinds, order,
+                cut_slots: int, table_slots: int):
+    """`_merge_group_tables` over the tables' slots laid end to end.
+
+    Per-segment tables are key-sorted, so the merge is the per-segment
+    kernel's own machinery over all the slots: one sort by key that
+    carries the columns, segmented scans, and a group's totals in the row
+    that ends it. The cut ranks an int64 exactly: a sum is an f64 that
+    holds an integer (never through float32), thousands of groups share a
+    value, and the k-th value is found by bisection on counts — no second
+    sort; of the rows that tie with it, the ORDER BY's key takes the first.
+    A column that holds a fraction, or an integer past 2^62, is not cut
+    (header), and the host trims as before."""
     m = key.shape[0]
-    # sort-iota + gather, same as the n-row kernel: permute only (key, iota)
-    skey, perm = jax.lax.sort(
-        (key, jnp.arange(m, dtype=jnp.int32)), num_keys=1)
-    cnt = cnt[perm]
-    states = [s[perm] for s in states]
-    valid = skey < jnp.int64(COMBINE_KEY_SENTINEL)
-    first = jnp.concatenate(
-        [jnp.ones((1,), dtype=bool), skey[1:] != skey[:-1]]) & valid
-    gidx = _prefix_sum(first.astype(jnp.int32)) - 1
-    gidx_m = jnp.where(valid, gidx, jnp.int32(1 << 30))
-    edges = jnp.searchsorted(gidx_m, jnp.arange(m + 1, dtype=jnp.int32))
-    fi = edges[:m]
-    li = jnp.maximum(edges[1:] - 1, fi)
-    fic = jnp.clip(fi, 0, m - 1)
-    lic = jnp.clip(li, 0, m - 1)
-    occupied = edges[1:] > edges[:-1]
-    pc = _prefix_sum(jnp.where(valid, cnt, 0))
-    counts_m = jnp.where(
-        occupied,
-        pc[lic] - pc[fic] + jnp.where(valid[fic], cnt[fic], 0), 0)
-    outs = [jnp.concatenate([counts_m, trash[None]])]
-    for v, kind in zip(states, kinds):
-        if kind == "add":
-            vz = jnp.where(valid, v, jnp.zeros((), v.dtype))
-            s = _segmented_scan(vz, first, jnp.add)[lic]
-            merged = jnp.where(occupied, s, jnp.zeros((), v.dtype))
-            tail = jnp.zeros((1,), v.dtype)
-        elif kind == "min":
-            vz = jnp.where(valid, v, jnp.inf)
-            s = _segmented_scan(vz, first, jnp.minimum)[lic]
-            merged = jnp.where(occupied, s, jnp.inf)
-            tail = jnp.full((1,), jnp.inf)
-        else:  # max
-            vz = jnp.where(valid, v, -jnp.inf)
-            s = _segmented_scan(vz, first, jnp.maximum)[lic]
-            merged = jnp.where(occupied, s, -jnp.inf)
-            tail = jnp.full((1,), -jnp.inf)
-        outs.append(jnp.concatenate([merged, tail]))
-    outs.append(jnp.where(occupied, skey[fic], jnp.int64(-1)))
+    combined = (cnt > 0).sum()
+    # a segment's count fits int32 (its rows do): one word less to sort
+    skey, cnt, *cols = jax.lax.sort(
+        (key, cnt.astype(jnp.int32)) + tuple(cols), num_keys=1,
+        is_stable=False)
+    cnt = cnt.astype(jnp.int64)
+    valid = skey < _key_sentinel(skey.dtype)
+    differs = skey[1:] != skey[:-1]
+    first = jnp.concatenate([jnp.ones((1,), bool), differs]) & valid
+    last = jnp.concatenate([differs, jnp.ones((1,), bool)]) & valid
+    totals = [_segmented_scan(cnt, first, jnp.add)]
+    for v, kind in zip(cols, kinds):
+        op = {"add": jnp.add, "min": jnp.minimum,
+              "max": jnp.maximum}[kind]
+        totals.append(_segmented_scan(v, first, op))
+    groups = last.sum()
+    scanned = cnt.sum() + trash
+    is_cut = jnp.zeros((), bool)
+    table = ()
+    if cut_slots:
+        keep, exact = _rows_of_the_cut(last, totals, order, k, groups)
+        is_cut = exact & (groups > threshold) & (groups > k)
+        # the kept rows, in row order: the j-th is where the running count
+        # of kept rows first reaches j (k binary searches)
+        at = jnp.searchsorted(
+            _prefix_sum(keep.astype(jnp.int32)),
+            jnp.arange(1, cut_slots + 1, dtype=jnp.int32))
+        full = at < m
+        at = jnp.minimum(at, m - 1)
+        table = _table_of(full, skey[at], [t[at] for t in totals], kinds,
+                          trash)
+    elif table_slots:
+        # the ending rows move to the front by one more sort that carries
+        # the columns
+        out = _flagged_to_front(last, [skey] + totals, table_slots)
+        table = _table_of(out[0] < _I32_MAX, out[1], out[2:], kinds, trash)
+    header = jnp.stack([groups.astype(jnp.int64),
+                        combined.astype(jnp.int64), scanned, trash,
+                        is_cut.astype(jnp.int64)])
+    return header, table
+
+
+def _rows_of_the_cut(last, totals, order, k, groups):
+    """(keep, exact): the `k` ending rows that come first in the ORDER BY,
+    and whether the ranked column could be ranked exactly."""
+    col, descending, ties_high = order
+    m = last.shape[0]
+    nth = _prefix_sum(last.astype(jnp.int32))  # 1-based rank by key
+    if col is None:  # the key alone: the first k groups, or the last
+        keep = last & ((nth > groups - k) if descending else (nth <= k))
+        return keep, jnp.ones((), bool)
+    v = totals[col]
+    if jnp.issubdtype(v.dtype, jnp.floating):
+        vz = jnp.where(last, v, 0.0)
+        exact = jnp.all((vz == jnp.floor(vz))
+                        & (jnp.abs(vz) < float(1 << 62)))
+        v = vz.astype(jnp.int64)
+    else:
+        exact = jnp.ones((), bool)
+        v = v.astype(jnp.int64)
+    lowest = jnp.int64(-(1 << 63))
+    r = jnp.where(last, v if descending else -v, lowest)  # higher is first
+    # the k-th highest rank, by bisection on the count of rows at or above
+    lo = jnp.min(jnp.where(last, r, jnp.int64((1 << 63) - 1)))
+    hi = jnp.max(r)
+
+    def narrow(c):
+        lo, hi, i = c
+        mid = lo + (hi - lo + 1) // 2
+        enough = (r >= mid).sum() >= k
+        return jnp.where(enough, mid, lo), jnp.where(enough, hi, mid - 1), \
+            i + 1
+
+    kth, _, _ = jax.lax.while_loop(
+        lambda c: (c[0] < c[1]) & (c[2] < 64), narrow,
+        (lo, hi, jnp.int32(0)))
+    above = r > kth
+    ties = last & (r == kth)
+    want = k - above.sum()  # of the ties, by the key
+    tie_nth = _prefix_sum(ties.astype(jnp.int32))
+    if ties_high:
+        taken = tie_nth > tie_nth[m - 1] - want
+    else:
+        taken = tie_nth <= want
+    return above | (ties & taken), exact
+
+
+def _table_of(occupied, key, totals, kinds, trash):
+    """Slot columns in the per-segment output layout: counts (T+1,) with
+    the trash slot last, a (T+1,) column per state, keys (T,)."""
+    zero64 = jnp.zeros((), jnp.int64)
+    outs = [jnp.concatenate([jnp.where(occupied, totals[0], zero64),
+                             trash[None]])]
+    for v, kind in zip(totals[1:], kinds):
+        empty = jnp.asarray({"add": 0, "min": jnp.inf,
+                             "max": -jnp.inf}[kind], v.dtype)
+        outs.append(jnp.concatenate(
+            [jnp.where(occupied, v, empty), empty[None]]))
+    outs.append(jnp.where(occupied, key.astype(jnp.int64), jnp.int64(-1)))
     return tuple(outs)
+
+
+merge_group_tables = jit_named(
+    _merge_group_tables, "merge_group_tables",
+    static_argnames=("how", "key32", "kinds", "order", "cut_slots",
+                     "table_slots"))

@@ -27,8 +27,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..engine import ir
-from ..ops.kernels import (PackedOuts, ProgramJit, _apply_packed, _pack_flat,
-                           _run_program_impl, jit_named)
+from ..ops.kernels import (PackedOuts, ProgramJit, _pack_flat,
+                           _run_program_batch, _run_program_impl, jit_named)
 
 ROW_AXIS = "sp"  # intra-segment row sharding (sequence-parallel analogue)
 SEGMENT_AXIS = "dp"  # across segments (data-parallel analogue)
@@ -220,14 +220,9 @@ def _batch_sharded(program: ir.Program, arrays: tuple, params: tuple,
     mesh = segment_mesh(ndev)
 
     def shard_fn(arrays_l, params_l, num_docs_l):
-        # mirror run_program_batch exactly: widen packed planes, then vmap
-        # the per-segment impl over the (local) stack rows
-        arrays_w = _apply_packed(arrays_l, packed)
-
-        def one(arrays_s, params_s, nd):
-            return _run_program_impl(program, arrays_s, params_s, nd, padded)
-
-        return jax.vmap(one)(arrays_w, params_l, num_docs_l)
+        # run_program_batch's own body over the (local) stack rows
+        return _run_program_batch(program, arrays_l, params_l, num_docs_l,
+                                  padded, packed)
 
     fn = jax.shard_map(
         shard_fn, mesh=mesh,
@@ -327,7 +322,7 @@ def pack_outputs_collective(outs: tuple, s_real: int, ndev: int,
 def gather_outputs(outs: tuple, s_real: int) -> tuple:
     """Cross-chip gather for the raw path (sparse device combine): commit
     every [S_pad, ...] output to device 0 over ICI — no host crossing — so
-    downstream per-row slices and `combine_sparse_group_tables` colocate
+    downstream per-row slices and `merge_group_tables` colocate
     with device-0-resident dictionaries."""
     dev0 = jax.devices()[0]
     return tuple(jax.device_put(o[:s_real], dev0) for o in outs)

@@ -21,7 +21,8 @@ from pinot_tpu.segment.builder import SegmentBuilder
 from pinot_tpu.segment.loader import load_segment
 from pinot_tpu.spi.data_types import Schema
 
-from test_sparse_groupby_perf import _jaxpr_for, _sort_eqns
+from test_sparse_groupby_perf import (_grouping_sorts, _jaxpr_for,
+                                      _table_sorts)
 
 SCHEMA = Schema.build(
     "mk",
@@ -91,7 +92,8 @@ def test_composite_presorted_compiles_with_zero_sorts(lexseg):
         lexseg, FORCE + "SELECT a, b, SUM(v), COUNT(*) FROM mk "
                         "GROUP BY a, b LIMIT 100000")
     assert program.keys_presorted
-    assert _sort_eqns(jaxpr) == []
+    assert _grouping_sorts(jaxpr) == []  # the rows are never sorted by key
+    assert len(_table_sorts(jaxpr)) == 1
 
 
 def test_composite_presorted_results_match_host(tmp_path):

@@ -274,3 +274,68 @@ def test_label_names_the_module_and_scopes_name_its_ops(engine):
     assert f"module @jit_pack_{label} " in text
     assert kernels.unpack_outputs(kernels.pack_outputs(outs, label))[0].sum() \
         == np.asarray(outs[0]).sum()
+
+
+def _benchmark_scope_of():
+    """`benchmark/xplane.scope_of`, the rule the per-layer kernel metrics
+    file a device operation by (stdlib only; loaded by path)."""
+    import importlib.util
+
+    path = Path(__file__).resolve().parents[1] / "benchmark" / "xplane.py"
+    spec = importlib.util.spec_from_file_location("bench_xplane", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.scope_of
+
+
+def _sorted_family(engine):
+    from pinot_tpu.ops import kernels
+
+    segs = list(engine.tables["sptab"].segments)
+    sql = "SET sparseGroupBy = true; " \
+        "SELECT spk, SUM(spv) FROM sptab WHERE spy < 5 GROUP BY spk LIMIT 50"
+    plans = [_plan(engine, sql)[1] for _ in segs]
+    assert plans[0].program.mode == "group_by_sparse"
+    views, arrays, params, packed, num_docs = engine.tpu._gather_batch(
+        segs, plans)
+    return kernels.run_program_batch.lower(
+        plans[0].program, arrays, params, num_docs, views[0].padded,
+        packed=packed)
+
+
+def _cut_merge(engine):
+    import jax
+
+    from pinot_tpu.ops import kernels
+
+    s, k = 4, 13 << 13  # above kernels.QUARTER_MERGE_ABOVE_SLOTS: a branch
+    tables = ((jax.ShapeDtypeStruct((s, k), np.int64),
+               jax.ShapeDtypeStruct((s,), np.int64),
+               jax.ShapeDtypeStruct((s, k + 1), np.int64),
+               (jax.ShapeDtypeStruct((s, k + 1), np.float64),)),)
+    return kernels.merge_group_tables.lower(
+        tables, np.int64(5), np.int64(10), how=("base",), key32=True,
+        kinds=("add",), order=(1, True, False), cut_slots=8, table_slots=0)
+
+
+@pytest.mark.parametrize("lower,scope,inside", [
+    pytest.param(_sorted_family, "group_by_sparse", "while/body",
+                 id="the-sorted-family-under-lax-map"),
+    pytest.param(_cut_merge, "combine", "cond/branch",
+                 id="the-merge-under-its-branch"),
+])
+def test_ops_inside_a_loop_or_a_branch_are_filed_under_the_programs_scope(
+        engine, lower, scope, inside):
+    # a trace files an op under the FIRST part of its name after the
+    # module's: a scope opened inside `lax.map` or `lax.cond` would read
+    # `while` or `cond`, and `kernel_groupby_ms` would lose the scan
+    scope_of = _benchmark_scope_of()
+    names = set(re.findall(r'op_name="([^"]+)"',
+                           lower(engine).compile().as_text()))
+    # (the CPU's compiler leaves a few names of a called computation
+    # without the module's part; the chip's does not)
+    filed = {n: scope_of(n) for n in names if n.startswith("jit(")}
+    assert not [n for n, sc in filed.items() if sc in ("while", "cond")]
+    sorts = [n for n in names if n.endswith("/sort")]
+    assert sorts and all(filed[n] == scope for n in sorts), sorts
+    assert any(inside in n for n in sorts)

@@ -109,6 +109,19 @@ def test_agg_matrix_with_filter_vs_sqlite(env):
     assert got == want
 
 
+@pytest.mark.parametrize("where", ["WHERE d < 3 ", "WHERE d < 1 AND v < 0 "],
+                         ids=["a-fifth", "a-hundredth"])
+def test_a_selective_filter_takes_the_short_tail_vs_sqlite(env, where):
+    # the sorted path's rows after the sort are a branch on how many the
+    # filter kept: an eighth of the padded rows (here a half: 2,048 of
+    # 4,096) or fewer run scans and the table's sort over that prefix alone
+    tpu, conn, segs, presorted = env
+    got = _int_rows(tpu.execute_sql(FORCE + MATRIX_SQL.format(where=where)))
+    want = [tuple(int(v) for v in row)
+            for row in conn.execute(ORACLE_SQL.format(where=where))]
+    assert got == want and 0 < len(got)
+
+
 def test_trimmed_groups_stay_exact(env):
     tpu, conn, segs, presorted = env
     resp = tpu.execute_sql(

@@ -4,11 +4,16 @@ These tests pin the SHAPE of the compiled program, not its timings, so CI
 catches a regression that silently reintroduces the O(n log n) sort or the
 full-payload sort without any flaky wall-clock assertions:
 
-  * the presorted path (keys_presorted=True) must compile to a jaxpr with
-    ZERO `sort` primitives — the whole point of the fast path;
+  * the presorted path (keys_presorted=True) must compile to a jaxpr that
+    never sorts the ROWS BY KEY — the whole point of the fast path;
   * the sort-iota path must sort exactly (sort keys + iota32), never the
-    payload columns: the one `sort` eqn carries num_sort_keys + 1 operands
-    regardless of how many aggregation payloads ride the query.
+    payload columns: the one grouping `sort` eqn carries num_sort_keys + 1
+    operands regardless of how many aggregation payloads ride the query;
+  * every path moves its group-ending rows to the table's first slots by
+    ONE unstable sort keyed by the row index (kernels._GroupEnds; since
+    PR 30, in place of a binary search of log2(n) gathers a slot); the
+    sorted path has it twice, on either side of a `lax.cond`: over the
+    first eighth of the rows where the filter kept no more, or over all.
 """
 
 from __future__ import annotations
@@ -68,19 +73,56 @@ def _jaxpr_for(segment, sql):
 
 
 def _sort_eqns(jaxpr):
-    """All `sort` eqns in the jaxpr, recursing into sub-jaxprs."""
+    """All `sort` eqns in the jaxpr, recursing into sub-jaxprs, each with
+    the {var: producing eqn} map of the jaxpr it stands in."""
     found = []
 
     def walk(j):
+        made = {v: e for e in j.eqns for v in e.outvars}
         for eqn in j.eqns:
             if eqn.primitive.name == "sort":
-                found.append(eqn)
+                found.append((eqn, made))
             for v in eqn.params.values():
                 for sub in _subjaxprs(v):
                     walk(sub)
 
     walk(jaxpr.jaxpr)
     return found
+
+
+def _is_row_index(var, made, depth: int = 4) -> bool:
+    """Whether `var` holds the row index (an iota), as it is or with a
+    sentinel selected in: `jnp.where(flag, arange(n), I32_MAX)`. Only the
+    VALUES of a select are followed, never its predicate (every mask comes
+    from an iota compared with the doc count)."""
+    eqn = made.get(var)
+    if eqn is None or depth == 0:
+        return False
+    name = eqn.primitive.name
+    if name == "iota":
+        return True
+    if name == "select_n" or (name in ("pjit", "jit")
+                              and eqn.params.get("name") == "_where"):
+        return any(_is_row_index(v, made, depth - 1)
+                   for v in eqn.invars[1:] if not hasattr(v, "val"))
+    if name in ("convert_element_type", "broadcast_in_dim"):
+        return _is_row_index(eqn.invars[0], made, depth - 1)
+    return False
+
+
+def _table_sorts(jaxpr):
+    """The sorts that move group-ending rows to the table's front: ONE key,
+    the row index (kernels._flagged_to_front), which carries the columns."""
+    return [e for e, made in _sort_eqns(jaxpr)
+            if e.params["num_keys"] == 1 and _is_row_index(e.invars[0], made)]
+
+
+def _grouping_sorts(jaxpr):
+    """The sorts that order the ROWS: every sort that is not led by the row
+    index, stable or not (a sort by key is what the presorted path must
+    never lower)."""
+    table = {id(e) for e in _table_sorts(jaxpr)}
+    return [e for e, _ in _sort_eqns(jaxpr) if id(e) not in table]
 
 
 def _subjaxprs(v):
@@ -104,10 +146,14 @@ def test_presorted_path_compiles_with_zero_sorts(tmp_path):
                      "GROUP BY k LIMIT 1000")
     assert program.mode == "group_by_sparse"
     assert program.keys_presorted
-    eqns = _sort_eqns(jaxpr)
+    eqns = _grouping_sorts(jaxpr)
     assert eqns == [], (
-        f"presorted fast path must not lower any sort primitive, "
-        f"found {len(eqns)}")
+        f"presorted fast path must not sort the rows by key, "
+        f"found {len(eqns)} such sorts")
+    table = _table_sorts(jaxpr)
+    assert len(table) == 1
+    # row index | sentinel, key, running count, the SUM's prefix
+    assert len(table[0].invars) == 4 and table[0].params["num_keys"] == 1
 
 
 def test_presorted_detection_requires_sorted_column(tmp_path):
@@ -117,7 +163,9 @@ def test_presorted_detection_requires_sorted_column(tmp_path):
                      "GROUP BY k LIMIT 1000")
     assert program.mode == "group_by_sparse"
     assert not program.keys_presorted
-    assert len(_sort_eqns(jaxpr)) >= 1
+    assert len(_grouping_sorts(jaxpr)) == 1
+    # one on either side of the branch on how many rows the filter kept
+    assert len(_table_sorts(jaxpr)) == 2
 
 
 @pytest.mark.parametrize("aggs,num_sort_keys", [
@@ -134,7 +182,7 @@ def test_sort_iota_gather_sorts_keys_plus_iota_only(tmp_path, aggs,
         seg, FORCE + f"SELECT k, {aggs} FROM perfguard GROUP BY k LIMIT 1000")
     assert program.mode == "group_by_sparse"
     assert not program.keys_presorted
-    eqns = _sort_eqns(jaxpr)
+    eqns = _grouping_sorts(jaxpr)
     assert len(eqns) == 1, f"expected exactly one sort, got {len(eqns)}"
     got = len(eqns[0].invars)
     want = num_sort_keys + 1  # keys + iota32; payloads gather post-sort
@@ -151,6 +199,7 @@ def test_single_payload_skips_the_iota(tmp_path):
     program, jaxpr = _jaxpr_for(
         seg, FORCE + "SELECT k, SUM(v1) FROM perfguard GROUP BY k LIMIT 1000")
     assert program.mode == "group_by_sparse"
-    eqns = _sort_eqns(jaxpr)
+    eqns = _grouping_sorts(jaxpr)
     assert len(eqns) == 1
     assert len(eqns[0].invars) == 2  # key + the single payload
+    assert len(_table_sorts(jaxpr)) == 2

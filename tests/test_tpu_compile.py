@@ -106,12 +106,14 @@ def _spec(one_chip, shape, dtype):
 
 
 def _compile_program(one_chip, ssb, sql, padded, *, batch=0, fused="",
-                     sparse_groups=0, dict_len=0):
+                     sparse_groups=0, dict_len=0, whole_table=False):
     """Plan ``sql`` against the small segment, then lower run_program /
     run_program_batch with every row plane scaled to ``padded`` rows (and
     an [S] batch dim when ``batch``). ``sparse_groups`` scales a sparse
     program's key space, output groups and dictionary plane to a real
-    high-cardinality segment; ``dict_len`` sets the dictionary planes'
+    high-cardinality segment (cut at numGroupsLimit's default, or under
+    ``whole_table`` with a slot for every key, as the planner sizes a table
+    it sorts by its own rule); ``dict_len`` sets the dictionary planes'
     length (a family's `executor._dict_pad` bucket)."""
     segment, view = ssb
     plan = SegmentPlanner(parse_sql(sql), segment).plan()
@@ -127,7 +129,8 @@ def _compile_program(one_chip, ssb, sql, padded, *, batch=0, fused="",
         assert program.mode == "group_by_sparse"
         program = dataclasses.replace(
             program, key_space=sparse_groups,
-            num_groups=min(sparse_groups, 100_000))
+            num_groups=sparse_groups if whole_table
+            else min(sparse_groups, 100_000))
     lead = [batch] if batch else []
 
     def plane(a, kind):
@@ -272,6 +275,34 @@ def test_sparse_group_by_compiles(one_chip, ssb, sql):
     assert time.perf_counter() - t0 < 300  # did not end in 300 s with cumsum
 
 
+@pytest.mark.parametrize("sql,slots", [
+    # dd_top_customers: an unsorted key, 291 thousand entries a segment
+    pytest.param("SELECT p_brand, SUM(lo_revenue) FROM t WHERE lo_discount "
+                 "BETWEEN 1 AND 3 GROUP BY p_brand ORDER BY SUM(lo_revenue) "
+                 "DESC, p_brand LIMIT 20", 1 << 19, id="sort-2^19"),
+    # dd_top_orders: the key ascends in doc order, 1.05 million a segment
+    pytest.param("SELECT lo_orderkey, SUM(lo_quantity), SUM(lo_extendedprice)"
+                 " FROM t WHERE lo_discount BETWEEN 1 AND 3 GROUP BY "
+                 "lo_orderkey ORDER BY SUM(lo_quantity) DESC, lo_orderkey "
+                 "LIMIT 100", 1 << 20, id="presorted-2^20"),
+])
+def test_whole_table_sparse_family_compiles(one_chip, ssb, sql, slots):
+    """The sort-based scan with a slot for every key of the dictionary (the
+    planner's rule above the limb kernel's table), as one dispatch over
+    16 x 2^22 rows (`lax.map` over the members): the drill-down's two top-N
+    programs. On the chip's machine (PR 30): 34.7 and 47.6 s; here 48-56
+    and 80-85."""
+    t0 = time.perf_counter()
+    program, _ = _compile_program(
+        one_chip, ssb, "SET sparseGroupBy = true; " + sql, R22, batch=16,
+        sparse_groups=slots, whole_table=True)
+    took = time.perf_counter() - t0
+    print(f"sorted scan at {slots} slots x 16: compiled in {took:.1f} s")
+    assert program.mode == "group_by_sparse" and program.num_groups == slots
+    assert program.keys_presorted == (slots == 1 << 20)
+    assert took < 300
+
+
 def test_sparse_batch_family_compiles(one_chip, ssb):
     """The multi-segment form: one vmapped dispatch over 16 x 2^22 rows."""
     program, _ = _compile_program(
@@ -282,25 +313,62 @@ def test_sparse_batch_family_compiles(one_chip, ssb):
     assert program.mode == "group_by_sparse"
 
 
-def test_sparse_device_combine_compiles(one_chip):
-    """The server-level merge of 16 segments' sparse tables
-    (kernels.combine_sparse_group_tables) with f64 SUM states: min, max and
-    float adds all run the shift-pass scan. Its int64 lax.sort is what
-    takes the chip's compiler about a minute."""
-    s, k = 16, 100_000
-    keys = tuple(_spec(one_chip, (k,), jnp.int64) for _ in range(s))
-    counts = tuple(_spec(one_chip, (k + 1,), jnp.int64) for _ in range(s))
-    states = tuple(tuple(_spec(one_chip, (k + 1,), dt)
-                         for dt in (jnp.float64, jnp.int64, jnp.float64,
-                                    jnp.float64))
-                   for _ in range(s))
+@pytest.mark.parametrize(
+    "keys,how,key32,states,kinds,order,cut,table,seconds,temp_bytes", [
+    # dd_top_orders at the issue's size: 16 tables of 2^20 slots (1.05
+    # million orders a segment, consecutive integers), two sums, cut to
+    # 5,000 by SUM DESC, then the key
+    pytest.param(1 << 20, "base", True, (jnp.float64, jnp.float64),
+                 ("add", "add"), (1, True, False), 1 << 13, 0, 300, 4e9,
+                 id="cut-16x2^20"),
+    # dd_top_customers: 16 tables of 9 * 2^15 slots, never cut (300,000
+    # customers are under the threshold): the whole merged table crosses;
+    # a min and a max beside the sum (the count column rides every merge)
+    pytest.param(9 << 15, "plane", True,
+                 (jnp.float64, jnp.float64, jnp.float64),
+                 ("add", "min", "max"), None, 0, 19 << 14, 600, 4e9,
+                 id="whole-16x294912"),
+    # what `SET sparseGroupBy` and tables above 2^21 keys still send: 16
+    # tables cut at numGroupsLimit's 100,000 slots, int64 keys in value
+    # space already (tables kept on the device), merged whole (no branch
+    # at this size) into a table for every slot; a sum and a count (every
+    # 64-bit column the sort carries costs the compiler a minute here: the
+    # case above has the four kinds)
+    pytest.param(100_000, "values", False, (jnp.float64, jnp.int64),
+                 ("add", "add"), None, 0, 1 << 21, 400, 4e9,
+                 id="values-16x100000"),
+])
+def test_sparse_device_combine_compiles(one_chip, keys, how, key32, states,
+                                        kinds, order, cut, table, seconds,
+                                        temp_bytes):
+    """The server-level merge of 16 segments' sparse tables, cut on the
+    device (kernels.merge_group_tables): dictionary ids to values, one sort
+    that carries the columns, shift-pass scans, the bisection for the k-th
+    value and the f64 -> int64 ranking, at the drill-down's sizes, both
+    sides of the branch on how full the tables are. This file run on the
+    chip's machine (its host compiles; PR 30): 52.7 s, 125.5 s and 128.0 s
+    (the last two with four state columns each; three and two here), and
+    1.79 GB, 0.60 GB and 0.09 GB of temporaries; the sandbox's host takes
+    76 s, then 244-278 and 300-526 with four columns.
+    The seconds allowed are for the sandbox under six workers."""
+    s = 16
+    source = {"base": _spec(one_chip, (s,), jnp.int64),
+              "plane": _spec(one_chip, (s, keys), jnp.int32),
+              "values": None}[how]
+    tables = ((_spec(one_chip, (s, keys), jnp.int64), source,
+               _spec(one_chip, (s, keys + 1), jnp.int64),
+               tuple(_spec(one_chip, (s, keys + 1), dt) for dt in states)),)
     t0 = time.perf_counter()
-    compiled = kernels.combine_sparse_group_tables.lower(
-        keys, counts, states, kinds=("add", "add", "min", "max")).compile()
-    assert time.perf_counter() - t0 < 400  # 74 s alone; 87 s at 2^20 rows
-    # for the recursive scan this replaced, which grows with n
+    compiled = kernels.merge_group_tables.lower(
+        tables, _spec(one_chip, (), jnp.int64),
+        _spec(one_chip, (), jnp.int64), how=(how,), key32=key32, kinds=kinds,
+        order=order, cut_slots=cut, table_slots=table).compile()
+    took = time.perf_counter() - t0
     mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes < 4 * 10 ** 9
+    print(f"merge_group_tables {keys} x {s} ({how}): compiled in {took:.1f} s, "
+          f"temp {mem.temp_size_in_bytes} bytes")
+    assert took < seconds
+    assert mem.temp_size_in_bytes < temp_bytes
 
 
 def test_selection_compiles(one_chip, ssb):
