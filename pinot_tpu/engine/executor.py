@@ -129,14 +129,6 @@ def _register_compile(gkey, compile_ms: float, program, padded: int,
                 example=aot_example)
 
 
-def _register_dispatch(gkey) -> None:
-    """Warm-path half: one dict lookup + counter bumps, no fingerprint
-    walk, no spans, no syncs (tests/test_tracing_perf_guard.py)."""
-    from .compile_registry import COMPILE_REGISTRY
-
-    COMPILE_REGISTRY.note_dispatch(gkey)
-
-
 # (program mode, error type) pairs whose mesh-sharded dispatch already
 # failed once — warn once, then fall back quietly to solo batching
 _MESH_WARNED: set = set()
@@ -193,18 +185,6 @@ def _attach_dispatch_stats(span, cache: DeviceSegmentCache) -> None:
         span.set_attribute("stackMisses", stats["stackMisses"])
     span.attributes.update(cache.hbm_stats())
     clear_transfer_stats()
-
-
-def _describe_dispatch(span, program, padded: int, arrays) -> None:
-    """What a traced dispatch's span says of its program: mode, label, row
-    bucket, and how the planes it is fed decode their dictionaries
-    (`dictLookups`, kernels.dict_lookups)."""
-    if span is None:
-        return
-    span.set_attribute("mode", program.mode)
-    span.set_attribute("program", program_label(program))
-    span.set_attribute("padded", padded)
-    span.set_attribute("dictLookups", dict_lookups(program, arrays))
 
 
 @contextmanager
@@ -359,14 +339,6 @@ class TpuSegmentExecutor:
             return REALTIME_PLANES.view(segment)
         return self.cache.view(segment)
 
-    def execute(self, query: QueryContext, segment: ImmutableSegment):
-        plan = self.plan(query, segment)
-        return self.execute_plan(query, segment, plan)
-
-    def execute_plan(self, query: QueryContext, segment: ImmutableSegment, plan: SegmentPlan):
-        outs = self.dispatch_plan(segment, plan)
-        return self.collect(query, segment, plan, outs)
-
     def dispatch_plan(self, segment: ImmutableSegment, plan: SegmentPlan):
         """Launch the kernel and return UN-materialized device outputs.
 
@@ -375,32 +347,92 @@ class TpuSegmentExecutor:
         collect() each — host planning/decoding overlaps device compute
         (replaces the reference's per-segment worker-pool combine,
         pinot-core/.../operator/combine/GroupByCombineOperator.java:54, with
-        async device queueing instead of threads).
+        async device queueing instead of threads)."""
+        return self._traced_dispatch(
+            [segment], lambda span: self._dispatch_plan(segment, plan, span))
 
-        When a trace is active, the dispatch runs under a family_dispatch
-        span: gather + enqueue, with the compile time (detected via the
-        compile-cache guard, measured without a sync), the program's label,
-        per-slot transfer bytes and an HBM snapshot. The span adds no sync:
-        the wait for the device is the DEVICE_FETCH span, where the
-        untraced path waits too. Tracing off takes the first branch: one
-        thread-local read, no spans."""
+    def dispatch_plan_raw(self, segment: ImmutableSegment, plan: SegmentPlan):
+        """dispatch_plan without the flat-buffer packing: returns (the raw
+        device output tuple, the segment's view) for callers that keep
+        computing ON DEVICE with the per-segment outputs (the sparse device
+        combine, query_executor._sparse_device_combine) rather than
+        fetching them. Sparse programs never take the fused path, so the
+        fused negotiation is skipped."""
+        return self._traced_dispatch(
+            [segment],
+            lambda span: self._dispatch_plan(segment, plan, span, raw=True))
+
+    def _traced_dispatch(self, segments: list, body, batch: bool = False):
+        """The one wrapper around every dispatch body: the fault point,
+        and, when a trace is active, the family_dispatch span: gather +
+        enqueue, with the compile time (detected via the compile-cache
+        guard, measured without a sync), the program's label, per-slot
+        transfer bytes and an HBM snapshot. The span adds no sync: the wait
+        for the device is the DEVICE_FETCH span, where the untraced path
+        waits too. Tracing off takes the first branch: one thread-local
+        read, no spans."""
         if faults.ACTIVE:
             # kind="hbm_oom" specs raise RESOURCE_EXHAUSTED here and are
             # absorbed by the caller's with_oom_retry — the real OOM path
-            faults.FAULTS.fire("device.dispatch", segment=segment.name)
+            ctx = {"batch_size": len(segments)} if batch else {}
+            faults.FAULTS.fire("device.dispatch", segment=segments[0].name,
+                               **ctx)
         if TRACING.active_trace() is None:
-            return self._dispatch_plan(segment, plan, None)
+            return body(None)
         with TRACING.scope(FAMILY_DISPATCH) as span:
             reset_transfer_stats()
             try:
-                span.set_attribute("segment", segment.name)
-                span.set_attribute("numSegments", 1)
-                return self._dispatch_plan(segment, plan, span)
+                if not batch:
+                    span.set_attribute("segment", segments[0].name)
+                span.set_attribute("numSegments", len(segments))
+                return body(span)
             finally:
                 _attach_dispatch_stats(span, self.cache)
 
+    @staticmethod
+    def _launch(span, gkey, program, padded: int, arrays, run,
+                count_after: bool = False, **family):
+        """The one launch routine: note the compile key with the guard,
+        count the dispatch, say in the span what runs, run, and register
+        the compile (``family``: what `_register_compile` files it under)
+        or the dispatch. jit's first call compiles synchronously before the
+        async dispatch, so the host wall of ``run`` ≈ the compile cost on a
+        guard miss — measurable WITHOUT a sync, so the compile registry
+        gets fed on untraced production dispatches too. ``count_after``
+        counts only once ``run`` returned (a sharded dispatch that fails
+        falls back to a body that counts itself)."""
+        new_compile = _GUARD.note(gkey)
+        if not count_after:
+            _count_dispatch(new_compile)
+        if span is not None:
+            # mode, label, row bucket, and how the planes the program is
+            # fed decode their dictionaries (kernels.dict_lookups)
+            span.set_attribute("mode", program.mode)
+            span.set_attribute("program", program_label(program))
+            span.set_attribute("padded", padded)
+            span.set_attribute("dictLookups", dict_lookups(program, arrays))
+        t0 = time.perf_counter()
+        outs = run()
+        if count_after:
+            _count_dispatch(new_compile)
+        compile_ms = 0.0
+        if new_compile:
+            compile_ms = round((time.perf_counter() - t0) * 1000, 3)
+            _register_compile(gkey, compile_ms, program, padded, **family)
+        else:
+            # the warm path: one dict lookup + counter bumps, no
+            # fingerprint walk (tests/test_tracing_perf_guard.py)
+            from .compile_registry import COMPILE_REGISTRY
+
+            COMPILE_REGISTRY.note_dispatch(gkey)
+        if span is not None:
+            span.set_attribute("compileMs", compile_ms)
+        return outs
+
     def _dispatch_plan(self, segment: ImmutableSegment, plan: SegmentPlan,
-                       span):
+                       span, raw: bool = False):
+        """The solo body. ``raw``: the fused kernel is off and nothing is
+        packed — (outs, view) for a caller that stays on the device."""
         view = self._view_for(segment)
         arrays, packed = plan.gather_arrays_packed(view)
         # params pass as host numpy: jit converts arguments itself — an
@@ -418,7 +450,7 @@ class TpuSegmentExecutor:
         # Dict-LUT predicates (IN/LIKE/NOT...) join the fused scope when
         # their boolean LUT compresses to a few contiguous dict-id runs —
         # a dispatch-time property of the CONCRETE host params.
-        fused = fused_groupby.active() if plan.fused_ok else ""
+        fused = fused_groupby.active() if plan.fused_ok and not raw else ""
         lut_meta: tuple = ()
         base_params = params
         if fused:
@@ -432,17 +464,11 @@ class TpuSegmentExecutor:
         # one entry per compiled executable family: padded shape and the
         # fused/lut variants each compile separately
         gkey = (plan.program, view.padded, fused, lut_meta)
-        new_compile = _GUARD.note(gkey)
-        _count_dispatch(new_compile)
-        label = program_label(plan.program)
-        _describe_dispatch(span, plan.program, view.padded, arrays)
         if span is not None and fused:
             span.set_attribute("fused", fused)
-        if new_compile:
-            t0 = time.perf_counter()
         nd = np.int32(segment.num_docs)
-        compile_ms = 0.0
-        try:
+
+        def run():
             # AOT-prewarmed family (engine/aot_cache.py): the persisted
             # executable serves the dispatch — zero compiles in this
             # process for the family. Empty/disabled cache costs one
@@ -455,18 +481,13 @@ class TpuSegmentExecutor:
                 outs = run_program(plan.program, arrays, params, nd,
                                    view.padded, packed=packed, fused=fused,
                                    fused_lut_meta=lut_meta)
-            if new_compile:
-                # jit's first call compiles synchronously before the async
-                # dispatch, so host wall of run_program ≈ compile cost on
-                # a guard miss — measurable WITHOUT a sync, so the compile
-                # registry gets fed on untraced production dispatches too
-                compile_ms = round((time.perf_counter() - t0) * 1000, 3)
-                _register_compile(gkey, compile_ms,
-                                  plan.program, view.padded, fused, lut_meta,
-                                  packed=packed,
-                                  aot_example=(arrays, params, nd))
-            else:
-                _register_dispatch(gkey)
+            return outs
+
+        try:
+            outs = self._launch(span, gkey, plan.program, view.padded,
+                                arrays, run, fused=fused, lut_meta=lut_meta,
+                                packed=packed,
+                                aot_example=(arrays, params, nd))
             # the compiled fused kernel varies with lut_meta (run counts
             # are static), so validation is keyed per (program, meta)
             vkey = (plan.program, lut_meta)
@@ -488,60 +509,15 @@ class TpuSegmentExecutor:
             from .perf_ledger import PERF_LEDGER
 
             PERF_LEDGER.note_event("fused-host")
-            outs = run_program(plan.program, arrays, base_params,
-                               np.int32(segment.num_docs), view.padded,
-                               packed=packed, fused="")
+            outs = run_program(plan.program, arrays, base_params, nd,
+                               view.padded, packed=packed, fused="")
             if span is not None:
                 span.set_attribute("fusedFallback", True)
-        if span is not None:
-            span.set_attribute("compileMs", compile_ms)
+                span.attributes.setdefault("compileMs", 0.0)
+        if raw:
+            return outs, view
         # one flat buffer per query → one D2H transfer at collect()
-        return pack_outputs(outs, label)
-
-    def dispatch_plan_raw(self, segment: ImmutableSegment, plan: SegmentPlan):
-        """dispatch_plan without the flat-buffer packing: returns the raw
-        device output tuple for callers that keep computing ON DEVICE with
-        the per-segment outputs (the sparse device combine,
-        query_executor._try_sparse_device_combine) rather than fetching
-        them. Sparse programs never take the fused path, so the fused
-        negotiation is skipped."""
-        if faults.ACTIVE:
-            faults.FAULTS.fire("device.dispatch", segment=segment.name)
-        if TRACING.active_trace() is None:
-            return self._dispatch_plan_raw(segment, plan, None)
-        with TRACING.scope(FAMILY_DISPATCH) as span:
-            reset_transfer_stats()
-            try:
-                span.set_attribute("segment", segment.name)
-                span.set_attribute("numSegments", 1)
-                return self._dispatch_plan_raw(segment, plan, span)
-            finally:
-                _attach_dispatch_stats(span, self.cache)
-
-    def _dispatch_plan_raw(self, segment: ImmutableSegment,
-                           plan: SegmentPlan, span):
-        view = self._view_for(segment)
-        arrays, packed = plan.gather_arrays_packed(view)
-        params = tuple(p if isinstance(p, (np.ndarray, np.generic))
-                       else np.asarray(p) for p in plan.params)
-        gkey = (plan.program, view.padded, "", ())
-        new_compile = _GUARD.note(gkey)
-        _count_dispatch(new_compile)
-        _describe_dispatch(span, plan.program, view.padded, arrays)
-        if new_compile:
-            t0 = time.perf_counter()
-        outs = run_program(plan.program, arrays, params,
-                           np.int32(segment.num_docs), view.padded,
-                           packed=packed, fused="")
-        compile_ms = 0.0
-        if new_compile:
-            compile_ms = round((time.perf_counter() - t0) * 1000, 3)
-            _register_compile(gkey, compile_ms, plan.program, view.padded)
-        else:
-            _register_dispatch(gkey)
-        if span is not None:
-            span.set_attribute("compileMs", compile_ms)
-        return outs, view
+        return pack_outputs(outs, program_label(plan.program))
 
     def _gather_batch(self, segments: list, plans: list, ndev: int = 1):
         with TRACING.scope(GATHER_STACK):
@@ -623,24 +599,6 @@ class TpuSegmentExecutor:
                               dtype=np.int32)
         return views, tuple(stacked), tuple(params_b), packed, num_docs
 
-    def _dispatch_batch(self, segments: list, plans: list, mesh: tuple = (),
-                        pack: bool = False):
-        if faults.ACTIVE:
-            faults.FAULTS.fire("device.dispatch",
-                               segment=segments[0].name,
-                               batch_size=len(segments))
-        if TRACING.active_trace() is None:
-            return self._dispatch_batch_inner(segments, plans, None,
-                                              mesh=mesh, pack=pack)
-        with TRACING.scope(FAMILY_DISPATCH) as span:
-            reset_transfer_stats()
-            try:
-                span.set_attribute("numSegments", len(segments))
-                return self._dispatch_batch_inner(segments, plans, span,
-                                                  mesh=mesh, pack=pack)
-            finally:
-                _attach_dispatch_stats(span, self.cache)
-
     def _dispatch_batch_sharded(self, segments: list, plans: list, span,
                                 ndev: int, pack: bool):
         """ONE sharded dispatch for the whole family: the [S, ...] stacks
@@ -656,35 +614,25 @@ class TpuSegmentExecutor:
         asig = tuple((str(a.dtype), tuple(a.shape)) for a in arrays)
         gkey = ("batchmesh", ndev, plan0.program, views[0].padded, packed,
                 asig, len(segments))
-        new_compile = _GUARD.note(gkey)
-        label = program_label(plan0.program)
-        _describe_dispatch(span, plan0.program, views[0].padded, arrays)
         if span is not None:
             span.set_attribute("meshDevices", ndev)
-        t0 = time.perf_counter()
-        outs = pmesh.run_program_batch_sharded(
-            plan0.program, arrays, params_b, num_docs, views[0].padded,
-            ndev, packed=packed)
         # counted only after the sharded dispatch succeeded: a trace-time
         # failure falls back to the solo path, which counts itself — so
         # numDeviceDispatches stays exactly one per family either way
-        _count_dispatch(new_compile)
-        compile_ms = 0.0
-        if new_compile:
-            compile_ms = round((time.perf_counter() - t0) * 1000, 3)
-            _register_compile(gkey, compile_ms, plan0.program,
-                              views[0].padded, batch_size=len(segments),
-                              mesh=(ndev,))
-        else:
-            _register_dispatch(gkey)
+        outs = self._launch(
+            span, gkey, plan0.program, views[0].padded, arrays,
+            lambda: pmesh.run_program_batch_sharded(
+                plan0.program, arrays, params_b, num_docs, views[0].padded,
+                ndev, packed=packed),
+            count_after=True, batch_size=len(segments), mesh=(ndev,))
         if span is not None:
-            span.set_attribute("compileMs", compile_ms)
             # the record of which chips took part; when each ran is in the
             # profiler's per-device planes, not in a host stamp
             for d in pmesh.mesh_devices(ndev):
                 with TRACING.scope(f"mesh_device:{d.id}") as dspan:
                     dspan.set_attribute("device", d.id)
         if pack:
+            label = program_label(plan0.program)
             try:
                 # preferred: shuffle-inside-the-program — all_gather over
                 # the mesh axis + on-device pack, no dev0 funnel of raw outs
@@ -701,8 +649,14 @@ class TpuSegmentExecutor:
             result = pmesh.gather_outputs(outs, len(segments))
         return result, views
 
+    def _dispatch_batch(self, segments: list, plans: list, mesh: tuple,
+                        pack: bool):
+        return self._traced_dispatch(
+            segments, lambda span: self._dispatch_batch_inner(
+                segments, plans, span, mesh, pack), batch=True)
+
     def _dispatch_batch_inner(self, segments: list, plans: list, span,
-                              mesh: tuple = (), pack: bool = False):
+                              mesh: tuple, pack: bool):
         from ..ops.kernels import run_program_batch
 
         ndev = int(mesh[0]) if mesh else 1
@@ -726,28 +680,20 @@ class TpuSegmentExecutor:
         asig = tuple((str(a.dtype), tuple(a.shape)) for a in arrays)
         gkey = ("batch", plan0.program, views[0].padded, packed, asig,
                 len(segments))
-        new_compile = _GUARD.note(gkey)
-        _count_dispatch(new_compile)
-        _describe_dispatch(span, plan0.program, views[0].padded, arrays)
-        if new_compile:
-            t0 = time.perf_counter()
-        outs = aot_call(gkey, arrays, params_b, num_docs) \
-            if AOT_READY else None
-        if outs is None:
-            outs = run_program_batch(plan0.program, arrays, params_b,
-                                     num_docs, views[0].padded,
-                                     packed=packed)
-        compile_ms = 0.0
-        if new_compile:
-            compile_ms = round((time.perf_counter() - t0) * 1000, 3)
-            _register_compile(gkey, compile_ms, plan0.program,
-                              views[0].padded, batch_size=len(segments),
-                              packed=packed,
-                              aot_example=(arrays, params_b, num_docs))
-        else:
-            _register_dispatch(gkey)
-        if span is not None:
-            span.set_attribute("compileMs", compile_ms)
+
+        def run():
+            outs = aot_call(gkey, arrays, params_b, num_docs) \
+                if AOT_READY else None
+            if outs is None:
+                outs = run_program_batch(plan0.program, arrays, params_b,
+                                         num_docs, views[0].padded,
+                                         packed=packed)
+            return outs
+
+        outs = self._launch(span, gkey, plan0.program, views[0].padded,
+                            arrays, run, batch_size=len(segments),
+                            packed=packed,
+                            aot_example=(arrays, params_b, num_docs))
         return outs, views
 
     def dispatch_plan_batch(self, segments: list, plans: list,
@@ -761,7 +707,7 @@ class TpuSegmentExecutor:
         across the local device mesh and the byte-pack happens on device
         with the flat committed to device 0 — still one launch, one D2H.
         Raises BatchFamilyMismatch to request the per-segment fallback."""
-        outs, _ = self._dispatch_batch(segments, plans, mesh=mesh, pack=True)
+        outs, _ = self._dispatch_batch(segments, plans, mesh, True)
         return outs if isinstance(outs, PackedOuts) \
             else pack_outputs(outs, program_label(plans[0].program))
 
@@ -773,7 +719,7 @@ class TpuSegmentExecutor:
         combine slices per-member rows lazily — the slices never leave
         HBM). Mesh-sharded dispatches gather their outputs to device 0
         over ICI first so downstream device math colocates."""
-        return self._dispatch_batch(segments, plans, mesh=mesh)
+        return self._dispatch_batch(segments, plans, mesh, False)
 
     def collect(self, query: QueryContext, segment: ImmutableSegment,
                 plan: SegmentPlan, outs):

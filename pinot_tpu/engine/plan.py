@@ -789,7 +789,7 @@ class SegmentPlanner(AggPlanContext):
         """(sorted, reason) for a group-by the dense table could hold. One
         identifier key over an integer dictionary with every aggregation a
         columnar count/sum/min/max — the shapes the server merges and cuts
-        on the device (query_executor._try_sparse_device_combine) — gets a
+        on the device (query_executor._device_merge_takes) — gets a
         sorted table from the first size the limb kernel leaves
         (mxu_groupby.MAX_GROUPS slots): above it the dense table is filled
         by one 32-bit scatter per limb over every row, whatever its size,

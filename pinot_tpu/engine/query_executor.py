@@ -29,10 +29,10 @@ from .combine import (combine_aggregation, combine_group_by,
                       combine_selection, trim_group_by)
 from .ir import program_label
 from .plan import table_bucket
-from ..ops.kernels import PackedOuts, fetch_packed_batch, unpack_outputs
+from ..ops.kernels import fetch_packed_batch, unpack_outputs
 from .executor import (BatchFamilyMismatch, TpuSegmentExecutor,
                        batch_families, device_fetch, fetch_outputs,
-                       batch_family_key, dispatch_counters,
+                       dispatch_counters,
                        reset_dispatch_counters)
 from .host_executor import HostSegmentExecutor
 from .oom import HbmExhaustedError, with_oom_retry
@@ -105,6 +105,58 @@ def _cut_order(query: QueryContext, plan):
     col = vec.outs[vec.fin_tag[1]]
     ties_high = bool(rest) and not rest[0][1]
     return (col, not ascending, ties_high), trim_size, threshold
+
+
+@dataclass
+class _SegmentTask:
+    """One kept segment on its way through `QueryExecutor._run_segments`:
+    the query and segment as routed (a star-tree `rewrite` swaps both), its
+    plan (None: the planner refused it, host work), its partial-cache key,
+    and what has come back for it so far — `got` says what `value` holds:
+    "pack" a solo dispatch's PackedOuts, still on the device; "member" its
+    (family key, row) in a batch family's outputs; "arrays" its outputs as
+    host arrays; "decoded" a finished intermediate (the vectorised family
+    decode). "cached" (a partial-cache hit) and "done" are stored."""
+    idx: int
+    query: QueryContext
+    segment: object
+    rewrite: object
+    plan: object = None
+    cache_key: object = None
+    got: str = ""
+    value: object = None
+
+
+@dataclass
+class _SegmentRun:
+    """What the stages of one `_run_segments` call share."""
+    query: QueryContext
+    kept: list
+    tracker: object
+    deadline: Optional[float]
+    timeout_ms: object
+    cstats: dict
+    planned: object
+    msig: tuple
+    done: int = 0
+    rt_device: bool = False  # a consuming segment answered on device
+    # by family key: the batched PackedOuts (on the device), its (segments,
+    # plans) for a re-dispatch, the batched HOST arrays
+    fam_packs: dict = field(default_factory=dict)
+    fam_inputs: dict = field(default_factory=dict)
+    fam_outs: dict = field(default_factory=dict)
+
+    def __post_init__(self):
+        self.intermediates: list = [None] * len(self.kept)
+
+    def check(self, done: int = None) -> None:
+        if self.tracker is not None:
+            self.tracker.check_cancel()
+        if self.deadline is not None and time.perf_counter() > self.deadline:
+            raise TimeoutError(
+                f"query exceeded timeoutMs={self.timeout_ms} "
+                f"({self.done if done is None else done}/{len(self.kept)} "
+                f"segments done)")
 
 
 @dataclass
@@ -512,355 +564,324 @@ class QueryExecutor:
         }
 
     def _run_segments(self, query: QueryContext, kept: list, tracker,
-                      deadline, timeout_ms, cstats=None,
-                      planned=lambda: None) -> list:
-        """Two-phase multi-segment execution: dispatch every device kernel
-        first (async — the device queue fills and runs back-to-back), run
-        host-fallback segments while the device works, then collect. This
-        replaces the serial plan→dispatch→block loop the reference handles
-        with a worker pool (GroupByCombineOperator.java:54); here the
-        pipeline overlap comes from XLA's async dispatch instead of threads."""
+                      deadline, timeout_ms, cstats: dict, planned) -> list:
+        """Multi-segment execution, stage by stage: plan every kept segment
+        ONCE → partial-cache lookup → (the device merge | dispatch every
+        family, async, so the device queue fills and runs back-to-back →
+        host-fallback segments while the device works → one fetch →
+        decode) → partial-cache store. The overlap the reference gets from
+        a worker pool (GroupByCombineOperator.java:54) comes from XLA's
+        async dispatch. `planned` closes BUILD_QUERY_PLAN where the lookup
+        ends, on both routes."""
+        run = _SegmentRun(query, kept, tracker, deadline, timeout_ms, cstats,
+                          planned, self._mesh_sig(query))
+        tasks, host_tasks, families = self._plan_segments(run)
+        merged = None
+        if self._device_merge_takes(query, tasks, host_tasks):
+            merged = self._merge_on_device(run, tasks, families)
+        if merged is None:
+            self._lookup_partials(run, tasks)
+            run.planned()
+            self._dispatch_families(run, tasks, families)
+            self._run_host_work(run, host_tasks)
+            self._fetch(run, tasks)
+            self._decode(run, tasks)
+            self._store_partials(run, tasks)
+        if run.rt_device:
+            from ..realtime.device_plane import note_realtime_device_query
 
-        def check(done: int):
-            if tracker is not None:
-                tracker.check_cancel()
-            if deadline is not None and time.perf_counter() > deadline:
-                raise TimeoutError(
-                    f"query exceeded timeoutMs={timeout_ms} "
-                    f"({done}/{len(kept)} segments done)")
+            note_realtime_device_query()
+        return merged or run.intermediates
 
-        if len(kept) > 1 and self.backend != "host":
-            merged = self._try_sparse_device_combine(query, kept, tracker,
-                                                     check, cstats, planned)
-            if merged is not None:
-                return merged
-
-        pending: list = []  # (idx, run_query, segment, rewrite, plan, token)
-        host_work: list = []  # (idx, run_query, run_segment, rewrite)
-        intermediates: list = [None] * len(kept)
-        device_entries: list = []  # (idx, run_query, run_segment, rewrite, plan)
-        for idx, segment in enumerate(kept):
-            check(idx)
-            run_query, run_segment, rewrite = self._segment_route(query, segment)
-            if self.backend == "host":
-                host_work.append((idx, run_query, run_segment, rewrite))
-                continue
-            try:
-                # consuming-segment snapshots lower through the realtime
-                # planner (realtime/device_plane.py) and join the device
-                # path; unsupported shapes fall back per segment
-                device_entries.append((idx, run_query, run_segment, rewrite,
-                                       self.tpu.plan(run_query, run_segment)))
-            except UnsupportedQueryError:
-                if self.backend == "tpu" \
-                        and not getattr(run_segment, "is_mutable", False):
+    def _plan_segments(self, run: _SegmentRun):
+        """(device tasks, host tasks, families): every kept segment routed
+        (star-tree rewrite) and planned once — a segment the planner
+        refuses is host work —, the plans' sorted tables sized alike and
+        grouped into batch families (positions index the device tasks), the
+        partial-cache keys derived once, for both tiers."""
+        tasks, host_tasks = [], []
+        for idx, segment in enumerate(run.kept):
+            run.check(idx)
+            task = _SegmentTask(idx, *self._segment_route(run.query, segment))
+            if self.backend != "host":
+                try:
+                    # consuming-segment snapshots lower through the realtime
+                    # planner (realtime/device_plane.py) and join the device
+                    # path; unsupported shapes fall back per segment
+                    task.plan = self.tpu.plan(task.query, task.segment)
+                except UnsupportedQueryError:
                     # mutable snapshots stay best-effort even under the
                     # forced-device backend: realtime tables must answer
-                    raise
-                host_work.append((idx, run_query, run_segment, rewrite))
+                    if self.backend == "tpu" and not getattr(
+                            task.segment, "is_mutable", False):
+                        raise
+            (host_tasks if task.plan is None else tasks).append(task)
+        plans, families = batch_families(
+            [(t.segment, t.plan) for t in tasks], run.msig,
+            self._segment_batch_enabled(run.query))
+        cache_on = tasks and self._segment_cache_enabled(run.query)
+        for task, plan in zip(tasks, plans):
+            task.plan = plan
+            if cache_on:
+                task.cache_key = self._partial_cache_key(
+                    task.query, task.segment, task.rewrite, plan)
+        return tasks, host_tasks, families
 
-        # segment partial-result cache (cache/partial.py): a hit fills the
-        # intermediate directly and the segment never reaches dispatch; a
-        # miss is remembered so the collected result is inserted below.
-        # A traced run looks the cache up like any other and says so in a
-        # SEGMENT_CACHE(hit) span.
-        cache_on = device_entries and self._segment_cache_enabled(query)
-        cache_inserts: list = []  # (idx, cache key, segment name)
-        if cache_on:
-            from ..cache.partial import GLOBAL_PARTIAL_CACHE
+    def _lookup_partials(self, run: _SegmentRun, tasks: list) -> None:
+        """The per-segment tier of the partial cache (cache/partial.py): a
+        hit fills the intermediate directly and the segment never reaches
+        dispatch; `_store_partials` inserts what missed. A traced run looks
+        the cache up like any other and says so in a span."""
+        from ..cache.partial import GLOBAL_PARTIAL_CACHE
 
-            uncached = []
-            for e in device_entries:
-                idx, run_query, run_segment, rewrite, plan = e
-                key = self._partial_cache_key(run_query, run_segment,
-                                              rewrite, plan)
-                hit = None if key is None else GLOBAL_PARTIAL_CACHE.get(key)
-                if hit is not None:
-                    intermediates[idx] = hit
-                    if cstats is not None:
-                        cstats["hit"] += 1
-                    continue
-                if key is not None:
-                    if cstats is not None:
-                        cstats["miss"] += 1
-                    cache_inserts.append(
-                        (idx, key, getattr(run_segment, "name", "?")))
-                uncached.append(e)
-            hits = len(device_entries) - len(uncached)
-            if hits:
-                with TRACING.scope("SEGMENT_CACHE(hit)") as sp:
-                    if sp is not None:
-                        sp.set_attribute("segments", hits)
-                        sp.set_attribute("cache", "hit")
-            device_entries = uncached
+        hits = 0
+        for task in tasks:
+            hit = None if task.cache_key is None \
+                else GLOBAL_PARTIAL_CACHE.get(task.cache_key)
+            if hit is not None:
+                run.intermediates[task.idx], task.got = hit, "cached"
+                hits += 1
+            elif task.cache_key is not None:
+                run.cstats["miss"] += 1
+        if hits:
+            run.cstats["hit"] += hits
+            with TRACING.scope("SEGMENT_CACHE(hit)") as sp:
+                if sp is not None:
+                    sp.set_attribute("segments", hits)
+                    sp.set_attribute("cache", "hit")
 
-        # stacked segment batching: one vmapped dispatch per batch FAMILY
-        # (equal host-side family key → identical plane shapes), single-
-        # member families keep the per-segment path (incl. the fused
-        # kernel). Tokens mark family members: (family key, row in batch).
-        fam_packs: dict = {}    # fkey → batched PackedOuts
-        fam_inputs: dict = {}   # fkey → (segments, plans) for re-dispatch
-        fam_hosts: dict = {}    # fkey → HOST arrays from a coalesced group
-        msig = self._mesh_sig(query)
-        # cross-query coalescing (engine/coalesce.py): only armed when the
-        # opt-in hold window is set AND the family has repeat traffic
-        from .coalesce import coalesce_enabled
-        from ..realtime.device_plane import (RealtimeUploadError,
-                                             note_realtime_device_query)
+    def _store_partials(self, run: _SegmentRun, tasks: list) -> None:
+        from ..cache.partial import GLOBAL_PARTIAL_CACHE
 
-        co_on = coalesce_enabled(query)
-        rt_device = False  # any consuming segment answered on device
-        plans, families = self._batch_families(
-            query, [(e[2], e[4]) for e in device_entries], mesh=msig)
-        device_entries = [e[:4] + (plan,)
-                          for e, plan in zip(device_entries, plans)]
-        planned()
+        for task in tasks:
+            inter = run.intermediates[task.idx]
+            # selections bypass (LIMIT makes row sets order-dependent
+            # across segments and the payoff is row materialization, not
+            # device work); agg/group partials are pure merges
+            if task.cache_key is not None and task.got != "cached" \
+                    and isinstance(inter, (AggIntermediate,
+                                           GroupByIntermediate)):
+                GLOBAL_PARTIAL_CACHE.put(
+                    task.cache_key, inter,
+                    (getattr(task.segment, "name", "?"),))
+
+    def _dispatch_families(self, run: _SegmentRun, tasks: list,
+                           families: list) -> None:
+        """Stacked segment batching: one vmapped dispatch per batch FAMILY
+        (equal host-side family key → identical plane shapes); a family of
+        one, or one whose batch dispatch is refused, keeps the per-segment
+        path (incl. the fused kernel). Nothing is fetched here."""
+        from ..realtime.device_plane import RealtimeUploadError
+
         for fkey, positions in families:
-            entries = [device_entries[p] for p in positions]
-            if fkey is not None and len(entries) > 1:
-                segs_f = [e[2] for e in entries]
-                plans_f = [e[4] for e in entries]
-                fam_mutable = any(getattr(s, "is_mutable", False)
-                                  for s in segs_f)
-                # the coalescer's family key carries no snapshot
-                # generation, so a held group could serve one generation's
-                # stack to a later query — consuming families never join
-                if co_on and not fam_mutable:
-                    def _co_runner(segs_all, plans_all,
-                                   _keep=segs_f[0], _m=msig):
-                        pack = with_oom_retry(
-                            lambda: self.tpu.dispatch_plan_batch(
-                                segs_all, plans_all, mesh=_m),
-                            keep_segment=_keep, cache=self.tpu.cache)
-                        with device_fetch():
-                            return fetch_packed_batch([pack])[0]
-
-                    co = self.coalescer.offer(query.table_name, fkey,
-                                              segs_f, plans_f, msig,
-                                              _co_runner)
-                    if co is not None:
-                        # this query's S rows are zero-copy views of the
-                        # group's fetched stack; tokens ride the normal
-                        # family demux below
-                        fam_hosts[fkey] = co.outs
-                        for row, e in enumerate(entries):
-                            pending.append(e + ((fkey, row),))
-                        continue
+            members = [tasks[p] for p in positions
+                       if tasks[p].got != "cached"]
+            if fkey is not None and len(members) > 1 \
+                    and self._dispatch_family(run, fkey, members):
+                continue
+            for task in members:
                 try:
-                    # HBM pressure during plane upload/dispatch: evict cold
-                    # cached segments once and retry (engine/oom.py — the
-                    # DirectOOMHandler analogue). Relief drops whole stacks.
-                    pack = with_oom_retry(
-                        lambda: self.tpu.dispatch_plan_batch(segs_f, plans_f,
-                                                             mesh=msig),
-                        keep_segment=segs_f[0], cache=self.tpu.cache)
-                except BatchFamilyMismatch:
-                    pass  # host key over-grouped; per-segment is always valid
-                except RealtimeUploadError:
-                    pass  # per-segment path below host-falls the faulted one
-                except HbmExhaustedError:
-                    # the [S, N] stacks ~double the family's footprint, so a
-                    # family that fits per-segment can OOM batched even after
-                    # relief — fall back rather than fail a query the 1x
-                    # per-segment path (below, with its own retry) completes
-                    pass
-                else:
-                    if fam_mutable:
-                        rt_device = True
-                    fam_packs[fkey] = pack
-                    fam_inputs[fkey] = (segs_f, plans_f)
-                    for row, e in enumerate(entries):
-                        pending.append(e + ((fkey, row),))
-                    continue
-            for e in entries:
-                idx, run_query, run_segment, rewrite, plan = e
-                try:
-                    outs = with_oom_retry(
-                        lambda: self.tpu.dispatch_plan(run_segment, plan),
-                        keep_segment=run_segment, cache=self.tpu.cache)
+                    task.value = with_oom_retry(
+                        lambda: self.tpu.dispatch_plan(task.segment,
+                                                       task.plan),
+                        keep_segment=task.segment, cache=self.tpu.cache)
                 except RealtimeUploadError:
                     # delta upload faulted/overran its budget: THIS query
                     # answers on host (bit-identical); plane state is
                     # pre-fault-consistent or dropped for full re-upload
-                    inter = self._account(
-                        tracker, lambda rq=run_query, rs=run_segment:
-                        self.host.execute(rq, rs), run_segment)
-                    intermediates[idx] = (
-                        self._remap_star_tree(rewrite, inter) if rewrite
-                        else inter)
+                    self._host_execute(run, task)
                     continue
-                if getattr(run_segment, "is_mutable", False):
-                    rt_device = True
-                pending.append((idx, run_query, run_segment, rewrite, plan,
-                                outs))
+                task.got = "pack"
+                run.rt_device |= getattr(task.segment, "is_mutable", False)
 
-        done = 0
-        if self.num_threads > 1 and len(host_work) > 1:
-            caller_trace = TRACING.active_trace()
-            caller_span = TRACING.current_span()
+    def _dispatch_family(self, run: _SegmentRun, fkey, members: list) -> bool:
+        """One batch dispatch for a family's members, or their rows of a
+        coalesced group's. False: the family goes segment by segment."""
+        from .coalesce import coalesce_enabled
+        from ..realtime.device_plane import RealtimeUploadError
 
-            def run_one(run_query, run_segment):
+        segs = [t.segment for t in members]
+        plans = [t.plan for t in members]
+        mutable = any(getattr(s, "is_mutable", False) for s in segs)
+
+        def dispatch(segs_d=segs, plans_d=plans):
+            # HBM pressure during plane upload/dispatch: evict cold cached
+            # segments once and retry (engine/oom.py — the DirectOOMHandler
+            # analogue). Relief drops whole stacks.
+            return with_oom_retry(
+                lambda: self.tpu.dispatch_plan_batch(segs_d, plans_d,
+                                                     mesh=run.msig),
+                keep_segment=segs[0], cache=self.tpu.cache)
+
+        def co_runner(segs_all, plans_all):
+            pack = dispatch(segs_all, plans_all)
+            with device_fetch():
+                return fetch_packed_batch([pack])[0]
+
+        # cross-query coalescing (engine/coalesce.py): only armed when the
+        # opt-in hold window is set AND the family has repeat traffic. Its
+        # family key carries no snapshot generation, so a held group could
+        # serve one generation's stack to a later query — consuming
+        # families never join
+        co = self.coalescer.offer(
+            run.query.table_name, fkey, segs, plans, run.msig, co_runner) \
+            if coalesce_enabled(run.query) and not mutable else None
+        if co is not None:
+            # this query's S rows are zero-copy views of the group's
+            # fetched stack: host-side already
+            run.fam_outs[fkey] = co.outs
+        else:
+            try:
+                run.fam_packs[fkey] = dispatch()
+            except (BatchFamilyMismatch, RealtimeUploadError,
+                    HbmExhaustedError):
+                # the host key over-grouped; a consuming member's upload
+                # faulted (the per-segment path host-falls that one); or
+                # the [S, N] stacks, ~double the family's footprint, do
+                # not fit even after relief: per-segment is always valid
+                return False
+            run.fam_inputs[fkey] = (segs, plans)
+            run.rt_device |= mutable
+        for row, task in enumerate(members):
+            task.got, task.value = "member", (fkey, row)
+        return True
+
+    def _run_host_work(self, run: _SegmentRun, host_tasks: list) -> None:
+        """Host-fallback segments, while the device works; with
+        num_threads > 1 on the worker pool, the reference's combine-operator
+        fan-out (one task a segment on a shared executor)."""
+        if self.num_threads > 1 and len(host_tasks) > 1:
+            trace, span = TRACING.active_trace(), TRACING.current_span()
+
+            def run_one(task):
                 # traces are thread-local; seed the caller's span so
                 # worker scopes nest under QUERY_PLAN_EXECUTION
-                TRACING.adopt(caller_trace, caller_span)
+                TRACING.adopt(trace, span)
                 try:
-                    cpu0 = time.thread_time_ns()
-                    with TRACING.scope(
-                            f"segment:{getattr(run_segment, 'name', '?')}"):
-                        inter = self.host.execute(run_query, run_segment)
-                    return inter, time.thread_time_ns() - cpu0
+                    return self._host_timed(task)
                 finally:
                     TRACING.adopt(None)
 
-            futs = [
-                (idx, rewrite, self._host_pool().submit(
-                    run_one, run_query, run_segment))
-                for idx, run_query, run_segment, rewrite in host_work]
-            for idx, rewrite, fut in futs:
-                check(done)
-                inter, cpu_ns = fut.result()
-                if tracker is not None:
-                    tracker.add_cpu_ns(cpu_ns)
-                    GLOBAL_ACCOUNTANT.on_allocation(
-                        tracker, _estimate_bytes(inter))
-                intermediates[idx] = (
-                    self._remap_star_tree(rewrite, inter) if rewrite else inter)
-                done += 1
-            host_work = []
-        for idx, run_query, run_segment, rewrite in host_work:
-            check(done)
-            inter = self._account(tracker, lambda: self.host.execute(
-                run_query, run_segment), run_segment)
-            intermediates[idx] = (
-                self._remap_star_tree(rewrite, inter) if rewrite else inter)
-            done += 1
-        solo = [p for p in pending if isinstance(p[5], PackedOuts)]
-        fam_keys = list(fam_packs)
-        if fam_keys or fam_hosts or len(solo) > 1:
-            # ONE device→host transfer for the whole multi-segment batch —
-            # each batched family is already a single flat buffer, solo
-            # packs of equal length concat with it.
-            # async dispatch means an in-flight OOM surfaces HERE on
-            # error-poisoned buffers: the retry must RE-DISPATCH every
-            # pending segment/family after eviction, not re-fetch the dead
-            # outputs
-            def _refetch():
-                packs = [self.tpu.dispatch_plan(p[2], p[4]) for p in solo]
-                packs += [self.tpu.dispatch_plan_batch(*fam_inputs[k],
-                                                       mesh=msig)
-                          for k in fam_keys]
-                return fetch_packed_batch(packs)
+            results = [self._host_pool().submit(run_one, t).result
+                       for t in host_tasks]
+        else:
+            results = [lambda t=t: self._host_timed(t) for t in host_tasks]
+        for task, result in zip(host_tasks, results):
+            run.check()
+            self._finish(run, task, *result())
 
-            if solo or fam_keys:
-                try:
-                    # where the untraced path waits for the device: queue
-                    # behind other requests, execution, pack, copy
-                    with device_fetch():
-                        fetched = with_oom_retry(
-                            lambda: fetch_packed_batch(
-                                [p[5] for p in solo]
-                                + [fam_packs[k] for k in fam_keys]),
-                            cache=self.tpu.cache, retry_fn=_refetch)
-                except RealtimeUploadError:
-                    # double fault: OOM relief dropped the realtime planes
-                    # mid-query and the re-dispatch's re-upload faulted too.
-                    # Upload faults must never fail a query — host-execute
-                    # every still-pending segment instead.
-                    for p in pending:
-                        idx, run_query, run_segment, rewrite = p[:4]
-                        inter = self._account(
-                            tracker, lambda rq=run_query, rs=run_segment:
-                            self.host.execute(rq, rs), run_segment)
-                        intermediates[idx] = (
-                            self._remap_star_tree(rewrite, inter)
-                            if rewrite else inter)
-                        done += 1
-                    pending = []
-                    fetched = []
-                    solo, fam_keys, fam_hosts = [], [], {}
-            else:
-                fetched = []  # coalesced families arrive host-side already
-            solo_outs = {id(p): raw for p, raw in zip(solo, fetched)}
-            fam_outs = dict(zip(fam_keys, fetched[len(solo):]))
-            fam_outs.update(fam_hosts)
-            # vectorized family combine (engine/combine.py): dense and
-            # un-grouped aggregation families decode all members in one
-            # pass over the batched arrays; other modes slice per member
-            # and ride the normal collect()
-            from .combine import (combine_batched_aggregation,
-                                  combine_batched_dense)
+    def _fetch(self, run: _SegmentRun, tasks: list) -> None:
+        """ONE device→host transfer for the whole multi-segment batch: each
+        batched family is already a single flat buffer, solo packs of equal
+        length concat with it. A lone solo pack is left to its collect();
+        coalesced families are host-side already."""
+        from ..realtime.device_plane import RealtimeUploadError
 
-            precomputed: dict = {}
-            for fkey in fam_outs:
-                members = [p for p in pending
-                           if not isinstance(p[5], PackedOuts)
-                           and p[5][0] == fkey]
-                plans_f = [p[4] for p in members]
-                mode = plans_f[0].program.mode
-                batched = None
-                if mode == "group_by":
-                    batched = combine_batched_dense(fam_outs[fkey], plans_f)
-                elif mode == "aggregation":
-                    batched = combine_batched_aggregation(
-                        fam_outs[fkey], plans_f)
-                if batched is not None:
-                    for row, inter in enumerate(batched):
-                        precomputed[(fkey, row)] = inter
-            new_pending = []
-            for p in pending:
-                tok = p[5]
-                if isinstance(tok, PackedOuts):
-                    new_pending.append(p[:5] + (solo_outs[id(p)],))
-                elif tok in precomputed:
-                    new_pending.append(p[:5] + (precomputed[tok],))
-                else:
-                    fkey, row = tok
-                    # zero-copy per-segment views of the batched [S, ...]
-                    # host arrays; collect() consumes them unchanged
-                    new_pending.append(
-                        p[:5] + ([o[row] for o in fam_outs[fkey]],))
-            pending = new_pending
-        for idx, run_query, run_segment, rewrite, plan, outs in pending:
-            check(done)
-            if isinstance(outs, (AggIntermediate, GroupByIntermediate)):
-                # vectorized family combine already decoded this member
-                inter = self._account(tracker, lambda o=outs: o, run_segment)
-                intermediates[idx] = (
-                    self._remap_star_tree(rewrite, inter) if rewrite
-                    else inter)
-                done += 1
-                continue
+        solo = [t for t in tasks if t.got == "pack"]
+        fam_keys = list(run.fam_packs)
+        if not fam_keys and len(solo) < 2:
+            return
 
-            def _recollect(run_query=run_query, run_segment=run_segment,
-                           plan=plan):
-                return self.tpu.collect(
-                    run_query, run_segment, plan,
-                    self.tpu.dispatch_plan(run_segment, plan))
+        # async dispatch means an in-flight OOM surfaces HERE on
+        # error-poisoned buffers: the retry must RE-DISPATCH every pending
+        # segment/family after eviction, not re-fetch the dead outputs
+        def refetch():
+            return fetch_packed_batch(
+                [self.tpu.dispatch_plan(t.segment, t.plan) for t in solo]
+                + [self.tpu.dispatch_plan_batch(*run.fam_inputs[k],
+                                                mesh=run.msig)
+                   for k in fam_keys])
 
-            inter = self._account(
-                tracker,
-                lambda: with_oom_retry(
-                    lambda: self.tpu.collect(
-                        run_query, run_segment, plan, outs),
-                    keep_segment=run_segment, cache=self.tpu.cache,
-                    retry_fn=_recollect),
-                run_segment)
-            intermediates[idx] = (
-                self._remap_star_tree(rewrite, inter) if rewrite else inter)
-            done += 1
-        if cache_inserts:
-            from ..cache.partial import GLOBAL_PARTIAL_CACHE
+        try:
+            # where the untraced path waits for the device: queue behind
+            # other requests, execution, pack, copy
+            with device_fetch():
+                fetched = with_oom_retry(
+                    lambda: fetch_packed_batch(
+                        [t.value for t in solo]
+                        + [run.fam_packs[k] for k in fam_keys]),
+                    cache=self.tpu.cache, retry_fn=refetch)
+        except RealtimeUploadError:
+            # double fault: OOM relief dropped the realtime planes
+            # mid-query and the re-dispatch's re-upload faulted too. Upload
+            # faults must never fail a query — host-execute every
+            # still-pending segment instead.
+            for task in tasks:
+                if task.got in ("pack", "member"):
+                    self._host_execute(run, task)
+            run.fam_outs.clear()
+            return
+        for task, raw in zip(solo, fetched):
+            task.got, task.value = "arrays", raw
+        run.fam_outs.update(zip(fam_keys, fetched[len(solo):]))
 
-            for idx, key, seg_name in cache_inserts:
-                inter = intermediates[idx]
-                # selections bypass (LIMIT makes row sets order-dependent
-                # across segments and the payoff is row materialization,
-                # not device work); agg/group partials are pure merges
-                if isinstance(inter, (AggIntermediate, GroupByIntermediate)):
-                    GLOBAL_PARTIAL_CACHE.put(key, inter, (seg_name,))
-        if rt_device:
-            note_realtime_device_query()
-        return intermediates
+    def _decode(self, run: _SegmentRun, tasks: list) -> None:
+        """Fetched outputs → intermediates. Vectorized family combine
+        (engine/combine.py): dense and un-grouped aggregation families
+        decode all members in one pass over the batched arrays; other modes
+        slice per member and ride the normal collect()."""
+        from .combine import (combine_batched_aggregation,
+                              combine_batched_dense)
+
+        vectorized = {"group_by": combine_batched_dense,
+                      "aggregation": combine_batched_aggregation}
+        for fkey, outs in run.fam_outs.items():
+            members = [t for t in tasks
+                       if t.got == "member" and t.value[0] == fkey]
+            plans = [t.plan for t in members]
+            decode = vectorized.get(plans[0].program.mode)
+            batched = decode(outs, plans) if decode else None
+            for row, task in enumerate(members):
+                # else: zero-copy per-segment views of the batched
+                # [S, ...] host arrays; collect() consumes them unchanged
+                task.got, task.value = ("decoded", batched[row]) \
+                    if batched is not None \
+                    else ("arrays", [o[row] for o in outs])
+        for task in tasks:
+            if task.got in ("pack", "arrays", "decoded"):
+                run.check()
+                self._finish(run, task, *self._timed(
+                    task.segment, lambda: self._collect(task)))
+
+    def _collect(self, task: _SegmentTask):
+        if task.got == "decoded":
+            return task.value
+        return with_oom_retry(
+            lambda: self.tpu.collect(task.query, task.segment, task.plan,
+                                     task.value),
+            keep_segment=task.segment, cache=self.tpu.cache,
+            retry_fn=lambda: self.tpu.collect(
+                task.query, task.segment, task.plan,
+                self.tpu.dispatch_plan(task.segment, task.plan)))
+
+    @staticmethod
+    def _timed(segment, fn):
+        """(fn(), its thread CPU ns), under the segment's span."""
+        cpu0 = time.thread_time_ns()
+        with TRACING.scope(f"segment:{getattr(segment, 'name', '?')}"):
+            inter = fn()
+        return inter, time.thread_time_ns() - cpu0
+
+    def _host_timed(self, task: _SegmentTask):
+        return self._timed(task.segment, lambda: self.host.execute(
+            task.query, task.segment))
+
+    def _host_execute(self, run: _SegmentRun, task: _SegmentTask) -> None:
+        self._finish(run, task, *self._host_timed(task))
+
+    def _finish(self, run: _SegmentRun, task: _SegmentTask, inter,
+                cpu_ns: int) -> None:
+        """What every stage ends a segment with: account its intermediate
+        to the query, take a star-tree result back to the outer
+        aggregations, store it."""
+        if run.tracker is not None:
+            run.tracker.add_cpu_ns(cpu_ns)
+            GLOBAL_ACCOUNTANT.on_allocation(run.tracker,
+                                            _estimate_bytes(inter))
+        if task.rewrite is not None:
+            inter = self._remap_star_tree(task.rewrite, inter)
+        run.intermediates[task.idx], task.got = inter, "done"
+        run.done += 1
 
     def _segment_cache_enabled(self, query: QueryContext) -> bool:
         """Segment partial-result caching is ON by default for the device
@@ -901,40 +922,60 @@ class QueryExecutor:
         return str(query.query_options.get("segmentBatch")).lower() \
             not in ("false", "0", "off")
 
-    def _mesh_enabled(self, query: QueryContext) -> bool:
-        """Mesh execution (segment-axis sharding of batch families over the
-        local devices) is ON by default when more than one device exists;
-        ``SET meshExecution = false`` opts a query out and
-        PINOT_TPU_MESH_DEVICES sizes/disables it process-wide."""
-        return str(query.query_options.get("meshExecution")).lower() \
-            not in ("false", "0", "off")
-
     def _mesh_sig(self, query: QueryContext) -> tuple:
         """Mesh shape for this query's family dispatches: (ndev,) when the
         sharded path is active, () for solo batching. Part of the batch
-        family key so sharded and solo executables cache separately."""
-        if self.backend == "host" or not self._mesh_enabled(query):
+        family key so sharded and solo executables cache separately. Mesh
+        execution (segment-axis sharding of batch families over the local
+        devices) is ON by default when more than one device exists; ``SET
+        meshExecution = false`` opts a query out and PINOT_TPU_MESH_DEVICES
+        sizes/disables it process-wide."""
+        if self.backend == "host" or str(query.query_options.get(
+                "meshExecution")).lower() in ("false", "0", "off"):
             return ()
         from ..parallel.mesh import mesh_device_count
 
         ndev = mesh_device_count()
         return (ndev,) if ndev > 1 else ()
 
-    def _batch_families(self, query: QueryContext, pairs: list,
-                        mesh: tuple = ()):
-        """executor.batch_families under this query's options: (plans
-        with their sorted tables sized alike, ordered (fkey, positions)
-        groups; fkey None for pairs that take the per-segment path)."""
-        return batch_families(pairs, mesh,
-                              self._segment_batch_enabled(query))
-
     # device merge ops per sparse AggOp kind (count columns merge like sums)
     _SPARSE_COMBINE_KINDS = {"count": "add", "sum": "add", "sumsq": "add",
                              "min": "min", "max": "max"}
 
-    def _try_sparse_device_combine(self, query: QueryContext, kept, tracker,
-                                   check, cstats=None,
-                                   planned=lambda: None):
+    def _device_merge_takes(self, query: QueryContext, tasks: list,
+                            host_tasks: list) -> bool:
+        """Whether `_merge_on_device` can take the query: shapes where
+        value-space keys are exact. Every kept segment planned as a sparse
+        group-by with no star-tree rewrite, one identifier group key over
+        an integer dictionary, aggregations the device merges
+        (`_SPARSE_COMBINE_KINDS`), all vectorizable; `SET deviceCombine =
+        false` opts a query out."""
+        import numpy as np
+
+        if len(tasks) < 2 or host_tasks or query.query_options.get(
+                "deviceCombine") in (False, "false", 0):
+            return False
+        p0 = tasks[0].plan.program
+        agg_kinds = tuple(a.kind for a in p0.aggs)
+        if not agg_kinds or any(k not in self._SPARSE_COMBINE_KINDS
+                                for k in agg_kinds):
+            return False
+        return all(
+            t.rewrite is None
+            and p.mode == "group_by_sparse"
+            and p.group_strides == (1,)
+            and len(p.group_slots) == 1
+            and not p.group_vexprs
+            and p.mv_group_slot is None
+            and p.exact_trim == p0.exact_trim
+            and tuple(a.kind for a in p.aggs) == agg_kinds
+            and t.plan.group_dims
+            and np.issubdtype(
+                t.plan.group_dims[0].dictionary.values.dtype, np.integer)
+            and all(la.vec is not None for la in t.plan.lowered_aggs)
+            for t in tasks for p in (t.plan.program,))
+
+    def _merge_on_device(self, run: _SegmentRun, tasks: list, families: list):
         """Server-level merge ON DEVICE for multi-segment single-key sparse
         group-bys: one batch dispatch of the segments' kernels, each key
         column taken to dictionary VALUE space on device (dictionaries are
@@ -944,93 +985,49 @@ class QueryExecutor:
         (combine.trim_group_by: same size, same conditions, the whole
         ORDER BY) — so what crosses to the host is the cut, or the merged
         groups, and never S tables for the host's factorize/scatter merge.
-        Restricted to shapes where value-space keys are exact: one
-        identifier group key over an integer dictionary, vectorizable aggs
-        only. Returns the 1-element intermediates list, or None to fall
-        back to the normal per-segment collect + host merge (any failure
-        here is recoverable — nothing has been consumed)."""
-        if query.query_options.get("deviceCombine") in (False, "false", 0):
-            return None
+        Two cache tiers of its own (cache/partial.py): the fully merged
+        host GroupArrays keyed by the ORDERED per-segment keys — a hit is
+        the whole warm repeat with ZERO device dispatches — and per-segment
+        value-space tables kept DEVICE-resident against the HBM budget, so
+        partial overlap still skips member dispatches. Returns the
+        1-element intermediates list, or None to fall back to the
+        per-segment stages (any failure here is recoverable — nothing has
+        been consumed)."""
         import logging
 
-        import numpy as np
+        from ..cache.partial import GLOBAL_PARTIAL_CACHE
 
-        plans, segs = [], []
-        for segment in kept:
-            run_query, run_segment, rewrite = self._segment_route(
-                query, segment)
-            if rewrite is not None:
-                return None
-            try:
-                plans.append(self.tpu.plan(run_query, run_segment))
-            except UnsupportedQueryError:
-                return None
-            if plans[-1].program.mode != "group_by_sparse":
-                return None
-            segs.append(run_segment)
-        p0 = plans[0].program
-        kinds = tuple(self._SPARSE_COMBINE_KINDS.get(a.kind)
-                      for a in p0.aggs)
-        agg_kinds = tuple(a.kind for a in p0.aggs)
-        if not kinds or None in kinds:
-            return None
-        for pl in plans:
-            p = pl.program
-            if not (p.group_strides == (1,)
-                    and len(p.group_slots) == 1
-                    and not p.group_vexprs
-                    and p.mv_group_slot is None
-                    and p.exact_trim == p0.exact_trim
-                    and tuple(a.kind for a in p.aggs) == agg_kinds
-                    and pl.group_dims
-                    and np.issubdtype(
-                        pl.group_dims[0].dictionary.values.dtype,
-                        np.integer)
-                    and all(la.vec is not None for la in pl.lowered_aggs)):
-                return None
-        # one table size for the query's segments (one Program, one
-        # family), before anything is keyed by a plan
-        msig = self._mesh_sig(query)
-        plans, families = self._batch_families(
-            query, list(zip(segs, plans)), mesh=msig)
-        # two cache tiers for this path (cache/partial.py): the fully
-        # merged host GroupArrays keyed by the ORDERED per-segment keys —
-        # a hit is the whole warm repeat with ZERO device dispatches — and
-        # per-segment value-space tables kept DEVICE-resident against the
-        # HBM budget, so partial overlap still skips member dispatches and
-        # feeds the device combine directly.
-        cache_on = self._segment_cache_enabled(query)
-        keys = None
+        segs = [t.segment for t in tasks]
+        plans = [t.plan for t in tasks]
+        keys = [t.cache_key for t in tasks]
         merged_key = None
-        if cache_on:
-            keys = [self._partial_cache_key(query, seg, None, pl)
-                    for seg, pl in zip(segs, plans)]
-            if all(k is not None for k in keys):
-                from ..cache.partial import GLOBAL_PARTIAL_CACHE
-
-                # sorted: the sort/edge-reduce merge is order-insensitive,
-                # so any segment ordering of the same set may hit
-                merged_key = ("sparse_merged",) + tuple(sorted(keys))
-                hit = GLOBAL_PARTIAL_CACHE.get(merged_key)
-                if hit is not None:
-                    if cstats is not None:
-                        cstats["hit"] += len(segs)
-                    if tracker is not None:
-                        GLOBAL_ACCOUNTANT.on_allocation(
-                            tracker, _estimate_bytes(hit))
-                    with TRACING.scope("SEGMENT_CACHE(hit:merged)") as sp:
-                        if sp is not None:
-                            sp.set_attribute("segments", len(segs))
-                            sp.set_attribute("cache", "hit")
-                            sp.set_attribute("cacheHitBytes",
-                                             int(_estimate_bytes(hit)))
-                    return [hit]
-            else:
-                keys = None
+        if None in keys:
+            keys = None
+        else:
+            # sorted: the sort/edge-reduce merge is order-insensitive, so
+            # any segment ordering of the same set may hit
+            merged_key = ("sparse_merged",) + tuple(sorted(keys))
+            hit = GLOBAL_PARTIAL_CACHE.get(merged_key)
+            if hit is not None:
+                run.cstats["hit"] += len(segs)
+                if run.tracker is not None:
+                    GLOBAL_ACCOUNTANT.on_allocation(
+                        run.tracker, _estimate_bytes(hit))
+                with TRACING.scope("SEGMENT_CACHE(hit:merged)") as sp:
+                    if sp is not None:
+                        sp.set_attribute("segments", len(segs))
+                        sp.set_attribute("cache", "hit")
+                        sp.set_attribute("cacheHitBytes",
+                                         int(_estimate_bytes(hit)))
+                return [hit]
+        run.planned()
         try:
             ga, stats = self._sparse_device_combine(
-                segs, plans, families, msig, kinds, _cut_order(query, plans[0]),
-                keys, check, cstats, planned)
+                segs, plans, families, run.msig,
+                tuple(self._SPARSE_COMBINE_KINDS[a.kind]
+                      for a in plans[0].program.aggs),
+                _cut_order(run.query, plans[0]), keys, run.check,
+                run.cstats)
         except TimeoutError:
             raise
         except Exception as e:
@@ -1044,24 +1041,18 @@ class QueryExecutor:
                 "fallback", type(e).__name__, e)
             return None
         if merged_key is not None:
-            from ..cache.partial import GLOBAL_PARTIAL_CACHE
-
             GLOBAL_PARTIAL_CACHE.put(
                 merged_key, ga,
                 tuple(getattr(s, "name", "?") for s in segs))
-        if tracker is not None:
-            GLOBAL_ACCOUNTANT.on_allocation(tracker, _estimate_bytes(ga))
-        if any(getattr(s, "is_mutable", False) for s in segs):
-            from ..realtime.device_plane import note_realtime_device_query
-
-            note_realtime_device_query()
-        if cstats is not None:
-            cstats.update(stats)
+        if run.tracker is not None:
+            GLOBAL_ACCOUNTANT.on_allocation(run.tracker, _estimate_bytes(ga))
+        run.rt_device = any(getattr(s, "is_mutable", False) for s in segs)
+        run.cstats.update(stats)
         return [ga]
 
     def _sparse_device_combine(self, segs, plans, families, msig, kinds,
-                               cut, keys, check, cstats, planned):
-        """The device half of `_try_sparse_device_combine`: (merged
+                               cut, keys, check, cstats):
+        """The device half of `_merge_on_device`: (merged
         GroupArrays, what the device merge counted). `cut` is the trim the
         device may apply (`_cut_order`) or None. Raises on anything it
         cannot do; the caller falls back."""
@@ -1081,7 +1072,6 @@ class QueryExecutor:
         # 64-bit sorts are emulated on the chip: merge on int32 keys
         # wherever every dictionary's values fit (below the sentinel)
         key32 = -(1 << 31) <= lowest and highest < (1 << 31) - 1
-        planned()
         p0 = plans[0].program
         slots = p0.num_groups
         # a per-segment table in value space is kept on the device for a
@@ -1106,7 +1096,7 @@ class QueryExecutor:
                 continue
             if fkey is not None and len(positions) > 1:
                 try:
-                    # same batched-OOM discipline as _run_segments: a
+                    # same batched-OOM discipline as _dispatch_family: a
                     # transient OOM gets one eviction+retry, a persistent
                     # one (or a family-key drift) falls back to the 1x-
                     # footprint per-segment dispatch loop below instead
@@ -1143,7 +1133,7 @@ class QueryExecutor:
                     [segs[i] for i in positions], column)
                 how.append("plane")
             tables.append((outs[-1], source, outs[0], tuple(outs[1:-1])))
-            if keys is not None and cstats is not None:
+            if keys is not None:
                 cstats["miss"] += len(positions)
             if tabs_on:
                 values = kernels.table_keys_to_values(
@@ -1158,8 +1148,7 @@ class QueryExecutor:
             tables.append((tab[0][None], None, tab[1][None],
                            tuple(t[None] for t in tab[2:])))
             how.append("values")
-            if cstats is not None:
-                cstats["hit"] += 1
+            cstats["hit"] += 1
         # what may cross blind: the merged groups are at most the slots
         # merged and at most the integers between the dictionaries' ends
         bound = min(len(segs) * slots, highest - lowest + 1)
@@ -1240,15 +1229,6 @@ class QueryExecutor:
         if rewrite is not None:
             return rewrite.query, rewrite.view, rewrite
         return query, segment, None
-
-    def _account(self, tracker, fn, segment):
-        cpu0 = time.thread_time_ns()
-        with TRACING.scope(f"segment:{getattr(segment, 'name', '?')}"):
-            inter = fn()
-        if tracker is not None:
-            tracker.add_cpu_ns(time.thread_time_ns() - cpu0)
-            GLOBAL_ACCOUNTANT.on_allocation(tracker, _estimate_bytes(inter))
-        return inter
 
     @staticmethod
     def _remap_star_tree(rewrite, result):

@@ -270,3 +270,132 @@ def test_sparse_combine_batched_oom_falls_back(mixed, monkeypatch):
         mixed, "SET sparseGroupBy = true; "
                "SELECT k, COUNT(*), SUM(v) FROM sb "
                "GROUP BY k ORDER BY k LIMIT 100000")
+
+
+# -- one plan a segment, one family_dispatch span whatever the entry ---------
+
+WIDE_KEYS = 1 << 15  # the planner sorts a table from this many keys up
+WIDE = Schema.build("sbw", dimensions=[("wk", "INT")], metrics=[("wv", "INT")])
+
+
+@pytest.fixture(scope="module")
+def sixteen(tmp_path_factory):
+    """Sixteen segments of `sb` (40 keys: dense tables) and sixteen of `sbw`
+    (a key of its own in every row, 32,768 a segment: the planner sorts the
+    table by its own rule and the server merges on the device)."""
+    rng = np.random.default_rng(53)
+    d = tmp_path_factory.mktemp("sb_sixteen")
+    small, wide = [], []
+    for i in range(16):
+        SegmentBuilder(SCHEMA, segment_name=f"x{i}").build(
+            _gen(rng, 1024), d / f"x{i}")
+        small.append(load_segment(d / f"x{i}"))
+        SegmentBuilder(WIDE, segment_name=f"w{i}").build(
+            {"wk": rng.permutation(WIDE_KEYS).astype(np.int32) + 7 * i,
+             "wv": rng.integers(0, 100, WIDE_KEYS).astype(np.int32)},
+            d / f"w{i}")
+        wide.append(load_segment(d / f"w{i}"))
+    qe = QueryExecutor(backend="tpu")
+    qe.add_table(SCHEMA, small)
+    qe.add_table(WIDE, wide)
+    return qe
+
+
+@pytest.mark.parametrize("sql,mode,merged", [
+    pytest.param("SELECT k, SUM(v) FROM sb GROUP BY k LIMIT 100",
+                 "group_by", False, id="dense"),
+    pytest.param("SELECT wk, SUM(wv) FROM sbw GROUP BY wk "
+                 "ORDER BY SUM(wv) DESC, wk LIMIT 10",
+                 "group_by_sparse", True, id="sorted-top-n"),
+    # a sparse group-by the device merge declines (DISTINCTCOUNT is no
+    # column it merges): the per-segment stages take the plans it was shown
+    pytest.param("SET sparseGroupBy = true; SELECT k, DISTINCTCOUNT(d) "
+                 "FROM sb GROUP BY k ORDER BY k LIMIT 100",
+                 "group_by_sparse", False, id="sparse-declined"),
+])
+def test_each_kept_segment_is_routed_and_planned_once(sixteen, monkeypatch,
+                                                      sql, mode, merged):
+    """One route and one plan a kept segment, whichever way the query goes
+    (before PR 31: 17, 16 and 32 plans for these three)."""
+    from pinot_tpu.engine.plan import SegmentPlanner
+
+    planned, modes = [], set()
+    real = SegmentPlanner.plan
+
+    def counting(self):
+        planned.append(self.segment.name)
+        plan = real(self)
+        modes.add(plan.program.mode)
+        return plan
+
+    monkeypatch.setattr(SegmentPlanner, "plan", counting)
+    routed = []
+    real_route = sixteen._segment_route
+
+    def counting_route(query, segment):
+        routed.append(segment.name)
+        return real_route(query, segment)
+
+    monkeypatch.setattr(sixteen, "_segment_route", counting_route)
+    resp = sixteen.execute_sql("SET trace = true; " + sql)
+    assert _rows(resp)
+    assert modes == {mode}
+    names = sorted(s.name for s in sixteen.tables["sbw" if "sbw" in sql else "sb"].segments)
+    assert sorted(planned) == names and sorted(routed) == names, \
+        f"{len(planned)} plans and {len(routed)} routes for 16 segments"
+    assert resp.num_device_dispatches == 1
+    combine = [s for s in resp.trace_info
+               if s["operator"] == "SERVER_COMBINE"]
+    assert ("groupsCombined" in combine[0]["attributes"]) == merged
+
+
+@pytest.mark.parametrize("entry,members,aggs", [
+    ("dispatch_plan", 1, "MIN(v), MAX(f)"),
+    ("dispatch_plan_raw", 1, "MAX(v), MIN(f)"),
+    ("dispatch_plan_batch", 4, "SUM(f), MIN(v)"),
+    ("dispatch_plan_batch_raw", 4, "SUM(f), MAX(v)")])
+def test_every_dispatch_entry_leaves_one_family_dispatch_span(
+        uniform, entry, members, aggs):
+    """The four public entries share one wrapper and one launch routine:
+    each leaves ONE family_dispatch span that names the program, the
+    segments, the compile and the transfers, and counts one dispatch."""
+    from pinot_tpu.engine.ir import program_label
+    from pinot_tpu.engine.query_executor import parse_sql
+    from pinot_tpu.ops.kernels import PackedOuts
+    from pinot_tpu.segment.device_cache import pad_bucket
+    from pinot_tpu.spi.trace import TRACING
+
+    # aggregations of its own for every entry: each compiles its program
+    sql = f"SELECT k, {aggs} FROM sb WHERE d < 9 GROUP BY k LIMIT 100"
+    segs = list(uniform.tables["sb"].segments)[:members]
+    plans = [uniform.tpu.plan(parse_sql(sql), s) for s in segs]
+    args = (segs[0], plans[0]) if members == 1 else (segs, plans)
+    spans = []
+    for _ in range(2):
+        executor_mod.reset_dispatch_counters()
+        trace = TRACING.start_trace(f"entry:{entry}")
+        try:
+            out = getattr(uniform.tpu, entry)(*args)
+        finally:
+            TRACING.end_trace()
+        assert executor_mod.dispatch_counters()[0] == 1
+        # packed for the host, or (raw outputs, view or views) for a
+        # caller that stays on the device
+        assert isinstance(out, PackedOuts) != entry.endswith("_raw")
+        fam = [s for s in trace.to_json()
+               if s["operator"] == "family_dispatch"]
+        assert len(fam) == 1
+        spans.append(fam[0]["attributes"])
+    first, again = spans
+    assert first["program"] == program_label(plans[0].program)
+    assert first["mode"] == "group_by"
+    assert first["padded"] == pad_bucket(segs[0].num_docs)
+    assert first["numSegments"] == members
+    assert first.get("segment") == (segs[0].name if members == 1 else None)
+    assert first["compileMs"] > 0 and again["compileMs"] == 0.0
+    for attrs in spans:
+        assert {"transferBytes", "stackHits", "stackMisses", "dictLookups",
+                "hbmBytesUsed", "hbmBudgetBytes",
+                "hbmEvictions"} <= set(attrs)
+    gathers = [s for s in trace.to_json() if s["operator"] == "GATHER_STACK"]
+    assert len(gathers) == (members > 1)

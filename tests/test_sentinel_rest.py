@@ -96,8 +96,12 @@ def test_sentinel_detects_pins_and_clears_over_rest(cluster):
     store, controller, _server, broker, _d = cluster
     _burst(broker, 8)
     PERF_LEDGER.rotate_now()
+    # the drift floor stands between what six busy test workers do to a
+    # 10 ms query (a few ms at the median) and the injected 100 ms a
+    # dispatch: the alert fires on the fault and clears without it, whatever
+    # else the machine is doing
     sentinel = PerfRegressionSentinel(store, controller, min_queries=3,
-                                      breaches=2, clears=2)
+                                      breaches=2, clears=2, min_abs_ms=40.0)
     report = sentinel.evaluate()
     assert report["anomalies"] == [], report["anomalies"]
 
@@ -108,10 +112,10 @@ def test_sentinel_detects_pins_and_clears_over_rest(cluster):
         assert code == 200 and ledger["numPlans"] >= 1
         assert ledger["plans"][0]["totals"]["queries"] >= 8
 
-        # -- inject: every dispatch +50ms -------------------------------
+        # -- inject: every dispatch +100ms ------------------------------
         alert = None
         with faults.injected("device.dispatch", kind="delay",
-                             delay_s=0.05, times=None):
+                             delay_s=0.1, times=None):
             for _ in range(12):
                 _burst(broker, 6)
                 sentinel.evaluate()
@@ -146,7 +150,7 @@ def test_sentinel_detects_pins_and_clears_over_rest(cluster):
         slow = broker.query_logger.slow_queries()
         linked = [e for e in slow if alert["id"] in e.get("alertIds", [])]
         # (only present if any query crossed the slow threshold — the
-        # 50ms delay is under the 500ms default, so don't require it;
+        # 100ms delay is under the 500ms default, so don't require it;
         # active_ids_for is covered by unit tests)
         for e in linked:
             assert e["table"] == "sentab"
@@ -160,8 +164,10 @@ def test_sentinel_detects_pins_and_clears_over_rest(cluster):
         faults.FAULTS.reset()
         rs.close()
 
-    # -- recovery: clean rounds resolve the alert -----------------------
-    for _ in range(12):
+    # -- recovery: clean rounds resolve the alert (it clears once the
+    # short window's median is a clean query's again and two evaluations
+    # have seen it so; the count of rounds only bounds a hang) -----------
+    for _ in range(60):
         _burst(broker, 6)
         sentinel.evaluate()
         if not ALERTS.active_count:

@@ -7,6 +7,7 @@ PauselessSegmentCompletionFSM behavior.
 
 from __future__ import annotations
 
+import threading
 import time
 
 import numpy as np
@@ -107,6 +108,9 @@ def _config(topic, flush_rows):
         }))
 
 
+CEILING_S = 120.0  # bounds a hang, not a wait
+
+
 def wait_until(pred, timeout=20.0):
     t0 = time.time()
     while time.time() - t0 < timeout:
@@ -123,22 +127,24 @@ def test_pauseless_successor_consumes_during_commit(monkeypatch, tmp_path):
     monkeypatch.setattr(stream_mod, "GLOBAL_STREAM_REGISTRY", reg)
     reg.create_topic("pl", num_partitions=1)
     store = PropertyStore()
+    # no committer dies here: the lease outlasts every wait below
     completion = SegmentCompletionManager(store, num_replicas=1,
-                                          commit_lease_s=30)
+                                          commit_lease_s=4 * CEILING_S)
+    # the hook is called between build and commitEnd, with the segment
+    # sealed: it says so (`sealed`), and holds the commit until the test has
+    # seen the successor consume (`release`). The test waits on these
+    # events, not on a poll of `m._committing`, which the committer can set
+    # and clear between two looks when the machine is busy. The ceilings
+    # only bound a hang.
+    sealed, release = threading.Event(), threading.Event()
     observed = {"overlap": False}
 
     def slow_commit(mgr):
-        # committer dawdles between build and commitEnd until the successor
-        # is seen consuming (ingestion never paused); under several test
-        # workers the successor's thread can take seconds to start, so it
-        # waits for the overlap, up to 10 s, not for one second
-        t0 = time.time()
-        while time.time() - t0 < 10.0:
-            with m._lock:
-                if m._committing and m._consuming:
-                    observed["overlap"] = True
-                    break
-            time.sleep(0.01)
+        with m._lock:
+            # ingestion never paused: the successor is consuming already
+            observed["overlap"] = bool(m._committing and m._consuming)
+        sealed.set()
+        release.wait(CEILING_S)
         return False  # do not die — just slow
 
     m = RealtimeTableDataManager(
@@ -149,20 +155,22 @@ def test_pauseless_successor_consumes_during_commit(monkeypatch, tmp_path):
     try:
         reg.publish("pl", [{"u": f"u{i}", "ts": 1_600_000_000_000 + i,
                             "n": 1} for i in range(25)])
-        # while seg 0 commits (slowed), publish more: the successor consumes
-        assert wait_until(lambda: m._committing)  # sealed, not committed
+        # while seg 0 commits (held), publish more: the successor consumes
+        assert sealed.wait(CEILING_S)
+        assert m._committing  # sealed, not committed
+        assert observed["overlap"]  # committing + consuming coexisted
         reg.publish("pl", [{"u": f"v{i}", "ts": 1_600_000_100_000 + i,
                             "n": 1} for i in range(10)])
         assert wait_until(
-            lambda: sum(s.num_docs for s in m.segments) == 35)
-        # committing + consuming coexisted (the committer reaches its hook
-        # once its build is done, which can be after the successor's rows)
-        assert wait_until(lambda: observed["overlap"])
-        assert wait_until(lambda: len(m._segment_names) >= 1)
-        assert wait_until(lambda: not m._committing)  # commit landed
+            lambda: sum(s.num_docs for s in m.segments) == 35, CEILING_S)
+        assert m._committing  # all 35 rows in while the commit was open
+        release.set()
+        assert wait_until(lambda: len(m._segment_names) >= 1, CEILING_S)
+        assert wait_until(lambda: not m._committing, CEILING_S)  # landed
         # everything stays queryable, exactly once
         assert sum(s.num_docs for s in m.segments) == 35
     finally:
+        release.set()
         m.stop()
 
 
