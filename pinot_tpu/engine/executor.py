@@ -20,8 +20,9 @@ import jax
 import numpy as np
 import jax.numpy as jnp
 
-from ..ops.kernels import (PackedOuts, dict_lookups, fetch_tally, pack_outputs,
-                           run_program, unpack_outputs)
+from ..ops.kernels import (PackedOuts, dict_lookups, fetch_tally,
+                           min_max_forms, pack_outputs, run_program,
+                           unpack_outputs)
 from .aot_cache import AOT_READY, aot_call
 from ..query.context import QueryContext
 from ..segment.device_cache import (
@@ -405,12 +406,14 @@ class TpuSegmentExecutor:
         if not count_after:
             _count_dispatch(new_compile)
         if span is not None:
-            # mode, label, row bucket, and how the planes the program is
-            # fed decode their dictionaries (kernels.dict_lookups)
+            # mode, label, row bucket, how the planes the program is fed
+            # decode their dictionaries (kernels.dict_lookups) and how a
+            # dense table takes its MINs and MAXs (kernels.min_max_forms)
             span.set_attribute("mode", program.mode)
             span.set_attribute("program", program_label(program))
             span.set_attribute("padded", padded)
             span.set_attribute("dictLookups", dict_lookups(program, arrays))
+            span.set_attribute("minMax", min_max_forms(program))
         t0 = time.perf_counter()
         outs = run()
         if count_after:

@@ -86,6 +86,38 @@ def dict_lookups(program: ir.Program, arrays) -> str:
     return f"select:{forms.count('select')},gather:{forms.count('gather')}"
 
 
+# Most groups (the trash slot apart) of a dense table whose MIN and MAX are
+# masked reductions; a larger table keeps the scatter. On a v5e, a MIN and a
+# MAX over 16 x 2^22 rows by scatter take 66-74 ms a segment whatever the
+# table's size (690-790 ms for float64 values: 64-bit scatters are
+# emulated); the reduction costs a compare, a select and a min a row AND
+# group: 0.9 ms a segment at 8 groups, 1.6 at 128, 2.7 at 256, 4.2 at 512,
+# 14.4 at 2,048 (int32; float32 a little under). Set at the largest swept
+# size where the reduction is ahead 2 x or more both in a first use
+# (compile and one run: 1.65-1.72 s against 0.65-0.72 at 256) and in the
+# steady state (26-32 x there); at 512 one first use of four read 1.99 x.
+# PERF.md section 6, PR 32, has the sweep
+# (`python -m pinot_tpu.tools.minmax_sweep`).
+MINMAX_REDUCE_MAX_GROUPS = 256
+
+
+def min_max_form(num_groups: int) -> str:
+    """How a dense group-by of `num_groups` groups takes a MIN or a MAX:
+    the one rule that `_run_agg` lowers by and `min_max_forms` counts by."""
+    return "reduce" if 0 < num_groups <= MINMAX_REDUCE_MAX_GROUPS \
+        else "scatter"
+
+
+def min_max_forms(program: ir.Program) -> str:
+    """`reduce:<n>,scatter:<m>`: how many of the program's MIN and MAX
+    aggregations take each form. Only the dense group-by chooses: the
+    ungrouped, sort-based and selection programs read 0 and 0."""
+    forms = [min_max_form(program.num_groups) for agg in program.aggs
+             if agg.kind in ("min", "max")] \
+        if program.mode == "group_by" else []
+    return f"reduce:{forms.count('reduce')},scatter:{forms.count('scatter')}"
+
+
 def _eval_value(node: ir.ValueExpr, arrays, params):
     if isinstance(node, ir.Col):
         return arrays[node.slot]
@@ -718,11 +750,12 @@ def _dense_group_by_entry(program: ir.Program, arrays, params, mask, n):
 def _run_dense_group_by(program: ir.Program, arrays, params, mask, gid,
                         num_segments, n):
     """COUNT and every int32-safe SUM ride ONE MXU pass (8-bit limb planes
-    through the kron-factored one-hot matmul — ops/mxu_groupby.py); scatters
-    only remain for what the MXU cannot reduce (min/max, float sums, matrix
-    ops). Replaces the batched (n, C) vector-payload scatter, whose minor
-    dim was padded 6→128 lanes by TPU tiling (a 21x HBM blowup that OOMed
-    real 100M-row segments)."""
+    through the kron-factored one-hot matmul — ops/mxu_groupby.py); a MIN
+    or MAX over a few groups is a masked reduction (`min_max_form`);
+    scatters only remain for what neither can reduce (min/max of a larger
+    table, float sums, matrix ops). Replaces the batched (n, C)
+    vector-payload scatter, whose minor dim was padded 6→128 lanes by TPU
+    tiling (a 21x HBM blowup that OOMed real 100M-row segments)."""
     planes = [mask.astype(mxu_groupby.PLANE_DTYPE)]  # count plane
     recipes: list = []  # per agg: callable(sums, counts) | None → _run_agg
     for agg in program.aggs:
@@ -1518,6 +1551,10 @@ def _run_agg(agg: ir.AggOp, arrays, params, mask, gid, num_segments, n,
         return counts[: num_groups * bins].reshape(num_groups, bins)
     v = _eval_value(agg.vexpr, arrays, params)
     fast32 = jnp.issubdtype(v.dtype, jnp.integer) and _fits_i32(v, agg)
+    if agg.kind in ("min", "max") and \
+            min_max_form(num_segments - 1) == "reduce":
+        return _min_max_reduce(agg.kind, v, mask, gid, num_segments,
+                               counts if fast32 else None)
     if agg.kind == "sum":
         if fast32:
             vm = jnp.where(mask, v, 0)
@@ -1555,6 +1592,40 @@ def _run_agg(agg: ir.AggOp, arrays, params, mask, gid, num_segments, n,
         v = jnp.where(mask, v, -jnp.inf).astype(jnp.float64)
         return jax.ops.segment_max(v, gid, num_segments=num_segments)
     raise ValueError(f"unknown agg kind {agg.kind}")
+
+
+def _min_max_reduce(kind: str, v, mask, gid, num_segments: int, counts):
+    """MIN or MAX of a dense group-by over a FEW groups with no scatter:
+    out[g] = reduce(where(gid == g, v, identity)) for every group at once,
+    one broadcast compare against the group numbers reduced over the rows.
+    XLA fuses compare, select and reduce (a MIN and a MAX of one program
+    into ONE fusion over the masked values and the group ids, which pass
+    HBM as (rows,) planes as they did for the scatter), so nothing of
+    (groups, rows) reaches HBM and the compile time does not grow with
+    the groups (no chain of selects: `_dict_lookup` says what those
+    cost). The output is `_run_agg`'s
+    scatter form's bit for bit, on its three value paths: int32 with the
+    empty groups read off `counts` (never off a sentinel that a value
+    could equal), float32, and float64 for everything else. The trash
+    slot holds the masked rows alone, which carry the identity: it is
+    appended, not reduced."""
+    lo = kind == "min"
+    if counts is not None:
+        ident = jnp.int32(_I32_MAX if lo else _I32_MIN)
+        vm = jnp.where(mask, v.astype(jnp.int32), ident)
+    elif v.dtype == jnp.float32:
+        ident = jnp.float32(jnp.inf if lo else -jnp.inf)
+        vm = jnp.where(mask, v, ident)
+    else:
+        ident = jnp.float64(jnp.inf if lo else -jnp.inf)
+        vm = jnp.where(mask, v, ident).astype(jnp.float64)
+    groups = jnp.arange(num_segments - 1, dtype=jnp.int32)
+    picked = jnp.where(gid[None, :] == groups[:, None], vm[None, :], ident)
+    out = picked.min(axis=1) if lo else picked.max(axis=1)
+    out = jnp.concatenate([out, ident[None]]).astype(jnp.float64)
+    if counts is None:
+        return out
+    return jnp.where(counts == 0, jnp.inf if lo else -jnp.inf, out)
 
 
 # ---------------------------------------------------------------------------

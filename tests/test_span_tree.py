@@ -318,6 +318,33 @@ def _cut_merge(engine):
         kinds=("add",), order=(1, True, False), cut_slots=8, table_slots=0)
 
 
+def test_dense_min_max_reductions_are_filed_under_group_by_dense(engine):
+    # the masked reductions of a small table's MIN and MAX (PR 32) carry
+    # the scope `_run_masked` opens, so `kernel_groupby_ms` counts them
+    # (the scatters they replace ran as loops the trace gave no name)
+    from pinot_tpu.ops import kernels
+
+    segs = list(engine.tables["sptab"].segments)
+    sql = "SELECT spy, MIN(spv), MAX(spv) FROM sptab WHERE spd < 9 " \
+        "GROUP BY spy LIMIT 50"
+    plans = [_plan(engine, sql)[1] for _ in segs]
+    program = plans[0].program
+    assert kernels.min_max_forms(program) == "reduce:2,scatter:0"
+    views, arrays, params, packed, num_docs = engine.tpu._gather_batch(
+        segs, plans)
+    text = kernels.run_program_batch.lower(
+        program, arrays, params, num_docs, views[0].padded,
+        packed=packed).compile().as_text()
+    scope_of = _benchmark_scope_of()
+    names = set(re.findall(r'op_name="([^"]+)"', text))
+    reductions = [n for n in names
+                  if n.endswith(("/reduce_min", "/reduce_max"))]
+    assert {n.rsplit("/", 1)[1] for n in reductions} \
+        == {"reduce_min", "reduce_max"}
+    assert all(scope_of(n) == "group_by_dense" for n in reductions), \
+        reductions
+
+
 @pytest.mark.parametrize("lower,scope,inside", [
     pytest.param(_sorted_family, "group_by_sparse", "while/body",
                  id="the-sorted-family-under-lax-map"),

@@ -61,6 +61,11 @@ def test_limb_kernel_compiles(one_chip, dtype, n, groups, planes):
 
 # -- engine programs ---------------------------------------------------------
 
+
+def _op_count(text: str, op: str) -> int:
+    return len(re.findall(rf"= \S+ {op}\(", text))
+
+
 _GROUP2 = ("SELECT d_year, p_brand, SUM(lo_revenue), COUNT(*) FROM t "
            "WHERE {where} GROUP BY d_year, p_brand LIMIT 10000")
 
@@ -116,6 +121,58 @@ def test_dense_group_by_compiles(one_chip, ssb, monkeypatch):
     assert "tpu_custom_call" in text
 
 
+# `dd_distinct_by_year` of the benchmark's `ssb16.drilldown`
+_DISTINCT_BY_YEAR = (
+    "SELECT d_year, DISTINCTCOUNT(lo_discount), MIN(lo_revenue), "
+    "MAX(lo_revenue) FROM t WHERE lo_quantity BETWEEN 12 AND 37 "
+    "GROUP BY d_year LIMIT 10")
+_MIN_MAX_BY_BRAND = (
+    "SELECT p_brand, MIN(lo_revenue), MAX(lo_revenue), MIN(lo_tax) FROM t "
+    "WHERE lo_quantity BETWEEN 12 AND 37 GROUP BY p_brand LIMIT 10000")
+
+
+@pytest.mark.parametrize("sql,groups,label,scatters", [
+    pytest.param(_DISTINCT_BY_YEAR, 0,
+                 "gby_rng_c0_by1_distinctbitmap_i2_min_c3_max_c3", False,
+                 id="dd_distinct_by_year"),
+    pytest.param(_MIN_MAX_BY_BRAND, kernels.MINMAX_REDUCE_MAX_GROUPS, None,
+                 False, id="at-the-constant"),
+    pytest.param(_MIN_MAX_BY_BRAND, kernels.MINMAX_REDUCE_MAX_GROUPS + 1,
+                 None, True, id="above-the-constant"),
+])
+def test_min_max_over_few_groups_compiles_without_a_scatter(
+        one_chip, ssb, monkeypatch, sql, groups, label, scatters):
+    """A dense group-by's MIN and MAX over a 16 x 2^22-row family: up to
+    `MINMAX_REDUCE_MAX_GROUPS` groups they are masked reductions (int32
+    and float64 value paths here) with no scatter in the optimised HLO
+    (two of 0.6 s a dispatch on the chip for 7 years, PERF.md, PR 32) and
+    no loop in its place; one group more keeps the scatters as they were.
+    The reduction is ONE broadcast compare whatever the groups: the time
+    bound holds it to that (a chain of selects a group would not)."""
+    monkeypatch.setattr(mxu_groupby, "backend_platform", lambda: "tpu")
+    t0 = time.perf_counter()
+    program, text = compile_program(one_chip, ssb, sql, R22, batch=16,
+                                     dense_groups=groups)
+    took = time.perf_counter() - t0
+    print(f"{ir.program_label(program)} x {program.num_groups} groups: "
+          f"compiled in {took:.1f} s")
+    assert took < 60  # 1-3 s alone; the issue's 10 s is the chip's host
+    assert program.mode == "group_by"
+    n = sum(agg.kind in ("min", "max") for agg in program.aggs)
+    assert kernels.min_max_forms(program) == (
+        f"reduce:0,scatter:{n}" if scatters else f"reduce:{n},scatter:0")
+    if label:
+        assert ir.program_label(program) == label
+    assert (_op_count(text, "scatter") > 0) == scatters
+    assert _op_count(text, "while") == 0
+    assert "tpu_custom_call" in text  # the COUNT column's limb kernel
+    # the reductions' fusion is named by a reduction, under the scope the
+    # benchmark's `kernel_groupby_ms` reads
+    reductions = re.findall(r'op_name="([^"]+/reduce_m(?:in|ax))"', text)
+    assert bool(reductions) != scatters
+    assert all("/vmap(group_by_dense)/" in n for n in reductions)
+
+
 def test_sparse_batch_family_compiles(one_chip, ssb):
     """The multi-segment form: one vmapped dispatch over 16 x 2^22 rows."""
     program, _ = compile_program(
@@ -154,10 +211,6 @@ _Q1_1 = ("SELECT SUM(lo_extendedprice * lo_discount) FROM t WHERE d_year = 1993 
 _Q1_3 = ("SELECT SUM(lo_extendedprice * lo_discount) FROM t WHERE p_brand = 6 "
          "AND d_year = 1994 AND lo_discount BETWEEN 5 AND 7 "
          "AND lo_quantity BETWEEN 26 AND 35")
-
-
-def _op_count(text: str, op: str) -> int:
-    return len(re.findall(rf"= \S+ {op}\(", text))
 
 
 @pytest.mark.parametrize("sql", [pytest.param(_Q1_1, id="q1.1"),

@@ -105,7 +105,8 @@ def spec(one_chip, shape, dtype):
 
 
 def compile_program(one_chip, ssb, sql, padded, *, batch=0, fused="",
-                     sparse_groups=0, dict_len=0, whole_table=False):
+                     sparse_groups=0, dict_len=0, whole_table=False,
+                     dense_groups=0):
     """Plan ``sql`` against the small segment, then lower run_program /
     run_program_batch with every row plane scaled to ``padded`` rows (and
     an [S] batch dim when ``batch``). ``sparse_groups`` scales a sparse
@@ -113,7 +114,8 @@ def compile_program(one_chip, ssb, sql, padded, *, batch=0, fused="",
     high-cardinality segment (cut at numGroupsLimit's default, or under
     ``whole_table`` with a slot for every key, as the planner sizes a table
     it sorts by its own rule); ``dict_len`` sets the dictionary planes'
-    length (a family's `executor._dict_pad` bucket)."""
+    length (a family's `executor._dict_pad` bucket); ``dense_groups``
+    re-sizes a dense program's table."""
     segment, view = ssb
     plan = SegmentPlanner(parse_sql(sql), segment).plan()
     arrays, packed = plan.gather_arrays_packed(view)
@@ -130,6 +132,9 @@ def compile_program(one_chip, ssb, sql, padded, *, batch=0, fused="",
             program, key_space=sparse_groups,
             num_groups=sparse_groups if whole_table
             else min(sparse_groups, 100_000))
+    if dense_groups:
+        assert program.mode == "group_by"
+        program = dataclasses.replace(program, num_groups=dense_groups)
     lead = [batch] if batch else []
 
     def plane(a, kind):
