@@ -407,13 +407,17 @@ class TpuSegmentExecutor:
             _count_dispatch(new_compile)
         if span is not None:
             # mode, label, row bucket, how the planes the program is fed
-            # decode their dictionaries (kernels.dict_lookups) and how a
+            # decode their dictionaries (kernels.dict_lookups), how a
             # dense table takes its MINs and MAXs (kernels.min_max_forms)
+            # and the slots of a member's group table (0: it has none)
             span.set_attribute("mode", program.mode)
             span.set_attribute("program", program_label(program))
             span.set_attribute("padded", padded)
             span.set_attribute("dictLookups", dict_lookups(program, arrays))
             span.set_attribute("minMax", min_max_forms(program))
+            span.set_attribute(
+                "groupSlots", program.num_groups
+                if program.mode in ("group_by", "group_by_sparse") else 0)
         t0 = time.perf_counter()
         outs = run()
         if count_after:

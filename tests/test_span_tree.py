@@ -7,7 +7,9 @@ clock, with stable device names.
 - under a profiler session the spans are TraceAnnotations on its host
   plane, with the spans' names, ids and clock;
 - the program label: equal for two literals of one SQL shape, different
-  for another shape, the name of the lowered module and of its ops' scopes.
+  for another shape, the name of the lowered module and of its ops' scopes;
+- every `family_dispatch` span says how many slots a member's group table has
+  (`groupSlots`; 0 for a program with none).
 """
 
 from __future__ import annotations
@@ -366,3 +368,36 @@ def test_ops_inside_a_loop_or_a_branch_are_filed_under_the_programs_scope(
     sorts = [n for n in names if n.endswith("/sort")]
     assert sorts and all(filed[n] == scope for n in sorts), sorts
     assert any(inside in n for n in sorts)
+
+
+# -- the slots of a dispatch's group table --------------------------------------
+
+
+@pytest.mark.parametrize("sql,slots", [
+    pytest.param(Q11.format(y=3, d=1, d2=3, q=25), 0, id="ungrouped"),
+    pytest.param("SELECT spk, spv FROM sptab WHERE spy = 3 LIMIT 5", 0,
+                 id="selection"),
+    pytest.param(SQL, 16, id="one-key"),
+    pytest.param("SELECT spk, spy, spd, SUM(spv) FROM sptab WHERE spv < 90 "
+                 "GROUP BY spk, spy, spd LIMIT 2000", 16 * 7 * 11,
+                 id="dense-three-keys"),
+    pytest.param("SET sparseGroupBy = true; SELECT spk, SUM(spv) FROM sptab "
+                 "WHERE spy < 5 GROUP BY spk LIMIT 50", None, id="sorted"),
+])
+def test_dispatch_span_carries_the_slots_of_the_group_table(engine, sql,
+                                                            slots):
+    # `groupSlots` (PR 34): what `group_slots_per_query` reads. A dense
+    # table has the product of its keys' cardinalities, from the plan's
+    # static shape; a sort-based one at most its numGroupsLimit
+    resp = engine.execute_sql("SET trace = true; " + NOCACHE + sql)
+    assert not resp.exceptions, resp.exceptions
+    spans = [s["attributes"] for s in resp.trace_info
+             if s["operator"] == "family_dispatch"]
+    assert spans and resp.num_device_dispatches == len(spans)
+    for attrs in spans:
+        assert isinstance(attrs["groupSlots"], int)
+        if slots is None:
+            assert attrs["mode"] == "group_by_sparse"
+            assert attrs["groupSlots"] > 0
+        else:
+            assert attrs["groupSlots"] == slots
