@@ -21,8 +21,8 @@ import numpy as np
 import jax.numpy as jnp
 
 from ..ops.kernels import (PackedOuts, dict_lookups, fetch_tally,
-                           min_max_forms, pack_outputs, run_program,
-                           unpack_outputs)
+                           group_table_form, min_max_forms, pack_outputs,
+                           run_program, unpack_outputs)
 from .aot_cache import AOT_READY, aot_call
 from ..query.context import QueryContext
 from ..segment.device_cache import (
@@ -408,8 +408,9 @@ class TpuSegmentExecutor:
         if span is not None:
             # mode, label, row bucket, how the planes the program is fed
             # decode their dictionaries (kernels.dict_lookups), how a
-            # dense table takes its MINs and MAXs (kernels.min_max_forms)
-            # and the slots of a member's group table (0: it has none)
+            # dense table takes its MINs and MAXs (kernels.min_max_forms),
+            # the slots of a member's group table (0: it has none) and
+            # which table that is (kernels.group_table_form)
             span.set_attribute("mode", program.mode)
             span.set_attribute("program", program_label(program))
             span.set_attribute("padded", padded)
@@ -418,6 +419,7 @@ class TpuSegmentExecutor:
             span.set_attribute(
                 "groupSlots", program.num_groups
                 if program.mode in ("group_by", "group_by_sparse") else 0)
+            span.set_attribute("groupTable", group_table_form(program))
         t0 = time.perf_counter()
         outs = run()
         if count_after:

@@ -63,6 +63,16 @@ def table_bucket(card: int) -> int:
     return -(-int(card) // step) * step
 
 
+def row_major_strides(cards) -> list:
+    """Strides of the composite group key Σ id_i · stride_i over keys of
+    `cards` entries, the last key fastest (reference
+    DictionaryBasedGroupKeyGenerator:119-137)."""
+    strides = [1] * len(cards)
+    for i in range(len(cards) - 2, -1, -1):
+        strides[i] = strides[i + 1] * cards[i + 1]
+    return strides
+
+
 def _vexpr_uses_slots(ve, slots: set) -> bool:
     """True when a value expression reads any of the given array slots."""
     if ve is None:
@@ -784,40 +794,41 @@ class SegmentPlanner(AggPlanContext):
             return ir.FNot(node) if p.type == PredicateType.NOT_IN else node
         raise UnsupportedQueryError(f"predicate {p.type} on raw column not lowered")
 
-    def _sorted_table_rule(self, group_exprs, group_dims, num_groups,
-                           any_derived, mv_group_slot, lowered):
-        """(sorted, reason) for a group-by the dense table could hold. One
-        identifier key over an integer dictionary with every aggregation a
-        columnar count/sum/min/max — the shapes the server merges and cuts
-        on the device (query_executor._device_merge_takes) — gets a
-        sorted table from the first size the limb kernel leaves
-        (mxu_groupby.MAX_GROUPS slots): above it the dense table is filled
-        by one 32-bit scatter per limb over every row, whatever its size,
-        and the chip sweep (tools/groupby_crossover_sweep.py, PERF.md
-        section 6, PR 30) found the sort-based kernel ahead from there on at
-        every filter factor, so the crossover is no constant of its own.
-        Matrix ops, derived, MV, string and multiple keys stay dense: the
-        device merge takes none of them."""
+    def _sorted_table_rule(self, group_dims, num_groups, any_derived,
+                           mv_group_slot, lowered):
+        """(sorted, reason) for a group-by the dense table could hold. Every
+        test here is about the SCAN: identifier keys over single-value
+        dictionary columns (any number of them, any dictionary type: the
+        kernel sorts the composite id Σ id_i · stride_i) with every
+        aggregation a columnar count/sum/min/max get a sorted table from
+        the first size the limb kernel leaves (mxu_groupby.MAX_GROUPS
+        slots): above it the dense table is filled by one 32-bit scatter
+        per limb over every row, whatever its size, and the chip sweeps
+        (tools/groupby_crossover_sweep.py, PERF.md section 6, PR 30 for one
+        key and PR 35 for composites) found the sort-based kernel ahead
+        from there on at every filter factor, so the crossover is no
+        constant of its own. Matrix ops, derived and MV keys stay dense:
+        the sorted kernel refuses them. Which of the sorted tables the
+        server then merges on the device (one key, an integer dictionary)
+        is query_executor._device_merge_takes' own test; the others run
+        the per-segment stages, as the dense table did."""
         if mxu_groupby.supports(num_groups + 1, 1):
             return False, (f"{num_groups} keys fit the limb kernel's "
                            f"{mxu_groupby.MAX_GROUPS} slots")
-        if len(group_exprs) != 1:
-            return False, f"{len(group_exprs)} keys: only one key is sorted"
-        if any_derived or not group_exprs[0].is_identifier:
+        if any_derived:
             return False, "a derived key"
         if mv_group_slot is not None:
             return False, "a multi-value key"
-        values = getattr(group_dims[0].dictionary, "values", None)
-        if values is None or not np.issubdtype(
-                np.asarray(values).dtype, np.integer):
-            return False, "the key's dictionary is not of integers"
         for op in self.ops:
             if op.kind not in _SPARSE_AGG_KINDS:
                 return False, f"{op.kind} needs the dense table"
         if not self.ops or any(la.vec is None for la in lowered):
             return False, "an aggregation without a columnar state"
-        return True, (f"one integer key of {num_groups} entries, above the "
-                      f"limb kernel's {mxu_groupby.MAX_GROUPS} slots")
+        above = f"above the limb kernel's {mxu_groupby.MAX_GROUPS} slots"
+        if len(group_dims) == 1:
+            return True, f"one key of {num_groups} entries, {above}"
+        keys = " x ".join(f"{d.column}[{d.cardinality}]" for d in group_dims)
+        return True, f"{len(group_dims)} keys {keys} = {num_groups}, {above}"
 
     # -- top-level plan ----------------------------------------------------
     def plan(self) -> SegmentPlan:
@@ -870,10 +881,7 @@ class SegmentPlanner(AggPlanContext):
                 raise UnsupportedQueryError(
                     f"group cardinality product {num_groups} exceeds the "
                     "int64 composite-key space")
-            # row-major strides (reference DictionaryBasedGroupKeyGenerator:119-137)
-            strides = [1] * len(cards)
-            for i in range(len(cards) - 2, -1, -1):
-                strides[i] = strides[i + 1] * cards[i + 1]
+            strides = row_major_strides(cards)
 
             # lets approximate aggs size their occupancy matrices: e.g. the
             # tdigest family picks exact value-hist vs fixed-bin by whether
@@ -908,9 +916,10 @@ class SegmentPlanner(AggPlanContext):
                     dense_ok = False
                     dense_reason = f"{op.kind} occupancy {num_groups}x{width}"
             sparse = not dense_ok
-            # a table sorted BY THE RULE holds every key of the dictionary:
-            # nothing is trimmed in a segment, as in the dense table it
-            # replaces (numGroupsLimit keeps its meaning for the other two)
+            # a table sorted BY THE RULE holds every key of the dictionary
+            # (every combination of several keys): nothing is trimmed in a
+            # segment, as in the dense table it replaces (numGroupsLimit
+            # keeps its meaning for the other two)
             whole_table = False
             if not sparse and group_exprs and self.query.query_options.get(
                     "sparseGroupBy") in (True, "true", 1):
@@ -922,8 +931,8 @@ class SegmentPlanner(AggPlanContext):
                 dense_reason = "sparseGroupBy=true"
             elif not sparse and group_exprs:
                 whole_table, dense_reason = self._sorted_table_rule(
-                    group_exprs, group_dims, num_groups, any_derived,
-                    mv_group_slot, lowered)
+                    group_dims, num_groups, any_derived, mv_group_slot,
+                    lowered)
                 sparse = whole_table
             if sparse:
                 n_distinct = sum(1 for op in self.ops

@@ -949,7 +949,11 @@ class QueryExecutor:
         group-by with no star-tree rewrite, one identifier group key over
         an integer dictionary, aggregations the device merges
         (`_SPARSE_COMBINE_KINDS`), all vectorizable; `SET deviceCombine =
-        false` opts a query out."""
+        false` opts a query out. The tests for ONE key and an INTEGER
+        dictionary are the merge's own (segment-local ids become values on
+        the device): `plan._sorted_table_rule` sorts tables of several
+        keys and of string keys too, and those run the per-segment stages
+        (fetch, decode, `combine_group_arrays`) as a dense table does."""
         import numpy as np
 
         if len(tasks) < 2 or host_tasks or query.query_options.get(

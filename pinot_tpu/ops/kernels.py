@@ -118,6 +118,21 @@ def min_max_forms(program: ir.Program) -> str:
     return f"reduce:{forms.count('reduce')},scatter:{forms.count('scatter')}"
 
 
+def group_table_form(program: ir.Program) -> str:
+    """Which group table a program fills, from its static fields: `limb`
+    (a dense table inside `mxu_groupby.MAX_GROUPS`, the planner's own test:
+    its COUNT and 32-bit SUMs ride the limb kernel), `dense` (a larger one:
+    a scatter per limb), `sorted` / `presorted` (the sort-based kernel,
+    rows sorted by key or read in the segment's own order), `none` (no
+    group-by)."""
+    if program.mode == "group_by_sparse":
+        return "presorted" if program.keys_presorted else "sorted"
+    if program.mode != "group_by":
+        return "none"
+    return "limb" if mxu_groupby.supports(program.num_groups + 1, 1) \
+        else "dense"
+
+
 def _eval_value(node: ir.ValueExpr, arrays, params):
     if isinstance(node, ir.Col):
         return arrays[node.slot]

@@ -1,14 +1,17 @@
-"""Chip sweep behind `engine/plan.SegmentPlanner._sorted_table_rule`: one integer key's
-group-by over a batch family of segments of 4,194,304 rows, its group table
+"""Chip sweep behind `engine/plan.SegmentPlanner._sorted_table_rule`: a
+group-by by one dictionary key, or by a composite of `--key-columns` of
+them, over a batch family of segments of 4,194,304 rows, its group table
 filled by the dense path (above `mxu_groupby.MAX_GROUPS` slots: one 32-bit
 scatter per limb) and by the sort-based kernel, at several key counts and
 filter factors.
 
     python -m pinot_tpu.tools.groupby_crossover_sweep [--keys 32768,...]
-        [--factors 0.01,0.1,0.25,1.0] [--segs 4] [--out f]
+        [--key-columns 1] [--factors 0.01,0.1,0.25,1.0] [--segs 4] [--out f]
 
-One line of JSON a case: keys, filter factor, form (`dense` or `sorted`),
-table slots, compile seconds, milliseconds a SEGMENT (median of `--reps`
+One line of JSON a case: keys (with several key columns: the product of
+their cardinalities, `cards`), filter factor, form (`dense` or `sorted`),
+table slots, the first call's seconds (a form's first factor compiles; the
+others run the same executable), milliseconds a SEGMENT (median of `--reps`
 dispatches over the family, host clock around `block_until_ready`, divided
 by the segments), and whether the sorted table's sums equal the dense
 one's. Fails without a TPU unless `--rehearse` (toy rows, any backend).
@@ -30,7 +33,8 @@ import time
 import jax
 import numpy as np
 
-from ..engine.plan import SegmentPlanner, _key_space_bucket, table_bucket
+from ..engine.plan import (SegmentPlanner, _key_space_bucket,
+                           row_major_strides, table_bucket)
 from ..ops import kernels
 from ..query.parser.sql import parse_sql
 from ..segment.builder import SegmentBuilder
@@ -38,42 +42,73 @@ from ..segment.loader import load_segment
 from ..spi.data_types import Schema
 from ..spi.table_config import IndexingConfig, TableConfig
 
-SQL = ("SELECT k, SUM(v) FROM t WHERE f BETWEEN 0 AND 9 GROUP BY k "
-       "LIMIT 10")
 F_RANGE = 1000  # `f` is uniform over [0, F_RANGE): a factor is a range of it
+TOY_CARD = 8  # a toy key column's entries: three of them stay a limb table
 
 
-def plans(scratch: str):
-    """(dense plan, sorted plan) of SQL against a toy segment: a dictionary
-    key, a raw filter column and a raw int32 metric, as the drill-down's
-    top-N reads them."""
+def plans(scratch: str, key_columns: int = 1):
+    """(dense plan, sorted plan) of a filtered SUM grouped by `key_columns`
+    dictionary keys against a toy segment: the keys, a raw filter column
+    and a raw int32 metric, as the drill-down's top-N and flight 3's
+    city-to-city tables read them."""
     rng = np.random.default_rng(7)
     n = 1 << 13
-    schema = Schema.build("t", dimensions=[("k", "INT"), ("f", "INT")],
-                          metrics=[("v", "INT")])
+    keys = [f"k{i}" for i in range(key_columns)]
+    schema = Schema.build(
+        "t", dimensions=[(k, "INT") for k in keys] + [("f", "INT")],
+        metrics=[("v", "INT")])
     cfg = TableConfig(table_name="t", indexing=IndexingConfig(
         no_dictionary_columns=["f", "v"]))
-    cols = {"k": rng.integers(0, 4096, n).astype(np.int32),
-            "f": rng.integers(0, F_RANGE, n).astype(np.int32),
-            "v": rng.integers(1, 10_000_000, n).astype(np.int32)}
+    cols = {k: rng.integers(0, TOY_CARD, n).astype(np.int32) for k in keys}
+    cols["f"] = rng.integers(0, F_RANGE, n).astype(np.int32)
+    cols["v"] = rng.integers(1, 10_000_000, n).astype(np.int32)
     SegmentBuilder(schema, cfg, "s0").build(cols, scratch + "/s")
     seg = load_segment(scratch + "/s")
-    dense = SegmentPlanner(parse_sql(SQL), seg).plan()
+    by = ", ".join(keys)
+    sql = (f"SELECT {by}, SUM(v) FROM t WHERE f BETWEEN 0 AND 9 "
+           f"GROUP BY {by} LIMIT 10")
+    dense = SegmentPlanner(parse_sql(sql), seg).plan()
     sort = SegmentPlanner(
-        parse_sql("SET sparseGroupBy = true; " + SQL), seg).plan()
+        parse_sql("SET sparseGroupBy = true; " + sql), seg).plan()
     assert dense.program.mode == "group_by"
     assert sort.program.mode == "group_by_sparse"
     assert dense.slots == sort.slots
     return dense, sort
 
 
-def family_inputs(plan, segs: int, rows: int, keys: int, factor: float, rng):
-    """Stacked int32 planes of a `segs` x `rows` family, every key of
-    [0, keys) drawn uniformly, and the filter's bounds for `factor`."""
+def key_cards(keys: int, key_columns: int) -> list:
+    """`key_columns` cardinalities whose product is `keys`, as near to one
+    another as its divisors allow (458,752 over three: 64 x 64 x 112)."""
+    cards, left = [], keys
+    for i in range(key_columns, 1, -1):
+        root = round(left ** (1 / i))
+        card = next(c for c in range(root, 0, -1) if left % c == 0)
+        cards.append(card)
+        left //= card
+    return cards + [left]
+
+
+def sized(program, cards: list, slots: int):
+    """`program` re-sized to key columns of `cards` entries and a table of
+    `slots` slots."""
+    size = dict(group_strides=tuple(row_major_strides(cards)),
+                num_groups=slots)
+    if program.mode == "group_by_sparse":
+        size["key_space"] = _key_space_bucket(slots)
+    return dataclasses.replace(program, **size)
+
+
+def family_inputs(plan, segs: int, rows: int, cards: list, factor: float,
+                  rng):
+    """Stacked int32 planes of a `segs` x `rows` family, every entry of
+    every key column drawn uniformly, and the filter's bounds for
+    `factor`."""
+    his = {f"k{i}": card for i, card in enumerate(cards)}
+    his.update(f=F_RANGE, v=10_000_000)
     arrays = []
     for column, _kind in plan.slots:
-        hi = {"k": keys, "f": F_RANGE, "v": 10_000_000}[column]
-        arrays.append(rng.integers(0, hi, (segs, rows), dtype=np.int32))
+        arrays.append(rng.integers(0, his[column], (segs, rows),
+                                   dtype=np.int32))
     params = []
     for p in plan.params:
         p = np.asarray(p)
@@ -83,24 +118,32 @@ def family_inputs(plan, segs: int, rows: int, keys: int, factor: float, rng):
     return tuple(jax.device_put(a) for a in arrays), tuple(params)
 
 
-def time_case(program, arrays, params, rows: int, reps: int):
+def scan_of(program, rows: int):
+    """The family's scan as one jitted function: compiled at its first
+    call, the same executable at every filter factor (the bounds are
+    params)."""
+    return jax.jit(lambda a, p, nd: kernels._run_program_batch(
+        program, a, p, nd, rows, ()))
+
+
+def time_case(scan, arrays, params, rows: int, reps: int):
+    """(first call's seconds, median ms a segment, outputs): the first call
+    compiles where `scan` has not run yet."""
     segs = arrays[0].shape[0]
     num_docs = np.full((segs,), rows, dtype=np.int32)
-    scan = jax.jit(lambda a, p, nd: kernels._run_program_batch(
-        program, a, p, nd, rows, ()))
 
     def call():
         return jax.block_until_ready(scan(arrays, params, num_docs))
 
     t0 = time.perf_counter()
     outs = call()
-    compile_s = time.perf_counter() - t0
+    first_s = time.perf_counter() - t0
     ms = []
     for _ in range(reps):
         t0 = time.perf_counter()
         call()
         ms.append((time.perf_counter() - t0) * 1000 / segs)
-    return compile_s, statistics.median(ms), outs
+    return first_s, statistics.median(ms), outs
 
 
 def sums_by_key(program, outs, keys: int) -> np.ndarray:
@@ -120,6 +163,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--keys", default=",".join(
         str(1 << b) for b in (15, 16, 17, 19, 20, 21)))
+    ap.add_argument("--key-columns", type=int, default=1)
     ap.add_argument("--factors", default="0.01,0.1,0.25,1.0")
     ap.add_argument("--segs", type=int, default=4)
     ap.add_argument("--reps", type=int, default=3)
@@ -134,30 +178,30 @@ def main(argv=None) -> int:
     factors = [float(x) for x in args.factors.split(",")]
     with tempfile.TemporaryDirectory(prefix="gby_sweep_") as scratch, \
             open(args.out or os.devnull, "a") as sink:
-        dense, sort = plans(scratch)
+        dense, sort = plans(scratch, args.key_columns)
         for keys in (int(x) for x in args.keys.split(",")):
-            slots = table_bucket(keys)
-            forms = [
-                ("dense", dataclasses.replace(dense.program,
-                                              num_groups=keys)),
-                ("sorted", dataclasses.replace(
-                    sort.program, num_groups=slots,
-                    key_space=_key_space_bucket(slots)))]
+            cards = key_cards(keys, args.key_columns)
+            forms = [(form, program, scan_of(program, rows))
+                     for form, program in (
+                         ("dense", sized(dense.program, cards, keys)),
+                         ("sorted", sized(sort.program, cards,
+                                          table_bucket(keys))))]
             for factor in factors:
                 arrays, params = family_inputs(
-                    dense, segs, rows, keys, factor,
+                    dense, segs, rows, cards, factor,
                     np.random.default_rng(keys))
                 want = None
-                for form, program in forms:
-                    compile_s, ms, outs = time_case(
-                        program, arrays, params, rows, args.reps)
+                for form, program, scan in forms:
+                    first_s, ms, outs = time_case(
+                        scan, arrays, params, rows, args.reps)
                     got = sums_by_key(program, outs, keys)
                     want = got if want is None else want
                     line = json.dumps({
                         "device": dev.device_kind, "rows": [segs, rows],
-                        "keys": keys, "factor": factor, "form": form,
+                        "keys": keys, "cards": cards, "factor": factor,
+                        "form": form,
                         "slots": program.num_groups,
-                        "compile_s": round(compile_s, 3),
+                        "first_call_s": round(first_s, 3),
                         "ms_a_segment": round(ms, 3),
                         "equal": bool(np.array_equal(got, want))})
                     print(line, flush=True)

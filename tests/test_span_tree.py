@@ -25,6 +25,7 @@ import pytest
 from pinot_tpu.cluster import (Broker, ClusterController, PropertyStore,
                                ServerInstance)
 from pinot_tpu.engine.ir import program_label
+from pinot_tpu.engine.plan import table_bucket
 from pinot_tpu.engine.query_executor import QueryExecutor, parse_sql
 from pinot_tpu.query.optimizer import optimize_filter
 from pinot_tpu.segment.builder import SegmentBuilder
@@ -290,6 +291,54 @@ def _benchmark_scope_of():
     return module.scope_of
 
 
+# three keys whose product, 40 x 40 x 30 = 48,000, lies above the limb
+# kernel's slots: the planner sorts the table by its own rule (PR 35), as
+# it does `ssb16.flight3city`'s `c_city x s_city x d_year`
+ST3 = Schema.build("sp3", dimensions=[("a", "STRING"), ("b", "STRING"),
+                                      ("c", "INT"), ("e", "INT")],
+                   metrics=[("spv", "INT")])
+SQL3 = ("SELECT a, b, c, SUM(spv) FROM sp3 WHERE a IN ('a3', 'a17') AND "
+        "b IN ('b5', 'b21') AND e < 6 GROUP BY a, b, c LIMIT 50")
+
+
+@pytest.fixture(scope="module")
+def engine3():
+    d = Path(tempfile.mkdtemp(prefix="sptree_3k_"))
+    rng = np.random.default_rng(11)
+    segs = []
+    for i in range(4):
+        n = 2400
+        k = np.arange(n)
+        cols = {"a": np.char.add("a", (k % 40).astype(str)).astype(object),
+                "b": np.char.add("b", (k // 40 % 40).astype(str)).astype(
+                    object),
+                "c": (k % 30).astype(np.int32),
+                "e": (k % 8).astype(np.int32),
+                "spv": rng.integers(0, 100, n).astype(np.int32)}
+        SegmentBuilder(ST3, segment_name=f"sp3_{i}").build(cols, d / f"sp3_{i}")
+        segs.append(load_segment(d / f"sp3_{i}"))
+    qe = QueryExecutor(backend="tpu")
+    qe.add_table(ST3, segs)
+    return qe
+
+
+def _three_key_family(engine3):
+    from pinot_tpu.ops import kernels
+
+    segs = list(engine3.tables["sp3"].segments)
+    query = parse_sql(SQL3)
+    query.filter = optimize_filter(query.filter)
+    plans = [engine3.tpu.plan(query, seg) for seg in segs]
+    program = plans[0].program
+    assert program.mode == "group_by_sparse"  # no SET: the rule's own
+    assert kernels.group_table_form(program) == "sorted"
+    assert "lut0_lut1" in program_label(program)
+    views, arrays, params, packed, num_docs = engine3.tpu._gather_batch(
+        segs, plans)
+    return kernels.run_program_batch.lower(
+        program, arrays, params, num_docs, views[0].padded, packed=packed)
+
+
 def _sorted_family(engine):
     from pinot_tpu.ops import kernels
 
@@ -347,14 +396,17 @@ def test_dense_min_max_reductions_are_filed_under_group_by_dense(engine):
         reductions
 
 
-@pytest.mark.parametrize("lower,scope,inside", [
-    pytest.param(_sorted_family, "group_by_sparse", "while/body",
+@pytest.mark.parametrize("lower,table,scope,inside", [
+    pytest.param(_sorted_family, "engine", "group_by_sparse", "while/body",
                  id="the-sorted-family-under-lax-map"),
-    pytest.param(_cut_merge, "combine", "cond/branch",
+    pytest.param(_cut_merge, "engine", "combine", "cond/branch",
                  id="the-merge-under-its-branch"),
+    pytest.param(_three_key_family, "engine3", "group_by_sparse",
+                 "while/body", id="the-three-key-family-under-lax-map"),
 ])
 def test_ops_inside_a_loop_or_a_branch_are_filed_under_the_programs_scope(
-        engine, lower, scope, inside):
+        request, lower, table, scope, inside):
+    engine = request.getfixturevalue(table)
     # a trace files an op under the FIRST part of its name after the
     # module's: a scope opened inside `lax.map` or `lax.cond` would read
     # `while` or `cond`, and `kernel_groupby_ms` would lose the scan
@@ -368,27 +420,46 @@ def test_ops_inside_a_loop_or_a_branch_are_filed_under_the_programs_scope(
     sorts = [n for n in names if n.endswith("/sort")]
     assert sorts and all(filed[n] == scope for n in sorts), sorts
     assert any(inside in n for n in sorts)
+    if lower is _three_key_family:
+        # its two LUT filters are gathers batched BEFORE the loop, under
+        # `filter` (`kernel_filter_ms` reads them), and the composite key's
+        # multiply-adds lie inside it, under the group-by's scope
+        gathers = [n for n in names if n.endswith("/gather")]
+        assert gathers and all(filed[n] == "filter" and "while" not in n
+                               for n in gathers), gathers
+        muls = [n for n in names if n.endswith("/mul")]
+        assert muls and all(filed[n] == scope for n in muls), muls
 
 
 # -- the slots of a dispatch's group table --------------------------------------
 
 
-@pytest.mark.parametrize("sql,slots", [
-    pytest.param(Q11.format(y=3, d=1, d2=3, q=25), 0, id="ungrouped"),
+@pytest.mark.parametrize("sql,slots,table", [
+    pytest.param(Q11.format(y=3, d=1, d2=3, q=25), 0, "none", id="ungrouped"),
     pytest.param("SELECT spk, spv FROM sptab WHERE spy = 3 LIMIT 5", 0,
-                 id="selection"),
-    pytest.param(SQL, 16, id="one-key"),
+                 "none", id="selection"),
+    pytest.param(SQL, 16, "limb", id="one-key"),
     pytest.param("SELECT spk, spy, spd, SUM(spv) FROM sptab WHERE spv < 90 "
-                 "GROUP BY spk, spy, spd LIMIT 2000", 16 * 7 * 11,
+                 "GROUP BY spk, spy, spd LIMIT 2000", 16 * 7 * 11, "limb",
                  id="dense-three-keys"),
     pytest.param("SET sparseGroupBy = true; SELECT spk, SUM(spv) FROM sptab "
-                 "WHERE spy < 5 GROUP BY spk LIMIT 50", None, id="sorted"),
+                 "WHERE spy < 5 GROUP BY spk LIMIT 50", None, "sorted",
+                 id="sorted"),
+    pytest.param(SQL3, table_bucket(40 * 40 * 30), "sorted",
+                 id="three-keys-sorted-by-the-rule"),
+    pytest.param("SELECT a, b, c, DISTINCTCOUNT(e) FROM sp3 WHERE e < 6 "
+                 "GROUP BY a, b, c LIMIT 50", 40 * 40 * 30, "dense",
+                 id="three-keys-a-bitmap-stays-dense"),
 ])
-def test_dispatch_span_carries_the_slots_of_the_group_table(engine, sql,
-                                                            slots):
+def test_dispatch_span_carries_the_slots_of_the_group_table(request, sql,
+                                                            slots, table):
     # `groupSlots` (PR 34): what `group_slots_per_query` reads. A dense
     # table has the product of its keys' cardinalities, from the plan's
-    # static shape; a sort-based one at most its numGroupsLimit
+    # static shape; a sort-based one at most its numGroupsLimit, and by the
+    # planner's rule a slot for every key. `groupTable` (PR 35) says which
+    # table it is (`kernels.group_table_form`); no metric reads it
+    engine = request.getfixturevalue(
+        "engine3" if " sp3 " in sql else "engine")
     resp = engine.execute_sql("SET trace = true; " + NOCACHE + sql)
     assert not resp.exceptions, resp.exceptions
     spans = [s["attributes"] for s in resp.trace_info
@@ -396,8 +467,20 @@ def test_dispatch_span_carries_the_slots_of_the_group_table(engine, sql,
     assert spans and resp.num_device_dispatches == len(spans)
     for attrs in spans:
         assert isinstance(attrs["groupSlots"], int)
+        assert attrs["groupTable"] == table
         if slots is None:
             assert attrs["mode"] == "group_by_sparse"
             assert attrs["groupSlots"] > 0
         else:
             assert attrs["groupSlots"] == slots
+
+
+def test_group_table_form_names_a_presorted_table(engine):
+    import dataclasses
+
+    from pinot_tpu.ops import kernels
+
+    _, plan = _plan(engine, "SET sparseGroupBy = true; " + SQL)
+    assert kernels.group_table_form(plan.program) == "sorted"
+    assert kernels.group_table_form(dataclasses.replace(
+        plan.program, keys_presorted=True)) == "presorted"

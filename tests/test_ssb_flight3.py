@@ -27,6 +27,8 @@ from __future__ import annotations
 import numpy as np
 import pytest
 
+from pinot_tpu.engine.plan import table_bucket
+
 # the drill-down test's cluster (one server, backend tpu, behind one broker,
 # as benchmark/run.py sets it up) and its loader of the benchmark's modules
 from test_drilldown_reference import BENCH, NOCACHE, Cluster, _module, \
@@ -41,8 +43,13 @@ CLASSES = ["ssb_q3_1", "ssb_q3_2", "ssb_q3_3", "ssb_q3_4"]
 # the cell's mix with all four classes, for their literals
 MIX = dict(traffic.load("traffic", "flight3city-stream1"), deck=4,
            classes=[{"class": c, "share": 1} for c in CLASSES])
-SLOTS = {"ssb_q3_1": 25 * 25 * 7, "ssb_q3_2": 250 * 250 * 7,
-         "ssb_q3_3": 250 * 250 * 7, "ssb_q3_4": 250 * 250 * 7}
+# a dense table has the product of its three keys' cardinalities (Q3.1's
+# fits the limb kernel); above the limb kernel's slots the planner sorts
+# the table (PR 35): a slot for every combination, `table_bucket`'s rounding
+SLOTS = {"ssb_q3_1": 25 * 25 * 7,
+         "ssb_q3_2": table_bucket(250 * 250 * 7),
+         "ssb_q3_3": table_bucket(250 * 250 * 7),
+         "ssb_q3_4": table_bucket(250 * 250 * 7)}
 PLANTED = 48  # lines a segment for each planted set of literals
 
 
@@ -144,11 +151,13 @@ def test_served_path_equals_the_reference_and_the_host_engine(
     host = deployment.host.execute_sql(NOCACHE + sql)
     assert not host.exceptions, host.exceptions
     assert [tuple(r) for r in host.result_table.rows] == want
-    # the normal path: one batch dispatch over the four segments, into a
-    # table of the product of its three keys' cardinalities
+    # the normal path: one batch dispatch over the four segments, into the
+    # limb kernel's table (Q3.1) or the sorted table of the three keys
     assert resp.num_device_dispatches == 1 and len(spans) == 1
     assert spans[0]["numSegments"] == SEGMENTS
-    assert spans[0]["mode"] == "group_by"
+    assert (spans[0]["mode"], spans[0]["groupTable"]) == (
+        ("group_by", "limb") if cls == "ssb_q3_1"
+        else ("group_by_sparse", "sorted"))
     assert spans[0]["groupSlots"] == SLOTS[cls]
 
 
