@@ -29,6 +29,7 @@ from ..segment.device_cache import (
     GLOBAL_DEVICE_CACHE,
     DeviceSegmentCache,
     clear_transfer_stats,
+    note_dict_pad,
     reset_transfer_stats,
     transfer_stats,
 )
@@ -184,6 +185,7 @@ def _attach_dispatch_stats(span, cache: DeviceSegmentCache) -> None:
             span.set_attribute("transfers", dict(stats["transfers"]))
         span.set_attribute("stackHits", stats["stackHits"])
         span.set_attribute("stackMisses", stats["stackMisses"])
+        span.set_attribute("dictPads", stats["dictPads"])
     span.attributes.update(cache.hbm_stats())
     clear_transfer_stats()
 
@@ -235,6 +237,26 @@ def _dict_pad(card: int) -> int:
     while b < card:
         b <<= 1
     return b
+
+
+def _build_stack(col: tuple, shape: tuple, pad: int, ndev: int):
+    """One slot's [S, ...] stack, built on a stacked-view miss only: each
+    member zero-padded to `shape` (a dictionary plane's family bucket),
+    the last member repeated `pad` times (a ragged mesh family), the stack
+    sharded over the segment axis when ndev > 1."""
+    members = []
+    for a in col:
+        if a.shape != shape:
+            a = jnp.pad(a, [(0, t - n) for t, n in zip(shape, a.shape)])
+            note_dict_pad()
+        members.append(a)
+    stack = jnp.stack(members + [members[-1]] * pad)
+    if ndev > 1:
+        from ..parallel import mesh as pmesh
+
+        stack = jax.device_put(stack,
+                               pmesh.segment_sharding(ndev, stack.ndim))
+    return stack
 
 
 def batch_family_key(segment: ImmutableSegment, plan: SegmentPlan,
@@ -565,37 +587,26 @@ class TpuSegmentExecutor:
         stacked = []
         for i in range(nslots):
             col = [g[0][i] for g in gathered]
+            shapes = [a.shape for a in col]
             if plans[0].slots[i][1] == "dict":
-                # dictionary sizes are segment-local: zero-pad every
-                # member's values plane to the family's shared power-of-two
-                # bucket (see _dict_pad — pads are never gathered)
-                target = _dict_pad(max(a.shape[0] for a in col))
-                col = [a if a.shape[0] == target
-                       else jnp.pad(a, (0, target - a.shape[0]))
-                       for a in col]
-            a0 = col[0]
-            if any(a.shape != a0.shape or a.dtype != a0.dtype
-                   for a in col[1:]):
+                # dictionary sizes are segment-local: every member's values
+                # plane is zero-padded to the family's shared power-of-two
+                # bucket (see _dict_pad — pads are never gathered). The
+                # shapes come from metadata; the pads run in _build_stack,
+                # only when the stack is not cached
+                target = _dict_pad(max(s[0] for s in shapes))
+                shapes = [(target,) + s[1:] for s in shapes]
+            s0, d0 = shapes[0], col[0].dtype
+            if any(s != s0 or a.dtype != d0
+                   for s, a in zip(shapes[1:], col[1:])):
                 raise BatchFamilyMismatch(
                     f"slot {i} ({plans[0].slots[i]}): unequal plane "
                     f"shapes/dtypes across family members")
-            if pad:
-                col = col + [col[-1]] * pad
-            pkey = (plans[0].slots[i], str(a0.dtype), tuple(a0.shape))
+            pkey = (plans[0].slots[i], str(d0), tuple(s0))
             if ndev > 1:
-                from ..parallel import mesh as pmesh
-
                 pkey = pkey + (("mesh", ndev),)
-
-                def build(c=tuple(col), nd=ndev):
-                    stack = jnp.stack(c)
-                    return jax.device_put(
-                        stack, pmesh.segment_sharding(nd, stack.ndim))
-
-                stacked.append(sview.plane(pkey, build))
-            else:
-                stacked.append(sview.plane(pkey, lambda c=tuple(col):
-                                           jnp.stack(c)))
+            stacked.append(sview.plane(pkey, lambda c=tuple(col), s=s0:
+                                       _build_stack(c, s, pad, ndev)))
         nparams = len(plans[0].params)
         if any(len(pl.params) != nparams for pl in plans):
             raise BatchFamilyMismatch("param-count mismatch")

@@ -35,7 +35,7 @@ _TRANSFER_TL = threading.local()
 def reset_transfer_stats() -> None:
     """Arm per-thread transfer counters (tracing on)."""
     _TRANSFER_TL.stats = {"transferBytes": 0, "transfers": {},
-                          "stackHits": 0, "stackMisses": 0}
+                          "stackHits": 0, "stackMisses": 0, "dictPads": 0}
 
 
 def clear_transfer_stats() -> None:
@@ -45,7 +45,7 @@ def clear_transfer_stats() -> None:
 def transfer_stats() -> Optional[dict]:
     """Counters since the last reset on this thread, or None when off:
     host→device bytes total + per-(column, plane-kind) slot, stacked-view
-    plane cache hits/misses."""
+    plane cache hits/misses, eager dictionary-plane pads launched."""
     return getattr(_TRANSFER_TL, "stats", None)
 
 
@@ -61,6 +61,13 @@ def _note_stack(hit: bool) -> None:
     stats = getattr(_TRANSFER_TL, "stats", None)
     if stats is not None:
         stats["stackHits" if hit else "stackMisses"] += 1
+
+
+def note_dict_pad() -> None:
+    """One member's dictionary plane zero-padded for a family stack."""
+    stats = getattr(_TRANSFER_TL, "stats", None)
+    if stats is not None:
+        stats["dictPads"] += 1
 
 
 def packed_hbm_enabled() -> bool:

@@ -15,8 +15,9 @@ import jax
 import numpy as np
 import pytest
 
+from pinot_tpu.engine import executor
 from pinot_tpu.engine.executor import _dict_pad
-from pinot_tpu.engine.query_executor import QueryExecutor
+from pinot_tpu.engine.query_executor import QueryExecutor, parse_sql
 from pinot_tpu.ops import kernels
 from pinot_tpu.segment.builder import SegmentBuilder
 from pinot_tpu.segment.loader import load_segment
@@ -52,7 +53,8 @@ def _family_cards(card: int, members: int) -> list:
     return [card, bucket // 2 + 1, bucket]
 
 
-def _engines(tmp_path, dtype: str, card: int, members: int):
+def _engines(tmp_path, dtype: str, card: int, members: int,
+             cards: list = None):
     table = f"dl_{dtype.lower()}_{card}_{members}"
     schema = Schema.build(table, dimensions=[("d", dtype)],
                           metrics=[("r", "INT")])
@@ -60,7 +62,7 @@ def _engines(tmp_path, dtype: str, card: int, members: int):
         no_dictionary_columns=["r"]))
     rng = np.random.default_rng(card * 10 + members)
     segs = []
-    cards = _family_cards(card, members)
+    cards = cards or _family_cards(card, members)
     universe = _values(dtype, max(cards))
     for i, c in enumerate(cards):
         # members share the least and the greatest value: the planner puts
@@ -113,6 +115,127 @@ def test_dictionary_values_equal_the_host_engine(tmp_path, dtype, card,
         assert spans[0]["dictLookups"] == (
             f"select:{nodes},gather:0" if plane <= L
             else f"select:0,gather:{nodes}")
+
+
+# -- the family's pads: metadata checks, pads only on a stack miss -----------
+
+# five members, every dictionary below the family's bucket of 16: five is
+# ragged on a mesh of four, so the mesh stack repeats its last member
+PAD_CARDS = [13, 9, 11, 10, 12]
+ONE_DEVICE = "SET meshExecution = false; "
+
+
+class _CountingPad:
+    """Counts the eager `jnp.pad` calls the executor makes."""
+
+    def __init__(self, monkeypatch):
+        self.calls = 0
+        real = executor.jnp.pad
+
+        def counting(*a, **kw):
+            self.calls += 1
+            return real(*a, **kw)
+
+        monkeypatch.setattr(executor.jnp, "pad", counting)
+
+
+def _mesh_path(monkeypatch, path: str) -> str:
+    """The options that send a family down `path`: one device, or the
+    mesh's sharded dispatch over 4 of the 8 virtual devices."""
+    if path == "mesh":
+        monkeypatch.setenv("PINOT_TPU_MESH_DEVICES", "4")
+        return ""
+    return ONE_DEVICE
+
+
+def _dict_slots(device, table: str, sql: str) -> int:
+    seg = device.tables[table].segments[0]
+    return sum(kind == "dict"
+               for _col, kind in device.tpu.plan(parse_sql(sql), seg).slots)
+
+
+@pytest.mark.parametrize("path", ["one-device", "mesh"])
+def test_warm_family_launches_no_pad(tmp_path, monkeypatch, path):
+    """The first dispatch of a family pads every member's dictionary plane
+    while it builds the stack; the warm one finds the stack cached and
+    launches no pad. The rows are the per-segment path's, bit for bit."""
+    table, device, host = _engines(tmp_path, "LONG", 13, len(PAD_CARDS),
+                                   cards=PAD_CARDS)
+    opts = _mesh_path(monkeypatch, path)
+    sql = AGGS.format(t=table)
+    slots = _dict_slots(device, table, sql)
+    assert slots == 1
+    pads = _CountingPad(monkeypatch)
+    spans, rows = [], []
+    for _ in range(2):
+        before = pads.calls
+        got = device.execute_sql("SET trace = true; " + NOCACHE + opts + sql)
+        rows.append(_rows(got))
+        (span,) = _dispatch_spans(got)
+        spans.append((span, pads.calls - before))
+        assert span["numSegments"] == len(PAD_CARDS)
+        assert span.get("meshDevices") == (4 if path == "mesh" else None)
+    (first, first_calls), (warm, warm_calls) = spans
+    assert first["dictPads"] == len(PAD_CARDS) * slots == first_calls
+    assert first["stackMisses"] > 0
+    assert warm["dictPads"] == 0 and warm_calls == 0
+    assert warm["stackHits"] > 0 and warm["stackMisses"] == 0
+    solo = _rows(device.execute_sql(NOCACHE + "SET segmentBatch = false; "
+                                    + sql))
+    assert rows[0] == rows[1] == solo == _rows(host.execute_sql(NOCACHE + sql))
+
+
+class _SkewedPlan:
+    """A member's plan whose dictionary plane reads as `skew` of the real
+    one: a metadata-only stand-in, which no device op could take."""
+
+    def __init__(self, plan, skew):
+        self._plan, self._skew = plan, skew
+
+    def __getattr__(self, name):
+        return getattr(self._plan, name)
+
+    def gather_arrays_packed(self, view, allow_packed=True):
+        arrs, packed = self._plan.gather_arrays_packed(view, allow_packed)
+        return tuple(self._skew(a) if kind == "dict" else a
+                     for a, (_col, kind) in zip(arrs, self._plan.slots)), \
+            packed
+
+
+_SKEWS = {
+    "dtype": lambda a: jax.ShapeDtypeStruct(a.shape, np.float32),
+    "trailing-dims": lambda a: jax.ShapeDtypeStruct(a.shape + (2,), a.dtype),
+}
+
+
+@pytest.mark.parametrize("skew", sorted(_SKEWS))
+def test_mismatch_is_found_from_metadata(tmp_path, monkeypatch, skew):
+    """A member whose dictionary plane differs in dtype or trailing
+    dimensions still raises BatchFamilyMismatch, with no pad launched:
+    the check reads shapes and dtypes only. The dispatch then falls back
+    to one per member, with the host engine's rows."""
+    table, device, host = _engines(tmp_path, "LONG", 13, len(PAD_CARDS),
+                                   cards=PAD_CARDS)
+    sql = NOCACHE + ONE_DEVICE + AGGS.format(t=table)
+    real = device.tpu._gather_batch
+    seen = []
+    pads = _CountingPad(monkeypatch)
+
+    def skewed(segments, plans, ndev=1):
+        plans = [plans[0], _SkewedPlan(plans[1], _SKEWS[skew]), *plans[2:]]
+        before = pads.calls
+        try:
+            return real(segments, plans, ndev)
+        except executor.BatchFamilyMismatch as e:
+            seen.append((str(e), pads.calls - before))
+            raise
+
+    monkeypatch.setattr(device.tpu, "_gather_batch", skewed)
+    got = device.execute_sql(sql)
+    assert len(seen) == 1 and "unequal plane shapes/dtypes" in seen[0][0]
+    assert seen[0][1] == 0
+    assert got.num_device_dispatches == len(PAD_CARDS)
+    assert _rows(got) == _rows(host.execute_sql(sql))
 
 
 # -- the helper alone ---------------------------------------------------------

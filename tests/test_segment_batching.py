@@ -394,8 +394,8 @@ def test_every_dispatch_entry_leaves_one_family_dispatch_span(
     assert first.get("segment") == (segs[0].name if members == 1 else None)
     assert first["compileMs"] > 0 and again["compileMs"] == 0.0
     for attrs in spans:
-        assert {"transferBytes", "stackHits", "stackMisses", "dictLookups",
-                "hbmBytesUsed", "hbmBudgetBytes",
+        assert {"transferBytes", "stackHits", "stackMisses", "dictPads",
+                "dictLookups", "hbmBytesUsed", "hbmBudgetBytes",
                 "hbmEvictions"} <= set(attrs)
     gathers = [s for s in trace.to_json() if s["operator"] == "GATHER_STACK"]
     assert len(gathers) == (members > 1)

@@ -126,6 +126,72 @@ def test_traced_run_syncs_exactly_as_untraced_and_allocates(
         assert "SEGMENT_CACHE(hit)" in ops and "family_dispatch" not in ops
 
 
+# a family whose members' dictionaries of `pdv` differ in size (9 to 16
+# entries, one bucket of 16): its stacked values plane is zero-padded
+DSQL = ("SET segmentCache = false; "
+        "SELECT pdk, SUM(pdv), MAX(pdv) FROM perfdict GROUP BY pdk")
+
+
+@pytest.fixture(scope="module")
+def warm_dict_engine(tmp_path_factory):
+    d = tmp_path_factory.mktemp("perfdict")
+    schema = Schema.build("perfdict", dimensions=[("pdk", "INT"),
+                                                  ("pdv", "INT")])
+    rng = np.random.default_rng(11)
+    segs = []
+    for i, card in enumerate((9, 12, 13, 16)):
+        # one family is one program: the members share the bounds of pdv
+        # (0 and 105) and the width of its ids
+        vals = np.concatenate([[0, 15], rng.permutation(14)[:card - 2] + 1])
+        cols = {"pdk": rng.integers(0, 8, 1500).astype(np.int32),
+                "pdv": (vals[np.arange(1500) % card] * 7).astype(np.int32)}
+        SegmentBuilder(schema, segment_name=f"pd_{i}").build(cols, d / f"s{i}")
+        segs.append(load_segment(d / f"s{i}"))
+        assert segs[-1].column_metadata("pdv").cardinality == card
+    qe = QueryExecutor()
+    qe.add_table(schema, segs)
+    # the cold run builds the stack: the three members below 16 entries pad
+    cold = qe.execute_sql("SET trace = true; " + DSQL)
+    assert not cold.exceptions, cold.exceptions
+    assert [s["attributes"]["dictPads"] for s in cold.trace_info
+            if s["operator"] == "family_dispatch"] == [3]
+    r = qe.execute_sql(DSQL)
+    assert not r.exceptions, r.exceptions
+    return qe
+
+
+def test_dict_pads_add_no_sync_and_no_launch(warm_dict_engine, monkeypatch):
+    """The dictPads counter is armed only under tracing: a warm untraced
+    run of a family with padded dictionary planes makes no sync, no span
+    and no pad, and the traced run of it makes the same launches and reads
+    dictPads 0 — the stack is cached, so nothing pads."""
+    import jax.numpy as jnp
+
+    sync = _CountingSync(monkeypatch)
+    pads = {"n": 0}
+    real_pad = jnp.pad
+
+    def counting_pad(*a, **kw):
+        pads["n"] += 1
+        return real_pad(*a, **kw)
+
+    monkeypatch.setattr(jnp, "pad", counting_pad)
+    spans_before = span_allocations()
+    plain = warm_dict_engine.execute_sql(DSQL)
+    assert not plain.exceptions, plain.exceptions
+    assert plain.trace_info is None and plain.num_device_dispatches == 1
+    assert (sync.block_calls, sync.device_get_calls, pads["n"]) == (0, 0, 0)
+    assert span_allocations() == spans_before
+    traced = warm_dict_engine.execute_sql("SET trace = true; " + DSQL)
+    assert (sync.block_calls, sync.device_get_calls, pads["n"]) == (0, 0, 0)
+    assert traced.num_device_dispatches == plain.num_device_dispatches
+    assert traced.result_table.rows == plain.result_table.rows
+    (fam,) = [s["attributes"] for s in traced.trace_info
+              if s["operator"] == "family_dispatch"]
+    assert fam["numSegments"] == 4 and fam["dictPads"] == 0
+    assert fam["stackHits"] > 0 and fam["stackMisses"] == 0
+
+
 # -- cluster-path guard: cost accounting + health rollup stay off the hot
 # -- path (observability PR discipline: with tracing off and no ANALYZE,
 # -- a broker query does zero span allocations, zero extra syncs, and
