@@ -50,7 +50,7 @@ MAX_PATTERNS_PER_TABLE = 64
 _SUM_FIELDS = (
     "queries", "failures", "rejected", "tracedQueries",
     "timeMs", "deviceWaitMs", "compileMs", "hostCombineMs",
-    "transferBytes", "hbmBytesTouched", "shuffledBytes", "cacheHitBytes",
+    "transferBytes", "shuffledBytes", "cacheHitBytes",
     "docsScanned", "deviceDispatches", "compiles",
     "segmentCacheHits", "segmentCacheMisses",
     "resultCacheHits", "scatterRetries", "hedgedRequests",
@@ -73,7 +73,7 @@ def build_cost_report(resp, table: str = "", client_id: str = "",
     into a flat cost report. Every numeric key is decayed-summable."""
     trace_info = getattr(resp, "trace_info", None)
     device_wait_ms = compile_ms = combine_ms = 0.0
-    transfer = shuffled = hbm_touched = cache_hit_bytes = 0
+    transfer = shuffled = cache_hit_bytes = 0
     if trace_info:
         from ..spi.trace import phase_breakdown
 
@@ -85,8 +85,6 @@ def build_cost_report(resp, table: str = "", client_id: str = "",
         shuffled = phases.get("shuffledBytes", 0)
         for span in trace_info:
             attrs = span.get("attributes") or {}
-            hbm_touched = max(hbm_touched,
-                              int(attrs.get("hbmBytesUsed", 0) or 0))
             cache_hit_bytes += int(attrs.get("cacheHitBytes", 0) or 0)
     mss = getattr(resp, "mse_stage_stats", None)
     if mss:
@@ -108,7 +106,6 @@ def build_cost_report(resp, table: str = "", client_id: str = "",
         "compileMs": compile_ms,
         "hostCombineMs": combine_ms,
         "transferBytes": transfer,
-        "hbmBytesTouched": hbm_touched,
         "shuffledBytes": shuffled,
         "cacheHitBytes": cache_hit_bytes,
         "docsScanned": int(getattr(resp, "num_docs_scanned", 0) or 0),

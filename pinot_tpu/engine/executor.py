@@ -35,7 +35,8 @@ from ..segment.device_cache import (
 )
 from ..segment.loader import ImmutableSegment
 from ..spi import faults
-from ..spi.trace import DEVICE_FETCH, FAMILY_DISPATCH, GATHER_STACK, TRACING
+from ..spi.trace import (DEVICE_COPY, DEVICE_FETCH, FAMILY_DISPATCH,
+                         FETCH_DECODE, GATHER_STACK, TRACING)
 from .ir import program_label
 from .plan import SegmentPlan, SegmentPlanner
 from .results import (
@@ -175,9 +176,9 @@ def _count_dispatch(new_compile: bool) -> None:
             c[1] += 1
 
 
-def _attach_dispatch_stats(span, cache: DeviceSegmentCache) -> None:
-    """Fold the thread-local transfer counters + an HBM snapshot into a
-    finished family-dispatch span (traced paths only)."""
+def _attach_dispatch_stats(span) -> None:
+    """Fold the thread-local transfer counters into a finished
+    family-dispatch span (traced paths only)."""
     stats = transfer_stats()
     if stats is not None:
         span.set_attribute("transferBytes", stats["transferBytes"])
@@ -186,7 +187,6 @@ def _attach_dispatch_stats(span, cache: DeviceSegmentCache) -> None:
         span.set_attribute("stackHits", stats["stackHits"])
         span.set_attribute("stackMisses", stats["stackMisses"])
         span.set_attribute("dictPads", stats["dictPads"])
-    span.attributes.update(cache.hbm_stats())
     clear_transfer_stats()
 
 
@@ -195,8 +195,13 @@ def device_fetch():
     """The DEVICE_FETCH span around a blocking device→host fetch: the one
     place a request waits for the device, traced or not. Its duration is
     the wait — queueing behind other requests' programs, execution, the
-    output pack and the copy; `hostFetches` and `fetchBytes` count what
-    crossed inside it (this thread's own, kernels.fetch_tally)."""
+    output pack — the copy and the decode. The fetch sites split it into
+    two children: DEVICE_COPY, the wait for the results and the copy (the
+    call that materializes them: the program cannot tell the two apart
+    without a sync of its own, the device trace can), and FETCH_DECODE,
+    the host's work on the fetched bytes. `hostFetches` and `fetchBytes`
+    count what crossed inside it (this thread's own,
+    kernels.fetch_tally)."""
     with TRACING.scope(DEVICE_FETCH) as span:
         if span is None:
             yield
@@ -219,7 +224,12 @@ def fetch_outputs(outs) -> list:
     if all(isinstance(o, np.ndarray) for o in outs):
         return list(outs)
     with device_fetch():
-        return [np.asarray(o) for o in outs]
+        with TRACING.scope(DEVICE_COPY):
+            host = [np.asarray(o) for o in outs]
+        # each output crossed as its own array: nothing to decode, and
+        # the span says so, as every fetch has both children
+        with TRACING.scope(FETCH_DECODE):
+            return host
 
 
 class BatchFamilyMismatch(Exception):
@@ -390,7 +400,7 @@ class TpuSegmentExecutor:
         and, when a trace is active, the family_dispatch span: gather +
         enqueue, with the compile time (detected via the compile-cache
         guard, measured without a sync), the program's label, per-slot
-        transfer bytes and an HBM snapshot. The span adds no sync: the wait
+        transfer bytes. The span adds no sync: the wait
         for the device is the DEVICE_FETCH span, where the untraced path
         waits too. Tracing off takes the first branch: one thread-local
         read, no spans."""
@@ -410,7 +420,7 @@ class TpuSegmentExecutor:
                 span.set_attribute("numSegments", len(segments))
                 return body(span)
             finally:
-                _attach_dispatch_stats(span, self.cache)
+                _attach_dispatch_stats(span)
 
     @staticmethod
     def _launch(span, gkey, program, padded: int, arrays, run,

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..engine import ir
+from ..spi.trace import DEVICE_COPY, FETCH_DECODE, TRACING
 from . import mxu_groupby
 
 jax.config.update("jax_enable_x64", True)
@@ -657,9 +658,9 @@ def fetch_tally():
 
 
 def unpack_outputs(p: PackedOuts) -> list:
-    count_host_fetch(4 * int(p.flat.shape[0]))
-    flat = np.asarray(p.flat)  # the query's single device→host transfer
-    return _split_flat(flat.view(np.uint8), p.metas)
+    """One packed buffer as host arrays: the query's single device→host
+    transfer, then its decode (`fetch_packed_batch` of one)."""
+    return fetch_packed_batch([p])[0]
 
 
 def _value_major_chunks(flat: np.ndarray, metas, planar: bool):
@@ -720,24 +721,33 @@ def fetch_packed_batch(packs: list) -> list:
     same program — the multi-segment combine case) concatenate on device
     and fetch once, so a 16-segment combine costs one fetch instead of
     16. Unequal lengths fetch individually — batching them
-    would compile a fresh concat executable per length combination."""
-    out = [None] * len(packs)
+    would compile a fresh concat executable per length combination.
+
+    Every buffer is copied first, then all are decoded: under the caller's
+    DEVICE_FETCH, one DEVICE_COPY span holds the wait for the results and
+    the copies, one FETCH_DECODE span the host's transposes and slicing."""
     by_len: dict[int, list[int]] = {}
     for i, p in enumerate(packs):
         by_len.setdefault(int(p.flat.shape[0]), []).append(i)
-    for n, idxs in by_len.items():
-        nbytes = n * 4
-        group_ok = len(idxs) > 1 and nbytes * len(idxs) <= _BATCH_FETCH_CAP
-        if not group_ok:
+    fetched = []  # (member positions, bytes a member, their host buffer)
+    with TRACING.scope(DEVICE_COPY):
+        for n, idxs in by_len.items():
+            nbytes = n * 4
+            if len(idxs) > 1 and nbytes * len(idxs) <= _BATCH_FETCH_CAP:
+                count_host_fetch(nbytes * len(idxs))
+                fetched.append((idxs, nbytes, np.asarray(_concat_flats(
+                    tuple(packs[i].flat for i in idxs)))))
+                continue
             for i in idxs:
-                out[i] = unpack_outputs(packs[i])
-            continue
-        count_host_fetch(nbytes * len(idxs))
-        flat = np.asarray(_concat_flats(
-            tuple(packs[i].flat for i in idxs))).view(np.uint8)
-        for j, i in enumerate(idxs):
-            out[i] = _split_flat(flat[j * nbytes:(j + 1) * nbytes],
-                                 packs[i].metas)
+                count_host_fetch(nbytes)
+                fetched.append(([i], nbytes, np.asarray(packs[i].flat)))
+    out = [None] * len(packs)
+    with TRACING.scope(FETCH_DECODE):
+        for idxs, nbytes, flat in fetched:
+            flat = flat.view(np.uint8)
+            for j, i in enumerate(idxs):
+                out[i] = _split_flat(flat[j * nbytes:(j + 1) * nbytes],
+                                     packs[i].metas)
     return out
 
 

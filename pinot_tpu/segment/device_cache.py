@@ -303,7 +303,7 @@ class DeviceSegmentCache:
         self.partial_hits = 0
         self.partial_misses = 0
         # lifetime pressure-eviction count (budget LRU + OOM relief),
-        # surfaced in hbm_stats() / dispatch-span HBM snapshots
+        # surfaced in hbm_stats()
         self.evictions = 0
         # flight-recorder attribution: which TIER paid each eviction and
         # WHY (budget LRU vs OOM relief vs lineage invalidation), plus

@@ -17,6 +17,8 @@ grouping). Per server shard the tree is
       family_dispatch           gather + enqueue, no sync
         GATHER_STACK            member planes to [S, ...] stacks
       DEVICE_FETCH              the host blocks until results are host arrays
+        DEVICE_COPY             the wait for the results and their copy
+        FETCH_DECODE            the host's decode of the fetched bytes
       SERVER_COMBINE            `groupsFetched`: groups the segments bring
       RESPONSE_SERIALIZATION    datatable.encode
 
@@ -25,8 +27,12 @@ FLAT list — consumers that only care about phase names/durations keep
 working — with `spanId`/`parentId` conveying the hierarchy, `startNs` (the
 epoch clock, the one the JAX profiler stamps its events with) aligning
 the spans of broker and servers, and an `attributes` dict carrying
-device-phase detail (compileMs, transferBytes, hostFetches, HBM snapshot).
-While a trace is active every scope is also a
+device-phase detail (compileMs, transferBytes, hostFetches). A span
+opened by `TRACING.scope` also carries `cpuMs`, the CPU time of the
+thread that opened it over the span (`time.thread_time_ns`): beside
+`durationMs` it says how much of the wall time the thread worked and how
+much it waited (for the device, a lock, the interpreter). While a trace
+is active every scope is also a
 `jax.profiler.TraceAnnotation`, so a profiler session running at the time
 records the same interval, with `query_id` and `span_id`, on the host
 plane of its own trace, beside the device's operations.
@@ -55,8 +61,12 @@ class ServerQueryPhase:
 
 
 # this engine's own span names, beside the reference's phases: the host
-# blocked on the device's results, and the [S, ...] stacking of a family
+# blocked on the device's results (its two steps: the copy, which waits
+# for the results, and the host's decode of them), and the [S, ...]
+# stacking of a family
 DEVICE_FETCH = "DEVICE_FETCH"
+DEVICE_COPY = "DEVICE_COPY"
+FETCH_DECODE = "FETCH_DECODE"
 GATHER_STACK = "GATHER_STACK"
 FAMILY_DISPATCH = "family_dispatch"
 
@@ -117,8 +127,8 @@ def sample_decision(query_id: str, rate: float) -> bool:
 class Span:
     """One recorded scope: a node in the query's span tree."""
 
-    __slots__ = ("name", "start_ms", "start_ns", "duration_ms", "span_id",
-                 "parent_id", "seq", "attributes")
+    __slots__ = ("name", "start_ms", "start_ns", "duration_ms", "cpu_ms",
+                 "span_id", "parent_id", "seq", "attributes")
 
     def __init__(self, name: str, start_ms: float, start_ns: int,
                  span_id: int, parent_id: Optional[int], seq: int):
@@ -128,6 +138,9 @@ class Span:
         self.start_ms = start_ms
         self.start_ns = start_ns
         self.duration_ms = 0.0
+        # the opening thread's CPU time over the span (TRACING.scope);
+        # None for a span recorded in one shot (Trace.record)
+        self.cpu_ms: Optional[float] = None
         self.span_id = span_id
         self.parent_id = parent_id
         self.seq = seq
@@ -140,6 +153,8 @@ class Span:
         out = {"operator": self.name, "startMs": self.start_ms,
                "startNs": self.start_ns,
                "durationMs": self.duration_ms, "spanId": self.span_id}
+        if self.cpu_ms is not None:
+            out["cpuMs"] = self.cpu_ms
         if self.parent_id is not None:
             out["parentId"] = self.parent_id
         if self.attributes:
@@ -179,7 +194,8 @@ class Trace:
 
     def record(self, name: str, start: float, end: float,
                parent: Optional[Span] = None) -> Span:
-        """Record a completed scope in one shot."""
+        """Record a completed scope in one shot (no `cpuMs`: the work was
+        not timed on this thread)."""
         span = self.new_span(name, start, parent)
         span.duration_ms = round((end - start) * 1000, 3)
         return span
@@ -208,7 +224,9 @@ def phase_breakdown(trace_json: list) -> dict:
     and host->device transfer volume. `deviceWaitMs` is the sum of the
     DEVICE_FETCH spans: how long the host stood blocked until results were
     host arrays — queueing behind other requests' programs, execution, the
-    output pack and the copy, NOT device time of this query alone."""
+    output pack, the copy and the host's decode of the copied bytes (its
+    DEVICE_COPY and FETCH_DECODE children), NOT device time of this query
+    alone."""
     out = {"compileMs": 0.0, "deviceWaitMs": 0.0, "hostCombineMs": 0.0,
            "transferBytes": 0, "shuffledBytes": 0}
     for span in trace_json:
@@ -285,14 +303,19 @@ class _Tracing:
         """Records a span into the active trace, nested under the current
         span; yields the Span so callers can attach attributes. The same
         interval is entered as a profiler TraceAnnotation carrying the
-        trace's query id and the span's id. No-op when tracing is off —
-        the hot path pays one thread-local read and yields None (zero Span
-        allocations, no annotation)."""
+        trace's query id and the span's id. The span's `cpu_ms` is the CPU
+        time of this thread between open and close: where the span's work
+        fans out to worker threads (`adopt`), the workers' own scopes
+        carry theirs, and the parent counts its own thread only. No-op when
+        tracing is off — the hot path pays one thread-local read and yields
+        None (zero Span allocations, no annotation, no clock read)."""
         trace = self.active_trace()
         if trace is None:
             yield None
             return
+        # the CPU interval nests inside the wall one: cpu_ms <= duration_ms
         start = time.perf_counter()
+        cpu0 = time.thread_time_ns()
         span = trace.new_span(name, start, self.current_span())
         stack = self._local.stack
         stack.append(span)
@@ -301,7 +324,9 @@ class _Tracing:
                              span_id=span.span_id):
                 yield span
         finally:
+            cpu = time.thread_time_ns() - cpu0
             span.duration_ms = round((time.perf_counter() - start) * 1000, 3)
+            span.cpu_ms = round(cpu / 1e6, 3)
             if stack and stack[-1] is span:
                 stack.pop()
 

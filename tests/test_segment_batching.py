@@ -395,7 +395,6 @@ def test_every_dispatch_entry_leaves_one_family_dispatch_span(
     assert first["compileMs"] > 0 and again["compileMs"] == 0.0
     for attrs in spans:
         assert {"transferBytes", "stackHits", "stackMisses", "dictPads",
-                "dictLookups", "hbmBytesUsed", "hbmBudgetBytes",
-                "hbmEvictions"} <= set(attrs)
+                "dictLookups"} <= set(attrs)
     gathers = [s for s in trace.to_json() if s["operator"] == "GATHER_STACK"]
     assert len(gathers) == (members > 1)

@@ -3,7 +3,8 @@ clock, with stable device names.
 
 - the tree of a traced query on a two-server cluster: every span of the
   server-side tree once per shard, children inside their parent, `startNs`
-  inside the client's wall and ordered broker -> server -> broker;
+  inside the client's wall and ordered broker -> server -> broker, each
+  span's `cpuMs` within its `durationMs`;
 - under a profiler session the spans are TraceAnnotations on its host
   plane, with the spans' names, ids and clock;
 - the program label: equal for two literals of one SQL shape, different
@@ -38,7 +39,11 @@ NOCACHE = "SET resultCache = false; SET segmentCache = false; "
 SQL = "SELECT spk, SUM(spv) FROM sptab WHERE spy = 3 GROUP BY spk LIMIT 50"
 SERVER_TREE = ("QUERY_PROCESSING", "SCHEDULER_WAIT", "BUILD_QUERY_PLAN",
                "family_dispatch", "GATHER_STACK", "DEVICE_FETCH",
-               "SERVER_COMBINE", "RESPONSE_SERIALIZATION")
+               "DEVICE_COPY", "FETCH_DECODE", "SERVER_COMBINE",
+               "RESPONSE_SERIALIZATION")
+# a span's parent where it is not QUERY_PROCESSING
+PARENT = {"GATHER_STACK": "family_dispatch", "DEVICE_COPY": "DEVICE_FETCH",
+          "FETCH_DECODE": "DEVICE_FETCH"}
 SLACK_MS = 1.0  # two traces read the epoch clock microseconds apart
 
 
@@ -108,8 +113,7 @@ def test_span_tree_of_a_traced_query_on_two_servers(cluster):
         assert root["attributes"]["queryId"] == resp.query_id
         by_name = {s["operator"]: s for s in own}
         for child in SERVER_TREE[1:]:
-            parent = "family_dispatch" if child == "GATHER_STACK" \
-                else "QUERY_PROCESSING"
+            parent = PARENT.get(child, "QUERY_PROCESSING")
             assert by_id[by_name[child]["parentId"]]["operator"] == parent
         # every child lies inside its parent, and siblings sum to no more
         for s in own:
@@ -136,6 +140,8 @@ def test_span_tree_of_a_traced_query_on_two_servers(cluster):
     for s in spans:
         assert wall0 - SLACK_MS * 1e6 <= s["startNs"]
         assert _end(s) * 1e6 <= wall1 + SLACK_MS * 1e6
+        # every span's thread CPU time, within its wall time
+        assert 0.0 <= s["cpuMs"] <= s["durationMs"] + 0.05, s
     # the layers add up to the whole, no span counted twice, none missing
     for shard, own in shards.items():
         by_name = {s["operator"]: s for s in own}

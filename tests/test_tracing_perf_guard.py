@@ -6,7 +6,8 @@ query is warm (compile guard satisfied, fused validation settled, planes
 resident in HBM), an untraced run must perform ZERO extra
 ``jax.block_until_ready`` / ``jax.device_get`` calls and allocate ZERO
 trace spans — the only tracing cost allowed is the single thread-local
-read in ``TRACING.scope``/``active_trace``. A traced run of the same query
+read in ``TRACING.scope``/``active_trace`` (not even a read of the thread
+CPU clock). A traced run of the same query
 must then make EXACTLY as many of those calls, and as many device→host
 fetches and dispatches, as the untraced one — spans record, they never
 add a sync, skip a cache or change a grouping — and still allocate spans,
@@ -73,18 +74,45 @@ class _CountingSync:
         monkeypatch.setattr(jax, "device_get", counting_get)
 
 
+class _CountingThreadClock:
+    """The tracer's own reads of the thread CPU clock: `spi/trace.py`'s
+    `time` module, replaced by one that counts `thread_time_ns` (the
+    executor reads that clock for its resource tracker, untraced too)."""
+
+    def __init__(self, monkeypatch):
+        import time
+
+        from pinot_tpu.spi import trace as trace_mod
+
+        self.reads = 0
+        clock = self
+
+        class _Time:
+            def __getattr__(self, name):
+                return getattr(time, name)
+
+            def thread_time_ns(self):
+                clock.reads += 1
+                return time.thread_time_ns()
+
+        monkeypatch.setattr(trace_mod, "time", _Time())
+
+
 def test_tracing_off_adds_zero_syncs_and_zero_spans(warm_engine, monkeypatch):
     sync = _CountingSync(monkeypatch)
+    clock = _CountingThreadClock(monkeypatch)
     spans_before = span_allocations()
-    r = warm_engine.execute_sql(SQL)
+    r = warm_engine.execute_sql("SET segmentCache = false; " + SQL)
     assert not r.exceptions, r.exceptions
-    assert r.trace_info is None
+    assert r.trace_info is None and r.num_device_dispatches == 1
     assert sync.block_calls == 0, (
         "tracing-off dispatch must not add block_until_ready syncs")
     assert sync.device_get_calls == 0, (
         "tracing-off dispatch must not add device_get syncs")
     assert span_allocations() == spans_before, (
         "tracing-off path must allocate zero Span objects")
+    assert clock.reads == 0, (
+        "tracing-off path must not read the thread CPU clock in the tracer")
 
 
 @pytest.mark.parametrize("options", [
@@ -97,28 +125,34 @@ def test_traced_run_syncs_exactly_as_untraced_and_allocates(
     from pinot_tpu.ops.kernels import host_fetches
 
     sync = _CountingSync(monkeypatch)
+    clock = _CountingThreadClock(monkeypatch)
 
     def run(prefix):
         before = (sync.block_calls, sync.device_get_calls, host_fetches(),
-                  span_allocations())
+                  span_allocations(), clock.reads)
         r = warm_engine.execute_sql(prefix + options + SQL)
         assert not r.exceptions, r.exceptions
         after = (sync.block_calls, sync.device_get_calls, host_fetches(),
-                 span_allocations())
+                 span_allocations(), clock.reads)
         return r, tuple(a - b for a, b in zip(after, before))
 
-    plain, (p_block, p_get, p_fetch, p_spans) = run("")
-    traced, (t_block, t_get, t_fetch, t_spans) = run("SET trace = true; ")
+    plain, (p_block, p_get, p_fetch, p_spans, p_reads) = run("")
+    traced, (t_block, t_get, t_fetch, t_spans, t_reads) = run(
+        "SET trace = true; ")
     assert plain.trace_info is None and traced.trace_info
     assert (t_block, t_get, t_fetch) == (p_block, p_get, p_fetch)
     assert traced.num_device_dispatches == plain.num_device_dispatches
     assert traced.num_segments_cache_hit == plain.num_segments_cache_hit
     assert traced.result_table.rows == plain.result_table.rows
     assert p_spans == 0 and t_spans > 0
+    # two reads of the thread clock a scope, none untraced
+    assert p_reads == 0 and t_reads == 2 * len(traced.trace_info)
     ops = [s["operator"] for s in traced.trace_info]
     if options:
         assert plain.num_device_dispatches == 1 and p_fetch == 1
         assert "DEVICE_FETCH" in ops and "family_dispatch" in ops
+        # the fetch's two parts, with no sync of their own
+        assert ops.count("DEVICE_COPY") == ops.count("FETCH_DECODE") == 1
     else:
         # the warm repeat is answered by the segment cache, traced or not,
         # and the trace says so
