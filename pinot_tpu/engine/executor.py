@@ -21,8 +21,9 @@ import numpy as np
 import jax.numpy as jnp
 
 from ..ops.kernels import (PackedOuts, dict_lookups, fetch_tally,
-                           group_table_form, limb_forms, min_max_forms,
-                           pack_outputs, run_program, unpack_outputs)
+                           group_table_form, limb_forms, lut_gathers,
+                           min_max_forms, pack_outputs, run_program,
+                           unpack_outputs)
 from .aot_cache import AOT_READY, aot_call
 from ..query.context import QueryContext
 from ..segment.device_cache import (
@@ -423,7 +424,7 @@ class TpuSegmentExecutor:
                 _attach_dispatch_stats(span)
 
     @staticmethod
-    def _launch(span, gkey, program, padded: int, arrays, run,
+    def _launch(span, gkey, program, padded: int, arrays, params, run,
                 count_after: bool = False, **family):
         """The one launch routine: note the compile key with the guard,
         count the dispatch, say in the span what runs, run, and register
@@ -439,7 +440,9 @@ class TpuSegmentExecutor:
             _count_dispatch(new_compile)
         if span is not None:
             # mode, label, row bucket, how the planes the program is fed
-            # decode their dictionaries (kernels.dict_lookups), how a
+            # decode their dictionaries (kernels.dict_lookups), how many
+            # of its LUT filters gather (kernels.lut_gathers; the fused
+            # kernel takes a LUT as id runs, no gather), how a
             # dense table takes its MINs and MAXs (kernels.min_max_forms),
             # the slots of a member's group table (0: it has none), which
             # table that is (kernels.group_table_form), the int8 planes
@@ -449,6 +452,8 @@ class TpuSegmentExecutor:
             span.set_attribute("program", program_label(program))
             span.set_attribute("padded", padded)
             span.set_attribute("dictLookups", dict_lookups(program, arrays))
+            span.set_attribute("lutGathers", 0 if family.get("fused")
+                               else lut_gathers(program, params))
             span.set_attribute("minMax", min_max_forms(program))
             span.set_attribute(
                 "groupSlots", program.num_groups
@@ -531,7 +536,8 @@ class TpuSegmentExecutor:
 
         try:
             outs = self._launch(span, gkey, plan.program, view.padded,
-                                arrays, run, fused=fused, lut_meta=lut_meta,
+                                arrays, params, run, fused=fused,
+                                lut_meta=lut_meta,
                                 packed=packed,
                                 aot_example=(arrays, params, nd))
             # the compiled fused kernel varies with lut_meta (run counts
@@ -655,7 +661,7 @@ class TpuSegmentExecutor:
         # failure falls back to the solo path, which counts itself — so
         # numDeviceDispatches stays exactly one per family either way
         outs = self._launch(
-            span, gkey, plan0.program, views[0].padded, arrays,
+            span, gkey, plan0.program, views[0].padded, arrays, params_b,
             lambda: pmesh.run_program_batch_sharded(
                 plan0.program, arrays, params_b, num_docs, views[0].padded,
                 ndev, packed=packed),
@@ -726,7 +732,7 @@ class TpuSegmentExecutor:
             return outs
 
         outs = self._launch(span, gkey, plan0.program, views[0].padded,
-                            arrays, run, batch_size=len(segments),
+                            arrays, params_b, run, batch_size=len(segments),
                             packed=packed,
                             aot_example=(arrays, params_b, num_docs))
         return outs, views

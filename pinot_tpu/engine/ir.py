@@ -333,10 +333,20 @@ def sparse_groupby_path(p: Program) -> str:
 def dict_gathers(p: Program) -> tuple:
     """Every DictGather of a Program (filter, group keys, aggregations), one
     entry per occurrence in the tree."""
+    return _nodes_of(p, DictGather)
+
+
+def luts(p: Program) -> tuple:
+    """Every Lut of a Program (its filter and the filters inside values),
+    one entry per occurrence in the tree."""
+    return _nodes_of(p, Lut)
+
+
+def _nodes_of(p: Program, kind: type) -> tuple:
     found = []
 
     def walk(x):
-        if isinstance(x, DictGather):
+        if isinstance(x, kind):
             found.append(x)
         elif isinstance(x, tuple):
             for c in x:

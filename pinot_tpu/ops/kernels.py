@@ -60,22 +60,68 @@ def dict_lookup_form(plane_len: int) -> str:
 def _dict_lookup(table, ids):
     """table[ids], the only lowering of ir.DictGather. The TPU gathers one
     row at a time (9.7 ns a row, whatever the table's size), so a small
-    table is looked up in registers: its entries are static slices (one
-    scalar a segment under the family's vmap) chosen by a chain of selects
-    that XLA fuses into the reduction, as it does the filter. A select is
-    exact for every dtype (a one-hot product would turn 0 * inf into NaN).
-    Ids outside [0, len) — row padding, masked by the caller — read entry
-    0 here and a clamped entry in the gather."""
-    n = table.shape[0]
-    if dict_lookup_form(n) == "gather":
+    table is looked up in registers (`_select_chain`). Ids outside
+    [0, len) — row padding, masked by the caller — read entry 0 there and
+    a clamped entry in the gather."""
+    if dict_lookup_form(table.shape[0]) == "gather":
         return table[ids]
+    return _select_chain(table, ids)
+
+
+def _select_chain(table, ids):
+    """table[ids] by a chain of selects: the table's entries are static
+    slices (one scalar a segment under the family's vmap), and XLA fuses
+    the chain into what reads it, as it does the filter. A select is exact
+    for every dtype (a one-hot product would turn 0 * inf into NaN). Ids
+    outside [0, len) read entry 0."""
     out = jnp.broadcast_to(table[0], ids.shape)
-    for k in range(1, n):
+    for k in range(1, table.shape[0]):
         out = jnp.where(ids == k, table[k], out)
         if k % _DICT_SELECT_FUSE == 0:
             # ends the fusion: the plane so far goes through HBM once
             out = jax.lax.optimization_barrier(out)
     return out
+
+
+# Longest boolean LUT (`ir.Lut`: an IN list, NOT IN, LIKE, an OR of EQs
+# over a dictionary column; its length is the cardinality + 1) tested as
+# bits in registers; a longer one is gathered. PERF.md section 6 has the
+# sweep (`python -m pinot_tpu.tools.lut_filter_sweep`).
+LUT_BITS_MAX = 4096
+
+
+def lut_filter_form(lut_len: int) -> str:
+    """How an `ir.Lut` over a LUT of `lut_len` entries is lowered: the one
+    rule that `_lut_mask` lowers by and `lut_gathers` counts by."""
+    return "bits" if 0 < lut_len <= LUT_BITS_MAX else "gather"
+
+
+def _lut_mask(lut, ids):
+    """lut[ids] for a boolean LUT, the only lowering of ir.Lut. The TPU
+    gathers one row at a time, so a LUT of up to LUT_BITS_MAX entries is
+    folded, inside the program, into W = ceil(len / 32) uint32 words (bit
+    b of word w is entry 32 w + b; O(len) a segment, not a row), and each
+    row picks its word by `_select_chain` over the W words and tests its
+    bit: W - 1 selects and a few shifts a row, fused with the rest of the
+    filter. Ids outside [0, len) — row padding, masked by the caller — read
+    some bit here and a clamped entry in the gather."""
+    n = lut.shape[0]
+    if lut_filter_form(n) == "gather":
+        return lut[ids]
+    w = -(-n // 32)
+    bits = jnp.pad(lut.astype(jnp.uint32), (0, 32 * w - n)).reshape(w, 32)
+    words = (bits << jnp.arange(32, dtype=jnp.uint32)).sum(
+        axis=1, dtype=jnp.uint32)
+    word = _select_chain(words, ids >> 5)
+    return ((word >> (ids & 31).astype(jnp.uint32)) & 1).astype(bool)
+
+
+def lut_gathers(program: ir.Program, params) -> int:
+    """How many of the program's Lut nodes lower as a gather, given the
+    params a dispatch feeds it (solo or stacked: the LUT's length is the
+    last dim)."""
+    return sum(lut_filter_form(params[node.lut_param].shape[-1]) == "gather"
+               for node in ir.luts(program))
 
 
 def dict_lookups(program: ir.Program, arrays) -> str:
@@ -396,7 +442,7 @@ def _eval_filter(node: ir.FilterNode, arrays, params, n: int):
             mask = mask.any(axis=1)
         return mask
     if isinstance(node, ir.Lut):
-        m = params[node.lut_param][arrays[node.ids_slot]]
+        m = _lut_mask(params[node.lut_param], arrays[node.ids_slot])
         if m.ndim == 2:
             m = m.any(axis=1)
         return m

@@ -10,7 +10,8 @@ clock, with stable device names.
 - the program label: equal for two literals of one SQL shape, different
   for another shape, the name of the lowered module and of its ops' scopes;
 - every `family_dispatch` span says how many slots a member's group table has
-  (`groupSlots`; 0 for a program with none).
+  (`groupSlots`; 0 for a program with none) and how many of its LUT filters
+  gather (`lutGathers`; 0 where each LUT is tested as bits).
 """
 
 from __future__ import annotations
@@ -427,12 +428,14 @@ def test_ops_inside_a_loop_or_a_branch_are_filed_under_the_programs_scope(
     assert sorts and all(filed[n] == scope for n in sorts), sorts
     assert any(inside in n for n in sorts)
     if lower is _three_key_family:
-        # its two LUT filters are gathers batched BEFORE the loop, under
-        # `filter` (`kernel_filter_ms` reads them), and the composite key's
-        # multiply-adds lie inside it, under the group-by's scope
-        gathers = [n for n in names if n.endswith("/gather")]
-        assert gathers and all(filed[n] == "filter" and "while" not in n
-                               for n in gathers), gathers
+        # its two LUT filters are bit tests batched BEFORE the loop, under
+        # `filter` (`kernel_filter_ms` reads them; no gather is left), and
+        # the composite key's multiply-adds lie inside it, under the
+        # group-by's scope
+        assert not [n for n in names if n.endswith("/gather")]
+        tests = [n for n in names if n.endswith("/shift_right_logical")]
+        assert tests and all(filed[n] == "filter" and "while" not in n
+                             for n in tests), sorted(names)
         muls = [n for n in names if n.endswith("/mul")]
         assert muls and all(filed[n] == scope for n in muls), muls
 
@@ -463,7 +466,9 @@ def test_dispatch_span_carries_the_slots_of_the_group_table(request, sql,
     # table has the product of its keys' cardinalities, from the plan's
     # static shape; a sort-based one at most its numGroupsLimit, and by the
     # planner's rule a slot for every key. `groupTable` (PR 35) says which
-    # table it is (`kernels.group_table_form`); no metric reads it
+    # table it is (`kernels.group_table_form`); no metric reads it.
+    # `lutGathers`: what `lut_gathers_per_query` reads; SQL3's two IN lists
+    # over 40 strings are tested as bits, as every program here
     engine = request.getfixturevalue(
         "engine3" if " sp3 " in sql else "engine")
     resp = engine.execute_sql("SET trace = true; " + NOCACHE + sql)
@@ -474,6 +479,7 @@ def test_dispatch_span_carries_the_slots_of_the_group_table(request, sql,
     for attrs in spans:
         assert isinstance(attrs["groupSlots"], int)
         assert attrs["groupTable"] == table
+        assert attrs["lutGathers"] == 0
         if slots is None:
             assert attrs["mode"] == "group_by_sparse"
             assert attrs["groupSlots"] > 0
