@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from ..ops.kernels import (PackedOuts, dict_lookups, fetch_tally,
                            group_table_form, limb_forms, lut_gathers,
                            min_max_forms, pack_outputs, run_program,
-                           unpack_outputs)
+                           signed_sums, unpack_outputs)
 from .aot_cache import AOT_READY, aot_call
 from ..query.context import QueryContext
 from ..segment.device_cache import (
@@ -447,7 +447,8 @@ class TpuSegmentExecutor:
             # the slots of a member's group table (0: it has none), which
             # table that is (kernels.group_table_form), the int8 planes
             # its limb pass carries and the SUMs left to a scatter
-            # (kernels.limb_forms)
+            # (kernels.limb_forms), the SUMs of a value that may be
+            # negative (kernels.signed_sums)
             span.set_attribute("mode", program.mode)
             span.set_attribute("program", program_label(program))
             span.set_attribute("padded", padded)
@@ -462,6 +463,7 @@ class TpuSegmentExecutor:
             planes, scatters = limb_forms(program, padded, arrays)
             span.set_attribute("limbPlanes", planes)
             span.set_attribute("sumScatters", scatters)
+            span.set_attribute("signedSums", signed_sums(program))
         t0 = time.perf_counter()
         outs = run()
         if count_after:

@@ -1090,10 +1090,13 @@ class SegmentPlanner(AggPlanContext):
                 # lexicographic value order, and a segment's L smallest keys
                 # contain every globally-L-smallest key it holds) — the
                 # device then ships L slots instead of millions (reference:
-                # ordering-aware server trim, TableResizer/minServerGroupTrimSize)
+                # ordering-aware server trim, TableResizer/minServerGroupTrimSize).
+                # The table keeps OFFSET + LIMIT rounded up to a power of two
+                # (still exact: the broker cuts to the LIMIT), so statements
+                # that differ in their LIMIT or OFFSET share one program
                 trim = None if any_derived else _orderby_prefix_trim(q)
                 if trim is not None and trim <= limit:
-                    limit = trim
+                    limit = min(limit, _key_space_bucket(trim))
                     exact_trim = True
                 mode = "group_by_sparse"
                 out_groups = min(capacity, max(1, limit))

@@ -264,6 +264,15 @@ def limb_forms(program: ir.Program, n: int, arrays) -> tuple:
     return 0, 0
 
 
+def signed_sums(program: ir.Program) -> int:
+    """How many of a program's SUMs have static bounds below zero, so that
+    they carry the signed recipe: a negative-count plane on the limb pass
+    (`_limb_recipe`), a signed payload through a sorted table. From the
+    program's static fields, no sync."""
+    return sum(1 for a in program.aggs
+               if a.kind == "sum" and a.vmin is not None and a.vmin < 0)
+
+
 def _eval_ring32(node: ir.ValueExpr, arrays, params):
     """`node`'s value modulo 2^32, as int32, in 32-bit arithmetic: +, - and
     * wrap in two's complement, so a tree of them over integer leaves,
@@ -1186,6 +1195,8 @@ def _run_sparse_group_by(program: ir.Program, arrays, params, mask, n):
     - sort-iota + gather: with >= 2 payload operands, sort only
       (key[, distinct_ids], iota32) and gather each payload through the
       permutation — (1+A)·n sorted bytes become ~2·n.
+    A key-ordered trim of k slots that k rows or fewer pass sorts only those
+    rows, gathered first (`_passing_rows`; decided per member at run time).
     """
     # 64-bit sorts/scatters are emulated on TPU: sort 32-bit keys whenever
     # the composite key space fits (key_space is static on the Program)
@@ -1275,23 +1286,24 @@ def _run_sparse_group_by(program: ir.Program, arrays, params, mask, n):
     if program.keys_presorted:
         return _presorted_sparse_tail(program, operands, specs, mask, n)
 
-    num_payloads = len(operands) - num_sort_keys
-    if num_payloads >= 2:
-        # sort-iota + gather: dragging every payload through the bitonic
-        # sort network costs (num_keys+A)·n sorted bytes and A extra
-        # compare-network permute lanes. Sort only (keys..., iota32) and
-        # gather each payload through the permutation instead — the sort
-        # moves ~2·n values and the payloads cross HBM once via gathers.
-        # lax.sort is stable, so the permutation (iota as the tie-broken
-        # last operand) reproduces the multi-operand sort bit-for-bit.
-        iota = jnp.arange(n, dtype=jnp.int32)
-        head = jax.lax.sort(tuple(operands[:num_sort_keys]) + (iota,),
-                            num_keys=num_sort_keys)
-        perm = head[num_sort_keys]
-        sorted_ops = tuple(head[:num_sort_keys]) + tuple(
-            op[perm] for op in operands[num_sort_keys:])
-    else:
-        sorted_ops = jax.lax.sort(tuple(operands), num_keys=num_sort_keys)
+    def by_key(ops):
+        if len(ops) - num_sort_keys >= 2:
+            # sort-iota + gather: dragging every payload through the
+            # bitonic sort network costs (num_keys+A)·n sorted bytes and A
+            # extra compare-network permute lanes. Sort only (keys...,
+            # iota32) and gather each payload through the permutation
+            # instead — the sort moves ~2·n values and the payloads cross
+            # HBM once via gathers. lax.sort is stable, so the permutation
+            # (iota as the tie-broken last operand) reproduces the
+            # multi-operand sort bit-for-bit.
+            iota = jnp.arange(ops[0].shape[0], dtype=jnp.int32)
+            head = jax.lax.sort(tuple(ops[:num_sort_keys]) + (iota,),
+                                num_keys=num_sort_keys)
+            perm = head[num_sort_keys]
+            return tuple(head[:num_sort_keys]) + tuple(
+                op[perm] for op in ops[num_sort_keys:])
+        return jax.lax.sort(tuple(ops), num_keys=num_sort_keys)
+
     def tail(sorted_ops):
         n = sorted_ops[0].shape[0]
         skey_raw = sorted_ops[0]
@@ -1366,11 +1378,41 @@ def _run_sparse_group_by(program: ir.Program, arrays, params, mask, n):
     # runs over the first eighth alone (a branch under `lax.map`, as a
     # batch family runs; both sides under a vmap)
     short = max(n // 8, 2048)  # whole blocks of `_sorted_prefix_f64`
-    if 2 * short > n:
-        return tail(sorted_ops)
-    return jax.lax.cond(
-        (sorted_ops[0] < sentinel).sum() <= short,
-        lambda ops: tail(tuple(o[:short] for o in ops)), tail, sorted_ops)
+
+    def whole(ops):
+        sorted_ops = by_key(ops)
+        if 2 * short > n:
+            return tail(sorted_ops)
+        return jax.lax.cond(
+            (sorted_ops[0] < sentinel).sum() <= short,
+            lambda ops: tail(tuple(o[:short] for o in ops)), tail,
+            sorted_ops)
+
+    k = program.num_groups
+    if program.exact_trim and k <= short:
+        # a key-ordered trim keeps a member's first k keys: where k rows or
+        # fewer pass the filter they are every row of its groups, so they
+        # are gathered into k rows and only those are sorted
+        return jax.lax.cond(
+            mask.sum() <= k,
+            lambda ops: tail(by_key(_passing_rows(ops, mask, k, sentinel))),
+            whole, tuple(operands))
+    return whole(operands)
+
+
+def _passing_rows(ops, mask, k: int, sentinel):
+    """`ops` at the rows where `mask` holds, in row order, in k rows: the
+    j-th passing row is the first whose running count of passing rows
+    reaches j (a binary search of k counts, no scatter). The first operand
+    is the sort key: past the passing rows it reads `sentinel`, so the
+    rows there sort last and count for no group."""
+    count = _prefix_sum(mask.astype(jnp.int32))
+    at = jnp.searchsorted(count, jnp.arange(1, k + 1, dtype=jnp.int32),
+                          method="scan_unrolled")
+    at = jnp.minimum(at, mask.shape[0] - 1)
+    live = jnp.arange(k) < count[-1]
+    return (jnp.where(live, ops[0][at], sentinel),) + tuple(
+        op[at] for op in ops[1:])
 
 
 def _bitmap_rows(words):

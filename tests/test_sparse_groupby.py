@@ -227,7 +227,8 @@ def test_orderby_prefix_trim_pushdown(env):
     q = parse_sql(sql)
     plan = SegmentPlanner(q, segs[0]).plan()
     assert plan.program.mode == "group_by_sparse"
-    assert plan.program.num_groups == 40  # not DEFAULT_NUM_GROUPS_LIMIT
+    # offset+limit rounded up to a power of two, not DEFAULT_NUM_GROUPS_LIMIT
+    assert plan.program.num_groups == 64
     assert plan.program.exact_trim
     resp = tpu.execute_sql(sql)
     assert not resp.exceptions, resp.exceptions
@@ -240,7 +241,7 @@ def test_orderby_prefix_trim_pushdown(env):
     # a DISTINCTCOUNT (dict-merge path) under the pushdown also stays exact
     sql2 = ("SELECT uid, code, DISTINCTCOUNT(tag), SUM(amount) FROM hc "
             "GROUP BY uid, code ORDER BY uid, code LIMIT 30")
-    assert SegmentPlanner(parse_sql(sql2), segs[0]).plan().program.num_groups == 30
+    assert SegmentPlanner(parse_sql(sql2), segs[0]).plan().program.num_groups == 32
     r2 = tpu.execute_sql(sql2)
     assert not r2.exceptions, r2.exceptions
     want2 = conn.execute(

@@ -159,6 +159,8 @@ def test_served_path_equals_the_reference_and_the_host_engine(
         ("group_by", "limb") if cls == "ssb_q3_1"
         else ("group_by_sparse", "sorted"))
     assert spans[0]["groupSlots"] == SLOTS[cls]
+    # SUM(lo_revenue) is bounded below by 81,000: no signed SUM
+    assert spans[0]["signedSums"] == 0
 
 
 def test_a_class_runs_one_executable_whatever_its_literals(deployment):

@@ -191,6 +191,78 @@ def test_q1_sums_past_int32_ride_one_limb_pass(one_chip, lineitem,
     assert len(rows) <= 11, len(rows)
 
 
+@pytest.fixture(scope="module")
+def flight4(tmp_path_factory):
+    """A small segment of SSB flight 4's columns at their published
+    cardinalities (7 years, 5 regions and 25 nations a side, 250 supplier
+    cities, 5 manufacturers, 25 categories, 1,000 brands) and the two
+    money columns raw, their difference's bounds straddling zero as in
+    benchmark/configs/ssb-flat-sf10-16seg-flight4.json."""
+    import numpy as np
+
+    from pinot_tpu.segment.builder import SegmentBuilder
+    from pinot_tpu.segment.device_cache import SegmentDeviceView
+    from pinot_tpu.segment.loader import load_segment
+    from pinot_tpu.spi.data_types import Schema
+    from pinot_tpu.spi.table_config import IndexingConfig, TableConfig
+
+    n = 1 << 15
+    i = np.arange(n)
+
+    def names(fmt, k):
+        return np.asarray([fmt % j for j in range(k)], dtype=object)
+
+    keys = ["c_region", "c_nation", "s_region", "s_nation", "s_city",
+            "p_mfgr", "p_category", "p_brand1"]
+    schema = Schema.build(
+        "t", dimensions=[("d_year", "INT")] + [(k, "STRING") for k in keys],
+        metrics=[("lo_revenue", "INT"), ("lo_supplycost", "INT")])
+    city, brand, nation = (i // 7) % 250, (i * 13) % 1000, i % 25
+    cols = {"d_year": (1992 + i % 7).astype(np.int32),
+            "c_nation": names("CN%02d", 25)[nation],
+            "c_region": names("R%d", 5)[nation // 5],
+            "s_city": names("SC%03d", 250)[city],
+            "s_nation": names("SN%02d", 25)[city // 10],
+            "s_region": names("R%d", 5)[city // 50],
+            "p_brand1": names("MFGR#%04d", 1000)[brand],
+            "p_category": names("MFGR#%02d", 25)[brand // 40],
+            "p_mfgr": names("MFGR#%d", 5)[brand // 200],
+            "lo_revenue": (81_000 + i * 37 % 10_000_000).astype(np.int32),
+            "lo_supplycost": (54_000 + i % 71_941).astype(np.int32)}
+    path = str(tmp_path_factory.mktemp("tpu_compile_f4") / "s")
+    SegmentBuilder(schema, TableConfig(table_name="t", indexing=IndexingConfig(
+        no_dictionary_columns=["lo_revenue", "lo_supplycost"])), "s0").build(
+        cols, path)
+    segment = load_segment(path)
+    return segment, SegmentDeviceView(segment)
+
+
+def test_signed_profit_rides_the_limb_pass(one_chip, flight4, monkeypatch):
+    """SSB Q4.2 (the cell `ssb16.flight4`) over a 16 x 2^22-row family: the
+    signed SUM(lo_revenue - lo_supplycost) on the limb kernel at 4,375
+    slots, the count plane, five 7-bit digits of the 32-bit value and its
+    negative-count plane, one Pallas call, no scatter; another LIMIT plans
+    the same program."""
+    from pinot_tpu.engine.plan import SegmentPlanner
+    from pinot_tpu.query.parser.sql import parse_sql
+
+    monkeypatch.setattr(mxu_groupby, "backend_platform", lambda: "tpu")
+    sql = ("SELECT SUM(lo_revenue - lo_supplycost), d_year, s_nation, "
+           "p_category FROM t WHERE c_region = 'R1' AND s_region = 'R1' AND "
+           "(d_year = 1997 OR d_year = 1998) AND (p_mfgr = 'MFGR#1' OR "
+           "p_mfgr = 'MFGR#2') GROUP BY d_year, s_nation, p_category "
+           "ORDER BY d_year, s_nation, p_category LIMIT 900")
+    program, text = compile_program(one_chip, flight4, sql, R22, batch=16)
+    assert program.mode == "group_by" and program.num_groups == 7 * 25 * 25
+    assert kernels.limb_forms(program, R22, None) == (7, 0)
+    assert kernels.signed_sums(program) == 1
+    assert text.count("tpu_custom_call") == 1
+    assert _op_count(text, "scatter") == 0
+    other = SegmentPlanner(parse_sql(sql.replace("LIMIT 900", "LIMIT 100")),
+                           flight4[0]).plan().program
+    assert other == program
+
+
 # `dd_distinct_by_year` of the benchmark's `ssb16.drilldown`
 _DISTINCT_BY_YEAR = (
     "SELECT d_year, DISTINCTCOUNT(lo_discount), MIN(lo_revenue), "
